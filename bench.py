@@ -20,6 +20,11 @@ decode_engine paged-kernel A/B) carry ``tile_config`` — the resolved
 tile blocks plus their resolution source (``table|fallback|override``,
 kubeflow_tpu/ops/autotune.py) — so an A/B across rounds can attribute
 a throughput move to a tile-table change (PERF.md "Tile autotune").
+
+This is a chip artifact: it exits non-zero — after printing the line —
+when any config raised or timed out, or when any row came from a
+backend other than ``tpu``. CPU runs of the configs are tests
+(tests/test_bench_suite.py), never rows here.
 """
 
 from __future__ import annotations
@@ -32,58 +37,34 @@ REFERENCE_GPU_IMAGES_PER_SEC = 360.0
 
 def main() -> None:
     import argparse
-    import os
 
-    from kubeflow_tpu.bench.suite import run_all_isolated, run_cpu_smoke
+    from kubeflow_tpu.bench.suite import run_all_isolated
 
     p = argparse.ArgumentParser()
     p.add_argument("--profile", metavar="DIR", default=None,
                    help="capture XLA profiler traces into DIR")
     args = p.parse_args()
 
-    # each config in its own subprocess under a hard timeout: a wedged
-    # device transport must never stop the one-JSON-line contract
+    # one process per chip: this parent never initializes a jax backend;
+    # each config runs in its own child, one after another, under a
+    # plain timeout
     results = run_all_isolated(profile_dir=args.profile)
     headline = results.get("resnet50", {})
     value = float(headline.get("images_per_sec_per_chip", 0.0))
-    # artifact hygiene: r03/r04 skipped every suite with "device
-    # transport unreachable" and the artifacts read as a flat perf
-    # trajectory. Stamp WHAT actually ran at the top level, and (below)
-    # exit nonzero — with the artifact already emitted — on transport
-    # failure, so a skipped round is unmistakably a failed round.
-    def _err_kind(r):
-        # the structured classification run_all_isolated stamps; the
-        # substring fallback only covers results from an older suite —
-        # never reword-couple new code to the free-text message
-        kind = r.get("error_kind", "")
-        if kind:
-            return kind
-        e = r.get("error", "")
-        if "device transport unreachable" in e:
-            return "transport_unreachable"
-        if "transport wedged" in e or "transport hung" in e:
-            return "transport_wedged"
-        return "error" if "error" in r else ""
-
-    kinds = [_err_kind(r) for r in results.values()]
-    if kinds and all(k == "transport_unreachable" for k in kinds):
-        transport = "unreachable"
-    elif any(k in ("transport_wedged", "transport_timeout")
-             for k in kinds):
-        transport = "wedged"
-    else:
-        transport = "ok"
-    platforms = {r.get("platform") for r in results.values()
-                 if "error" not in r and r.get("platform")}
-    accel = sorted(platforms - {"cpu"})
+    errored = sorted(n for n, r in results.items() if "error" in r)
+    off_chip = sorted(n for n, r in results.items()
+                      if "error" not in r and r.get("platform") != "tpu")
+    device_kinds = sorted({r["device_kind"] for r in results.values()
+                           if r.get("device_kind")})
     line = {
         "metric": "resnet50_train_images_per_sec_per_chip",
         "value": round(value, 2),
         "unit": "images/sec/chip",
         "vs_baseline": round(value / REFERENCE_GPU_IMAGES_PER_SEC, 3),
-        "device_transport": transport,
-        "tier": (accel[0] if accel
-                 else "cpu" if platforms else "cpu-smoke"),
+        "device_kind": device_kinds[0] if len(device_kinds) == 1
+        else device_kinds,
+        "errored": errored,
+        "not_on_tpu": off_chip,
     }
     if "mfu" in headline:
         line["mfu"] = headline["mfu"]
@@ -100,42 +81,12 @@ def main() -> None:
         # spent stepping vs recompiling vs unattributed host gaps
         line["goodput"] = headline["goodput"]
     line["extras"] = results
-    # the always-on CPU smoke tier (tier:"cpu" rows, tiny shapes): an
-    # accelerator outage degrades the artifact to labeled correctness
-    # evidence for every config instead of an empty all-skip record
-    # (KFTPU_BENCH_CPU_SMOKE=0 disables)
-    if os.environ.get("KFTPU_BENCH_CPU_SMOKE", "1") != "0":
-        smoke = run_cpu_smoke()
-        line["cpu_smoke"] = smoke
-        smoke_ok = bool(smoke) and all(
-            "error" not in r for r in smoke.values())
-    else:
-        smoke_ok = False
-    if not platforms and not smoke_ok:
-        line["tier"] = "none"
-    if value <= 0 and smoke_ok:
-        line["note"] = (
-            "accelerator unreachable this run; cpu_smoke rows (tier: "
-            "cpu, tiny shapes) prove every config executes end-to-end "
-            "— they are correctness evidence, not performance numbers")
     print(json.dumps(line))
-    if transport != "ok":
-        # the artifact above records the skip; the exit code records
-        # the FAILURE (a driver must not mistake it for a flat round)
-        sys.exit(1)
-    if value <= 0 and not smoke_ok:
+    if errored or off_chip:
+        # the artifact above records what happened; the exit code
+        # records that this is not a clean chip round
         sys.exit(1)
 
 
 if __name__ == "__main__":
-    try:
-        main()
-    except Exception as e:  # noqa: BLE001 — bench must always emit one line
-        print(json.dumps({
-            "metric": "resnet50_train_images_per_sec_per_chip",
-            "value": 0.0,
-            "unit": "images/sec/chip",
-            "vs_baseline": 0.0,
-            "error": f"{type(e).__name__}: {e}",
-        }))
-        sys.exit(1)
+    main()

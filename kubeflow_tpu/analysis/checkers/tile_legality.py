@@ -4,14 +4,16 @@ The TPU vector layout tiles the last two axes of every kernel block:
 the last (*lane*) axis in units of 128, the second-to-last (*sublane*)
 axis in units of 8 for f32 (16 for bf16, 32 for int8/fp8 — 8 is the
 weakest legal floor, so that is what a static checker can enforce
-without dtype inference). A block whose lane dim is not a multiple of
-128 (and not a full/broadcast dim of size 1) compiles in interpret mode
-— where CPU tests run — and then fails Mosaic lowering on real
+without dtype inference). The only other legal value is the array's
+own size in that dim. A block that breaks this compiles in interpret
+mode — where CPU tests run — and then fails Mosaic lowering on real
 hardware. That is exactly the PR 1 ``ops/bnconv.py`` bug: block sizes
 came from ``_pick_block(dim, want)`` whose default ``floor=8`` happily
-returns lane tiles of 8.
+returns lane tiles of 8 — and the PR 6 ``ops/sampling.py`` one: a
+``(1, Vp)`` block over a ``(B, Vp)`` array, which this rule used to
+wave through as "a size-1 dim", and which the v5e refused.
 
-Two detections:
+Three detections:
 
 1. a **literal** lane/sublane dim in a ``BlockSpec((...), ...)`` tuple
    that violates the floor — suppressed when the enclosing function
@@ -23,6 +25,12 @@ Two detections:
    guard, because the guard itself is typically computed with the same
    wrong floor (the PR 1 failure mode: ``_tileable`` said yes, Mosaic
    said no).
+
+3. a **literal 1** in the lane/sublane position whose index-map entry
+   is not the constant ``0``: a size-1 block dim is legal only when the
+   array's dim is 1 too, and an index that moves along the dim says it
+   is not. ``(1, block_q, 1)`` with ``lambda b, i, j: (b, i, 0)`` is
+   fine; ``(1, Vp)`` with ``lambda b: (b, 0)`` is not.
 
 **Table-resolved tiles** (the autotune plane): the flash/paged kernels'
 block dims are now dynamic values resolved from
@@ -131,6 +139,22 @@ def _has_fallback_guard(fn: Optional[ast.AST]) -> bool:
     return False
 
 
+def _index_moves(call: ast.Call, pos: int) -> bool:
+    """True when the BlockSpec's index map is a lambda returning a
+    tuple whose entry at ``pos`` (from the end) is anything but the
+    constant 0. Maps the checker cannot read (a named function, a
+    non-tuple body) say nothing."""
+    index_map = call.args[1] if len(call.args) > 1 else next(
+        (kw.value for kw in call.keywords if kw.arg == "index_map"), None)
+    if not isinstance(index_map, ast.Lambda):
+        return False
+    body = index_map.body
+    if not isinstance(body, ast.Tuple) or len(body.elts) < -pos:
+        return False
+    entry = body.elts[pos]
+    return not (isinstance(entry, ast.Constant) and entry.value == 0)
+
+
 @register_checker
 class TileLegalityChecker(Checker):
     rule = "TPU001"
@@ -213,10 +237,24 @@ class TileLegalityChecker(Checker):
             return
 
         value = astutil.resolve_int(fn, dim)
-        if value is None or value == 1:
-            # unresolvable (dynamic) or full/broadcast dim — Mosaic
-            # relayouts size-1 trailing dims (see ops/attention.py's
-            # (1, block_q, 1) lse blocks)
+        if value is None:
+            return  # unresolvable (dynamic): the table lint covers it
+        if value == 1:
+            # detection 3: legal only as the array's whole (size-1) dim,
+            # e.g. ops/attention.py's (1, block_q, 1) lse blocks — an
+            # index map that moves along it says the array's is larger
+            if _index_moves(call, -1 if lane else -2):
+                yield self.finding(
+                    module, call,
+                    f"{axis} block dim 1 is walked by its index map, so "
+                    f"the array's {axis} dim is larger than 1; Mosaic "
+                    "accepts a size-1 block dim only when it is the "
+                    "array's whole dim (interpret-mode CPU tests will "
+                    "not catch it)",
+                    hint="fold the walked dim out of the last two (e.g. "
+                         "reshape (B, N) to (B, N // 128, 128) and block "
+                         "(1, N // 128, 128)), or pass per-row scalars "
+                         "through SMEM whole")
             return
         if value % multiple != 0 and not guarded:
             yield self.finding(

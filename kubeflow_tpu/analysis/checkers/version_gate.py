@@ -1,29 +1,29 @@
 """TPU006 — version-gated jax APIs outside ``compat/``.
 
-The exact bug class the TPU rebuild warns about: code that only fails
-on the real runtime. The platform targets the current jax surface, but
-the pinned container jax (0.4.37) predates part of it — 4 direct
-``jax.shard_map`` call sites sailed through every CPU-side check and
-killed 22 tier-1 tests with an AttributeError at run time. The repo
-policy (docs/COMPAT.md) is that ``kubeflow_tpu/compat/`` is the single
-sanctioned call site for version-sensitive jax APIs; this rule makes
-the policy mechanical.
+The jax SPMD surface the platform stands on — ``shard_map``, the
+varying-axes cast, ``axis_size``, the ambient mesh and its context
+manager — moved, was renamed or was removed in every jax release from
+0.5 to 0.9. A direct call parses, imports and passes every test that
+does not trace it, then fails at trace time on a runtime with the
+other jax. The repo policy (docs/COMPAT.md) is that
+``kubeflow_tpu/compat/`` — written for the installed jax 0.9 — is the
+single call site; this rule makes the policy mechanical, so the next
+upgrade edits one module.
 
-Table-driven: :data:`GATED_APIS` maps a dotted jax name to the version
-window where it exists and the compat shim to call instead. Flagged,
-anywhere outside ``compat/``:
+Table-driven: :data:`GATED_APIS` maps a dotted jax name to where it
+stands across versions and the compat function to call instead.
+Flagged, anywhere outside ``compat/``:
 
 - attribute chains (``jax.shard_map(...)``, a bare
   ``jax.sharding.get_abstract_mesh`` reference);
-- ``from jax import shard_map`` / ``from jax.sharding import use_mesh``
+- ``from jax import shard_map`` / ``from jax.sharding import set_mesh``
   style imports of a gated name;
-- any import touching ``jax.experimental.shard_map`` — present on the
-  pinned jax but *removed* on current jax, so it is just as
-  version-gated in the other direction.
+- any import touching ``jax.experimental.shard_map`` (removed
+  upstream).
 
 ``hasattr(jax, "shard_map")`` / ``getattr(..., None)`` probes pass the
-name as a string and are deliberately not flagged — that is how the
-compat shims themselves resolve the surface, and a probe cannot crash.
+name as a string and are deliberately not flagged — a probe cannot
+crash.
 """
 
 from __future__ import annotations
@@ -41,21 +41,20 @@ SANCTIONED_DIR = "kubeflow_tpu/compat/"
 # dotted api -> (availability window, sanctioned replacement)
 GATED_APIS: Dict[str, Tuple[str, str]] = {
     "jax.shard_map":
-        ("jax>=0.6 (absent from the pinned 0.4.37)",
+        ("top-level since jax 0.6; signature changed with it",
          "kubeflow_tpu.compat.shard_map"),
     "jax.experimental.shard_map.shard_map":
-        ("jax<0.8 only (removed upstream)",
-         "kubeflow_tpu.compat.shard_map"),
+        ("removed upstream", "kubeflow_tpu.compat.shard_map"),
     "jax.sharding.get_abstract_mesh":
         ("jax>=0.5", "kubeflow_tpu.compat.current_mesh"),
     "jax.sharding.use_mesh":
-        ("jax>=0.8 window of the use_mesh/set_mesh rename",
+        ("renamed set_mesh; gone from the installed jax 0.9",
          "kubeflow_tpu.compat.mesh_context"),
     "jax.sharding.set_mesh":
-        ("jax>=0.9 side of the use_mesh/set_mesh rename",
+        ("the jax>=0.9 name of use_mesh",
          "kubeflow_tpu.compat.mesh_context"),
     "jax.lax.pvary":
-        ("jax>=0.6", "kubeflow_tpu.compat.pvary"),
+        ("deprecated in jax 0.9 for pcast", "kubeflow_tpu.compat.pvary"),
     "jax.lax.pcast":
         ("jax>=0.7", "kubeflow_tpu.compat.pvary"),
     "jax.lax.axis_size":
@@ -65,8 +64,7 @@ GATED_APIS: Dict[str, Tuple[str, str]] = {
 # gated import roots: importing the module at all is version-sensitive
 GATED_MODULES: Dict[str, Tuple[str, str]] = {
     "jax.experimental.shard_map":
-        ("jax<0.8 only (removed upstream)",
-         "kubeflow_tpu.compat.shard_map"),
+        ("removed upstream", "kubeflow_tpu.compat.shard_map"),
 }
 
 
@@ -82,8 +80,8 @@ class VersionGateChecker(Checker):
             module, node,
             f"{api} is version-gated ({window}); only compat/ may "
             "touch version-sensitive jax APIs",
-            hint=f"call {use} instead — the shim spans the versions "
-                 "this direct use does not")
+            hint=f"call {use} instead — compat/ is the one module a "
+                 "jax upgrade has to edit")
 
     def check(self, module: ModuleInfo) -> Iterable[Finding]:
         # exact path-component prefix, not a substring: a sibling

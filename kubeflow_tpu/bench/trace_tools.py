@@ -4,9 +4,9 @@ The profiler tier (``kubeflow_tpu/utils/profiler.py``) writes
 TensorBoard-compatible trace dirs (``plugins/profile/<run>/*.trace.json.gz``);
 this reads them back and aggregates device-lane op durations, so a perf
 claim ("backward conv fusions dominate at N ms/step") is reproducible
-from a committed artifact with one command:
+from a captured trace dir with one command:
 
-    ctl trace-top traces/r04/resnet50 [--top 20]
+    ctl trace-top chiprun_out/traces/resnet50 [--top 20]
 
 The reference's closest surface is "open TensorBoard and look"
 (``/root/reference/kubeflow/tensorboard/tensorboard.libsonnet``); a CLI

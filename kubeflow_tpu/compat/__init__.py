@@ -1,29 +1,19 @@
-"""jax version-compat shims — the ONLY sanctioned call site for
-version-gated jax APIs.
+"""The single call site for the jax SPMD surface (``shard_map``, the
+varying-axes cast, ``axis_size``, the ambient mesh and its context
+manager).
 
-The platform targets the current jax surface (``jax.shard_map``,
-``jax.sharding.get_abstract_mesh``, ``jax.lax.pvary`` /
-``jax.lax.axis_size``) while the pinned runtime may ship an older jax
-(the container pins 0.4.37, where ``shard_map`` still lives at
-``jax.experimental.shard_map.shard_map`` with a different signature).
-Code that touches such an API directly only fails on the real runtime —
-exactly the bug class the TPU rebuild warns about, and exactly what bit
-this repo: 4 direct ``jax.shard_map`` call sites killed 22 tier-1 tests
-with an AttributeError the CPU-side type checkers never saw.
-
-Policy (enforced by tpulint rule **TPU006**, see ``docs/COMPAT.md``):
-version-sensitive jax APIs are imported/attributed ONLY inside this
-package; everything else calls the shims re-exported here. Each shim
-resolves the new API lazily (so tests can monkeypatch the new surface
-onto an old jax) and falls back to the semantically-validated old-jax
-translation.
+Everything here targets the installed jax (``pyproject.toml`` pins
+``jax>=0.9``); there are no branches for older releases. The package
+exists because these names moved or were renamed in every jax release
+from 0.5 to 0.9: one module to edit on the next upgrade, enforced by
+tpulint rule **TPU006** (``docs/COMPAT.md``) — the rest of the package
+calls the functions re-exported here.
 """
 
 from kubeflow_tpu.compat.jaxshim import (  # noqa: F401
     axis_size,
     bound_axes,
     current_mesh,
-    has_new_shard_map,
     mesh_context,
     pvary,
     shard_map,
@@ -33,7 +23,6 @@ __all__ = [
     "axis_size",
     "bound_axes",
     "current_mesh",
-    "has_new_shard_map",
     "mesh_context",
     "pvary",
     "shard_map",

@@ -1,27 +1,13 @@
-"""Version-gated jax API shims (shard_map and friends).
+"""The jax surface the platform's SPMD code stands on, in one place.
 
-Every function here resolves the *new* jax surface lazily via
-``getattr`` — never at import time — so (a) importing this module never
-crashes on an old jax, and (b) tests can monkeypatch a stand-in for the
-new API onto an old runtime and assert kwargs pass through untranslated.
-
-The legacy (jax<0.6) translations were validated empirically against
-the pinned jax 0.4.37 on the virtual CPU mesh; the non-obvious findings
-are recorded next to the code they forced, because they are invisible
-from the API docs:
-
-- eager partial-manual ``shard_map`` (nonempty ``auto``) raises
-  ``NotImplementedError`` outright;
-- jitted partial-manual bodies hard-ABORT the process (C++ CHECK
-  failures in the XLA SPMD partitioner) on anything beyond ``psum`` —
-  ``ppermute``, ``all_to_all``, and ``with_sharding_constraint`` all
-  die — so the textbook ``axis_names=…`` → ``auto=mesh-axes-minus``
-  migration recipe is unusable at 0.4.x and :func:`shard_map` degrades
-  partial-manual regions to full-manual instead (exact whenever the
-  specs shard only over the manual axes, which is asserted);
-- ``jax.lax.axis_index`` inside a partial-manual body lowers to a
-  ``PartitionId`` HLO the partitioner rejects; under full-manual it is
-  fine, which is the other reason the degrade path is full-manual.
+Written against the installed jax (0.9): ``jax.shard_map`` with
+``axis_names``/``check_vma``, the varying-manual-axes type system
+(``jax.lax.pcast``), ``jax.lax.axis_size``, the abstract-mesh accessor
+and the ``set_mesh`` context manager. There is no branch for an older
+jax — ``pyproject.toml`` pins ``jax>=0.9``. What this module still buys
+is a single call site: these APIs moved or were renamed in every jax
+release from 0.5 to 0.9, and tpulint TPU006 keeps the rest of the
+package calling them through here.
 """
 
 from __future__ import annotations
@@ -30,185 +16,59 @@ from typing import Iterable, Optional, Sequence, Set
 
 import jax
 
-# ---------------------------------------------------------------------------
-# shard_map
-# ---------------------------------------------------------------------------
-
-
-def _new_shard_map():
-    """The jax>=0.6 top-level ``jax.shard_map``, or None on older jax.
-
-    Resolved per call (not at import) so tests can monkeypatch
-    ``jax.shard_map`` onto an old runtime; ``getattr`` with a default
-    swallows the AttributeError jax's deprecation module-getattr raises.
-    """
-    return getattr(jax, "shard_map", None)
-
-
-def has_new_shard_map() -> bool:
-    return _new_shard_map() is not None
-
-
-def _spec_axis_names(specs) -> Set[str]:
-    """Every mesh-axis name a (possibly nested) spec structure shards
-    over. PartitionSpec entries are names, tuples of names, or None."""
-    out: Set[str] = set()
-
-    def visit(obj) -> None:
-        if obj is None:
-            return
-        if isinstance(obj, str):
-            out.add(obj)
-        elif isinstance(obj, jax.sharding.PartitionSpec):
-            for entry in obj:
-                visit(entry)
-        elif isinstance(obj, (tuple, list)):
-            for item in obj:
-                visit(item)
-        elif isinstance(obj, dict):
-            for item in obj.values():
-                visit(item)
-
-    visit(specs)
-    return out
-
 
 def shard_map(f, *, mesh, in_specs, out_specs,
               axis_names: Optional[Iterable[str]] = None,
               check_vma: bool = True):
-    """``jax.shard_map`` across jax versions.
-
-    New jax (>=0.6): passes straight through — ``axis_names`` (when
-    given) and ``check_vma`` are forwarded untranslated.
-
-    Old jax: translates to ``jax.experimental.shard_map.shard_map``
-    with ``check_rep=False`` regardless of ``check_vma``: check_rep is
-    the vma checker's buggier ancestor and falsely rejects valid
-    programs this platform relies on — differentiating through
-    ``lax.cond`` (the ring-attention causal skip) dies with "branches
-    of cond produced mismatched replication types, please open an
-    issue". The vma discipline still gates on any runtime that has the
-    real checker. A partial-manual request
-    (``axis_names`` ⊂ mesh axes) is degraded to full-manual rather than
-    translated to ``auto=frozenset(mesh.axis_names) - axis_names``: on
-    the pinned 0.4.x, partial-manual bodies hard-abort XLA on any
-    collective beyond psum (see module docstring). Degrading is exact
-    as long as no in/out spec shards over an axis outside
-    ``axis_names`` — axes the specs never name see replicated data
-    either way — and that precondition is checked here, loudly.
-    """
-    new = _new_shard_map()
-    if new is not None:
-        kwargs = {}
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        return new(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_vma=check_vma, **kwargs)
-
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
+    """``jax.shard_map``; ``axis_names=None`` means manual over every
+    mesh axis (jax's own default), a subset makes the region
+    partial-manual with the other axes left to the partitioner."""
+    kwargs = {}
     if axis_names is not None:
-        manual = frozenset(axis_names)
-        auto = frozenset(mesh.axis_names) - manual
-        leaked = (_spec_axis_names(in_specs)
-                  | _spec_axis_names(out_specs)) & auto
-        if leaked:
-            raise NotImplementedError(
-                f"legacy shard_map fallback cannot run manual-over-"
-                f"{sorted(manual)} with specs sharding over auto axes "
-                f"{sorted(leaked)}: jax {jax.__version__}'s partial-"
-                f"manual lowering aborts on collectives, so this shim "
-                f"degrades to full-manual, which is only exact when "
-                f"the specs stay inside the manual axes")
-    return _legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False)
+        kwargs["axis_names"] = set(axis_names)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kwargs)
 
 
-# ---------------------------------------------------------------------------
-# named-axis helpers
-# ---------------------------------------------------------------------------
-
-
-def axis_size(axis_name: str):
-    """``jax.lax.axis_size`` (jax>=0.5) or the classic ``psum(1, axis)``
-    idiom, which constant-folds to a Python int inside manual regions —
-    callers rely on that to build static ``ppermute`` permutations."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
+def axis_size(axis_name: str) -> int:
+    """Size of a bound named axis — a Python int, so callers can build
+    static ``ppermute`` permutations from it."""
+    return jax.lax.axis_size(axis_name)
 
 
 def pvary(x, axis_names: Sequence[str]):
     """Type ``x`` as varying over ``axis_names`` for the shard_map vma
-    checker. Old jax has no varying-axes type system, so this is the
-    identity there — the value is already per-device."""
-    fn = getattr(jax.lax, "pvary", None)
-    if fn is not None:
-        return fn(x, tuple(axis_names))
-    fn = getattr(jax.lax, "pcast", None)
-    if fn is not None:
-        return fn(x, tuple(axis_names), to="varying")
-    return x
+    checker (scan carries initialised from replicated values need it).
+    Axes ``x`` already varies over are skipped: ``pcast`` refuses to
+    re-vary them."""
+    have = getattr(jax.typeof(x), "vma", frozenset())
+    need = tuple(a for a in axis_names if a not in have)
+    if not need:
+        return x
+    return jax.lax.pcast(x, need, to="varying")
 
 
 def bound_axes(axis_names: Iterable[str]) -> Set[str]:
     """Which of ``axis_names`` are bound as named axes at the current
-    trace point (i.e. we are inside a shard_map/pmap manual region over
-    them). Probed with ``psum(1, name)`` — a concrete reduction that
-    constant-folds when the axis is bound and raises when it is not —
-    because old jax exposes no public axis-env accessor at all."""
+    trace point (i.e. we are inside a shard_map manual region over
+    them)."""
     out: Set[str] = set()
     for name in axis_names:
         try:
-            jax.lax.psum(1, name)
-        except Exception:
+            jax.lax.axis_size(name)
+        except NameError:
             continue
         out.add(name)
     return out
 
 
-# ---------------------------------------------------------------------------
-# current mesh / mesh context
-# ---------------------------------------------------------------------------
-
-
-class _NoMesh:
-    """Stand-in with the two attributes callers probe, for runtimes
-    where neither the abstract-mesh API nor thread resources exist."""
-
-    empty = True
-    axis_names = ()
-
-
-_NO_MESH = _NoMesh()
-
-
 def current_mesh():
-    """The ambient mesh: ``jax.sharding.get_abstract_mesh()`` on new
-    jax; on jax<0.5 the physical mesh entered via ``with mesh:``, which
-    lives in the pxla thread resources. Always returns an object with
-    ``.empty`` and ``.axis_names`` (possibly the empty stand-in)."""
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is not None:
-        return fn()
-    try:
-        from jax.interpreters import pxla
-
-        return pxla.thread_resources.env.physical_mesh
-    except (ImportError, AttributeError):
-        return _NO_MESH
+    """The ambient (abstract) mesh; ``.empty`` outside any mesh
+    context."""
+    return jax.sharding.get_abstract_mesh()
 
 
 def mesh_context(mesh):
     """Context manager making ``mesh`` current for bare-PartitionSpec
-    sharding constraints; spans the jax 0.8/0.9 use_mesh→set_mesh
-    rename and falls back to ``with mesh:`` (thread resources) on old
-    jax, where Mesh itself is the context manager."""
-    fn = getattr(jax.sharding, "use_mesh", None)
-    if fn is not None:
-        return fn(mesh)
-    fn = getattr(jax.sharding, "set_mesh", None)
-    if fn is not None:
-        return fn(mesh)
-    return mesh
+    sharding constraints."""
+    return jax.sharding.set_mesh(mesh)

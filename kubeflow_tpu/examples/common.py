@@ -66,6 +66,9 @@ def launcher_init(
     setup_logging()
     penv = dist.initialize()
     from kubeflow_tpu.parallel.mesh import auto_mesh_config
+    from kubeflow_tpu.utils.compile_cache import enable_compile_cache
+
+    cache_dir = enable_compile_cache()
 
     if penv.is_multislice:
         per_slice = jax.device_count() // penv.num_slices
@@ -77,9 +80,11 @@ def launcher_init(
         config = auto_mesh_config(jax.device_count(), pp=pp, tp=tp)
         mesh = create_mesh(config)
     logging.info(
-        "launcher up: rank %d/%d, %d devices, mesh dcn=%d dp=%d pp=%d tp=%d",
+        "launcher up: rank %d/%d, %d %s devices (%s), mesh dcn=%d dp=%d "
+        "pp=%d tp=%d, compile cache %s",
         penv.process_id, penv.num_processes, jax.device_count(),
-        config.dcn, config.dp, config.pp, config.tp,
+        jax.default_backend(), jax.devices()[0].device_kind,
+        config.dcn, config.dp, config.pp, config.tp, cache_dir,
     )
     return penv, mesh
 

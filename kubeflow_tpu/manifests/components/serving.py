@@ -120,6 +120,10 @@ def render(config: DeploymentConfig, params: Dict[str, Any]) -> List[o.Obj]:
         "KFTPU_MAX_BATCH_SIZE": str(params["max_batch_size"]),
         "KFTPU_DECODE_SLOTS": str(params["decode_slots"]),
         "KFTPU_DECODE_STEPS_PER_SYNC": str(params["decode_steps_per_sync"]),
+        # the compile cache rides the model volume, so a restarted pod
+        # finds the executables its predecessor compiled
+        "JAX_COMPILATION_CACHE_DIR":
+            params["model_base_path"].rstrip("/") + "/.xla-compile-cache",
         **({"KFTPU_SERVING_MESH": params["serving_mesh"]}
            if params["serving_mesh"] else {}),
     }

@@ -36,6 +36,7 @@ from kubeflow_tpu.parallel.mesh import (
     DEFAULT_RULES,
     logical_to_mesh_axes,
     shard_constraint,
+    shard_kernel,
 )
 
 
@@ -409,9 +410,17 @@ class Attention(nn.Module):
                 paged_decode_attention,
             )
 
-            out = paged_decode_attention(
-                q[:, 0], ck.value, cv.value, pages, pos,
-                sm_scale=Dh ** -0.5, head_block=c.paged_head_block)
+            # on a serving mesh each tp rank runs the kernel over its own
+            # q-head group and the matching kv heads of the pool
+            pool = (None, None, "heads", None)
+            out = shard_kernel(
+                "paged_attn",
+                lambda q1, kp, vp, pg, ps_: paged_decode_attention(
+                    q1, kp, vp, pg, ps_, sm_scale=Dh ** -0.5,
+                    head_block=c.paged_head_block),
+                (q[:, 0], ck.value, cv.value, pages, pos),
+                ((None, "heads", None), pool, pool, (None, None), (None,)),
+                q[:, 0].shape, (None, "heads", None), c.rules)
             return out[:, None]
 
         # gather each row's logical view: (B, n_log, ps, KH, Dh) ->
@@ -481,8 +490,17 @@ class Attention(nn.Module):
                   if c.attention_block_q else None)
             bk = (autotune.fit_block(S, c.attention_block_k)
                   if c.attention_block_k else None)
-            return att.flash_attention(q, k, v, c.causal, bq, bk, None,
-                                       None, kv_len)
+            # on a mesh each device runs the kernels over its own batch
+            # rows and heads (attention is independent along both)
+            bshd = ("batch", None, "heads", None)
+            lens = () if kv_len is None else (kv_len,)
+            return shard_kernel(
+                "flash_attention",
+                lambda q, k, v, *lens: att.flash_attention(
+                    q, k, v, c.causal, bq, bk, None, None, *lens),
+                (q, k, v, *lens),
+                (bshd,) * 3 + (("batch",),) * len(lens), q.shape, bshd,
+                c.rules)
         # ring / ulysses: sequence-parallel over the seq mesh axis;
         # partial-manual shard_map (batch/other axes stay auto)
         from kubeflow_tpu import compat

@@ -202,10 +202,11 @@ def _flash_fwd_kernel(*refs, block_q: int, block_k: int,
     recompute probabilities from it without a second online-softmax
     pass.
 
-    ``masked`` (static) adds a per-row valid-length input (SMEM scalar
-    per fused batch·head row) whose padding mask composes with the
-    causal one; the unmasked argument list is byte-identical to the
-    pre-mask kernel.
+    ``masked`` (static) adds the per-row valid lengths (one int32 per
+    fused batch·head row, the whole vector SMEM-resident and indexed by
+    the grid's row id) whose padding mask composes with the causal one;
+    the unmasked argument list is byte-identical to the pre-mask
+    kernel.
     """
     import jax.experimental.pallas as pl  # deferred: test envs without pallas
 
@@ -218,6 +219,7 @@ def _flash_fwd_kernel(*refs, block_q: int, block_k: int,
 
     i = pl.program_id(1)  # q-block index
     j = pl.program_id(2)  # kv-block index
+    limit = len_ref[pl.program_id(0)] if masked else None
 
     @pl.when(j == 0)
     def _init():
@@ -240,7 +242,7 @@ def _flash_fwd_kernel(*refs, block_q: int, block_k: int,
         if causal:
             s = _causal_block_mask(s, i, j, block_q, block_k)
         if masked:
-            s = _pad_mask(s, len_ref[0, 0], j, block_k)
+            s = _pad_mask(s, limit, j, block_k)
         m = m_ref[...]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -290,17 +292,42 @@ def _causal_clamp_q(block_q: int, block_k: int, causal: bool):
         b, jnp.maximum(i, _first_live_q(j, block_q, block_k)), 0)
 
 
+# Per-row control vectors (these kernels' kv lengths, the fused
+# sampler's temperature/top-k/top-p) ride SMEM whole: 4 bytes an entry
+# for the kernel's life, exactly what a scalar-prefetch operand costs.
+# SMEM is small and the CPU interpreter has no such limit, so the size
+# is held to what a chip run has compiled (v5e, PR 21: this many
+# entries, both kernels) — past it the call fails here, by name, not in
+# Mosaic's allocator. Per device: under a mesh the kernels see their
+# shard_kernel shard.
+MAX_SMEM_CONTROL_ENTRIES = 4096
+
+
+def check_smem_entries(n: int, what: str) -> None:
+    if n > MAX_SMEM_CONTROL_ENTRIES:
+        raise ValueError(
+            f"{what}: {n} per-row control entries would ride SMEM whole; "
+            f"the largest compiled on a chip is {MAX_SMEM_CONTROL_ENTRIES}"
+            " — split the batch over more devices, or raise "
+            "ops/attention.py:MAX_SMEM_CONTROL_ENTRIES after a chip run")
+
+
 def _fused_lens(kv_len, H: int):
-    """(B,) per-row valid lengths → (B·H, 1) int32 aligned with the
+    """(B,) per-row valid lengths → (B·H,) int32 aligned with the
     kernels' fused batch·head grid axis."""
-    return jnp.repeat(kv_len.astype(jnp.int32), H)[:, None]
+    check_smem_entries(kv_len.shape[0] * H,
+                       "flash_attention kv_len (batch × heads)")
+    return jnp.repeat(kv_len.astype(jnp.int32), H)
 
 
 def _len_spec(pl, pltpu):
-    """One per-row length scalar per grid step, SMEM-resident (control
-    values, not vector data)."""
-    return pl.BlockSpec((1, 1), lambda b, i, j: (b, 0),
-                        memory_space=pltpu.SMEM)
+    """The whole length vector, SMEM-resident for every grid step
+    (control values, not vector data; size held by
+    :func:`check_smem_entries`); kernels index it with the grid's row
+    id. Not a ``(1, 1)`` block per step: Mosaic holds SMEM blocks to the
+    same last-two-dims rule as VMEM ones and refuses it (v5e, CHANGES.md
+    PR 21)."""
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _flash_fwd(q, k, v, *, causal: bool, block_q: Optional[int],
@@ -385,6 +412,7 @@ def _flash_bwd_dq_kernel(*refs, block_q: int, block_k: int,
 
     i = pl.program_id(1)
     j = pl.program_id(2)
+    limit = len_ref[pl.program_id(0)] if masked else None
 
     @pl.when(j == 0)
     def _init():
@@ -405,7 +433,7 @@ def _flash_bwd_dq_kernel(*refs, block_q: int, block_k: int,
         if causal:
             s = _causal_block_mask(s, i, j, block_q, block_k)
         if masked:
-            s = _pad_mask(s, len_ref[0, 0], j, block_k)
+            s = _pad_mask(s, limit, j, block_k)
         p = jnp.exp(s - lse)
         dp = jax.lax.dot_general(g, vb, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
@@ -439,6 +467,7 @@ def _flash_bwd_dkv_kernel(*refs, block_q: int,
 
     j = pl.program_id(1)  # kv-block index
     i = pl.program_id(2)  # q-block index
+    limit = len_ref[pl.program_id(0)] if masked else None
 
     @pl.when(i == 0)
     def _init():
@@ -460,7 +489,7 @@ def _flash_bwd_dkv_kernel(*refs, block_q: int,
         if causal:
             s = _causal_block_mask(s, i, j, block_q, block_k)
         if masked:
-            s = _pad_mask(s, len_ref[0, 0], j, block_k)
+            s = _pad_mask(s, limit, j, block_k)
         p = jnp.exp(s - lse)  # (block_q, block_k)
         dv_acc[...] = dv_acc[...] + jax.lax.dot_general(
             p, g, (((0,), (0,)), ((), ())),
@@ -555,8 +584,7 @@ def _flash_bwd(q, k, v, o, lse, g, *, causal: bool,
     ]
     if masked:
         inputs.append(lens)
-        in_specs.append(pl.BlockSpec((1, 1), lambda b, j, i: (b, 0),
-                                     memory_space=pltpu.SMEM))
+        in_specs.append(_len_spec(pl, pltpu))
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, block_q=bq_kv,
                           block_k=bk_kv, scale=scale, causal=causal,
@@ -580,8 +608,16 @@ def _flash_bwd(q, k, v, o, lse, g, *, causal: bool,
     return unfuse(dq), unfuse(dk), unfuse(dv)
 
 
-def _resolve_interpret(interpret: Optional[bool]) -> bool:
-    return (jax.default_backend() != "tpu") if interpret is None else interpret
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """The one interpret-mode decision for every Pallas kernel in
+    ``ops/``: an explicit value wins; ``None`` compiles on the TPU
+    backend and runs the Pallas interpreter elsewhere (so CPU tests
+    execute the real kernel bodies). ``chip_smoke.py`` asserts this is
+    False on the chip — a kernel must never reach the device
+    interpreted."""
+    if interpret is not None:
+        return bool(interpret)
+    return jax.default_backend() != "tpu"
 
 
 @functools.partial(
@@ -620,7 +656,7 @@ def flash_attention(q, k, v, causal: bool = True,
     """
     out, _ = _flash_fwd(q, k, v, causal=causal, block_q=block_q,
                         block_k=block_k, sm_scale=sm_scale,
-                        interpret=_resolve_interpret(interpret),
+                        interpret=resolve_interpret(interpret),
                         kv_len=kv_len)
     return out
 
@@ -629,7 +665,7 @@ def _flash_vjp_fwd(q, k, v, causal, block_q, block_k, sm_scale, interpret,
                    kv_len=None):
     out, lse = _flash_fwd(q, k, v, causal=causal, block_q=block_q,
                           block_k=block_k, sm_scale=sm_scale,
-                          interpret=_resolve_interpret(interpret),
+                          interpret=resolve_interpret(interpret),
                           kv_len=kv_len)
     return out, (q, k, v, out, lse, kv_len)
 
@@ -639,7 +675,7 @@ def _flash_vjp_bwd(causal, block_q, block_k, sm_scale, interpret, res, g):
     dq, dk, dv = _flash_bwd(q, k, v, out, lse, g, causal=causal,
                             block_q=block_q, block_k=block_k,
                             sm_scale=sm_scale,
-                            interpret=_resolve_interpret(interpret),
+                            interpret=resolve_interpret(interpret),
                             kv_len=kv_len)
     if kv_len is None:
         return dq, dk, dv, None

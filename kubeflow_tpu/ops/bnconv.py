@@ -29,7 +29,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from kubeflow_tpu.ops.attention import _resolve_interpret
+from kubeflow_tpu.ops.attention import resolve_interpret
 
 
 def _pick_block(dim: int, want: int, floor: int = 8) -> int:
@@ -162,7 +162,7 @@ def _fused_fwd_impl(x, a, b, w, interpret, act_dtype=None):
         out_specs=pl.BlockSpec((bm, bn), lambda m, n, k: (m, n)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=_resolve_interpret(interpret),
+        interpret=resolve_interpret(interpret),
     )(x, a.astype(jnp.float32)[None, :], b.astype(jnp.float32)[None, :],
       w)
 
@@ -208,7 +208,7 @@ def _fused_vjp_bwd(interpret, act_dtype, res, dz):
             out_specs=pl.BlockSpec((bk, bn), lambda k, n, m: (k, n)),
             out_shape=jax.ShapeDtypeStruct((K, N), jnp.float32),
             scratch_shapes=[pltpu.VMEM((bk, bn), jnp.float32)],
-            interpret=_resolve_interpret(interpret),
+            interpret=resolve_interpret(interpret),
         )(x, a.astype(jnp.float32)[None, :],
           b.astype(jnp.float32)[None, :], dz)
         dw = dw.astype(w.dtype)

@@ -57,13 +57,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from kubeflow_tpu.ops.attention import NEG_INF
+from kubeflow_tpu.ops.attention import NEG_INF, resolve_interpret
 from kubeflow_tpu.ops.autotune import resolve_paged
-
-
-def _resolve_interpret(interpret: Optional[bool]) -> bool:
-    return (jax.default_backend() != "tpu") if interpret is None else bool(
-        interpret)
 
 
 def _paged_decode_kernel(pages_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
@@ -260,5 +255,5 @@ def paged_decode_attention(q, k_pages, v_pages, pages, positions, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, QH, Dh), q.dtype),
-        interpret=_resolve_interpret(interpret),
+        interpret=resolve_interpret(interpret),
     )(pages, positions, q, k_pages, v_pages)

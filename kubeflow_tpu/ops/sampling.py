@@ -1,6 +1,6 @@
 """Fused temperature → top-k → top-p → sample kernel for the decode engine.
 
-The serving sampler problem (BENCH_r05): EXACT top-k/top-p sampling via
+The serving sampler problem: EXACT top-k/top-p sampling via
 :func:`kubeflow_tpu.models.decode.sample_logits`'s sort path pays a full
 (B, V) descending vocab sort per decode step — at engine batch 32 that
 is 32 vocab sorts per token, a ~2.4× throughput tax against the
@@ -34,10 +34,17 @@ sort with its (B, V) sorted materialization:
 Like every sampler change, switching the engine to the fused path draws
 different (identically distributed) streams for the same seed.
 
-Tile legality (TPU001): blocks are ``(1, Vp)`` with the vocab padded to
-a multiple of 128 lanes, and ``(1, 1)`` for per-row scalars/outputs —
-size-1 dims are relayout-legal. ``interpret=None`` auto-selects the
-Pallas interpreter off-TPU, so CPU tests run the same kernel.
+Tile legality (TPU001): each row's padded vocab is folded to a dense
+``(Vp/128, 128)`` tile stack — full sublanes instead of one sublane of
+a ``(1, Vp)`` row — and blocked ``(1, Vp/128, 128)``, whose last two
+dims equal the array's. The original ``(1, Vp)`` block over ``(B, Vp)``
+and the ``(1, 1)`` per-row scalar blocks were refused by Mosaic on the
+v5e (a size-1 second-to-last block dim is only legal when the array's
+is 1 too); per-row scalars now ride SMEM whole, and the token leaves
+as a lane-broadcast ``(1, 1, 128)`` block. Every reduction runs in f32
+(counts and indices stay exact below 2**24). ``interpret=None``
+auto-selects the Pallas interpreter off-TPU, so CPU tests run the same
+kernel.
 """
 
 from __future__ import annotations
@@ -47,17 +54,16 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from kubeflow_tpu.ops.attention import NEG_INF
+from kubeflow_tpu.ops.attention import (
+    NEG_INF,
+    check_smem_entries,
+    resolve_interpret,
+)
 
 LANE = 128
 _SEARCH_ITERS = 32  # one per int32 bit: exact convergence
 _INT_MIN = -(2 ** 31)
 _INT_MAX = 2 ** 31 - 1
-
-
-def _resolve_interpret(interpret: Optional[bool]) -> bool:
-    return (jax.default_backend() != "tpu") if interpret is None else bool(
-        interpret)
 
 
 def _ordered_bits(x: jnp.ndarray) -> jnp.ndarray:
@@ -74,28 +80,34 @@ def _mid(lo: jnp.ndarray, hi: jnp.ndarray) -> jnp.ndarray:
     return (lo >> 1) + (hi >> 1) + (lo & hi & 1)
 
 
-def _fused_sample_kernel(logits_ref, gumbel_ref, temp_ref, k_ref, p_ref,
+def _fused_sample_kernel(temp_ref, k_ref, p_ref, logits_ref, gumbel_ref,
                          out_ref, *, V: int):
-    """One grid row: exact filtered sampling over a (1, Vp) block."""
+    """One grid row: exact filtered sampling over a (Vp/128, 128) tile
+    stack holding the row's padded vocab in row-major order."""
+    import jax.experimental.pallas as pl  # deferred: envs without pallas
+
+    b = pl.program_id(0)
+    temp = temp_ref[b]
+    k = k_ref[b]
+    p = p_ref[b]
     neg = jnp.float32(NEG_INF)
-    valid = jax.lax.broadcasted_iota(
-        jnp.int32, logits_ref.shape, 1) < V
-    logits = jnp.where(valid, logits_ref[...].astype(jnp.float32), neg)
-    temp = temp_ref[0, 0]
-    k = k_ref[0, 0]
-    p = p_ref[0, 0]
+    shape = logits_ref.shape[1:]
+    token = (jax.lax.broadcasted_iota(jnp.int32, shape, 0) * LANE
+             + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+    valid = token < V
+    logits = jnp.where(valid, logits_ref[0].astype(jnp.float32), neg)
     greedy = temp <= 0.0
     scaled = jnp.where(valid,
                        logits / jnp.where(greedy, 1.0, temp), neg)
     ordered = _ordered_bits(scaled)
 
     # -- top-k: largest t with count(ordered >= t) >= k_eff -----------------
-    k_eff = jnp.where(k <= 0, V, jnp.minimum(k, V))
+    k_eff = jnp.where(k <= 0, V, jnp.minimum(k, V)).astype(jnp.float32)
 
     def k_step(_, carry):
         lo, hi = carry
         mid = _mid(lo, hi)
-        cnt = jnp.sum((valid & (ordered >= mid)).astype(jnp.int32))
+        cnt = jnp.sum(jnp.where(valid & (ordered >= mid), 1.0, 0.0))
         ge = cnt >= k_eff
         return jnp.where(ge, mid, lo), jnp.where(ge, hi, mid)
 
@@ -123,19 +135,23 @@ def _fused_sample_kernel(logits_ref, gumbel_ref, temp_ref, k_ref, p_ref,
     _, t0 = jax.lax.fori_loop(
         0, _SEARCH_ITERS, p_step,
         (jnp.int32(_INT_MIN), jnp.int32(_INT_MAX)))
-    p_thresh = jnp.min(jnp.where(kmask & (ordered >= t0), ordered,
-                                 jnp.int32(_INT_MAX)))
-    pmask = kmask & (ordered >= p_thresh)
-    mask = jnp.where(p >= 1.0, kmask, pmask)
+    # smallest kept value, found in the float domain (``ordered`` is
+    # monotone in ``scaled``, so the two thresholds keep the same set)
+    p_thresh = jnp.min(jnp.where(kmask & (ordered >= t0), scaled,
+                                 jnp.float32(jnp.inf)))
+    # p >= 1 switches the filter off through the (scalar) threshold:
+    # Mosaic cannot select between two mask vectors
+    mask = kmask & (scaled >= jnp.where(p >= 1.0, neg, p_thresh))
 
     # -- Gumbel-max sample (exact categorical over the masked support) ------
     # argmax as max+min-index (first occurrence, matching jnp.argmax's
-    # tie-break bitwise): plain reductions lower on every Mosaic version
-    iota = jax.lax.broadcasted_iota(jnp.int32, logits_ref.shape, 1)
-    score = jnp.where(mask, scaled + gumbel_ref[...], neg)
-    sampled = jnp.min(jnp.where(score >= jnp.max(score), iota, V))
-    top = jnp.min(jnp.where(logits >= jnp.max(logits), iota, V))
-    out_ref[0, 0] = jnp.where(greedy, top, sampled).astype(jnp.int32)
+    # tie-break bitwise), the index reduced in f32
+    idx = token.astype(jnp.float32)
+    score = jnp.where(mask, scaled + gumbel_ref[0], neg)
+    sampled = jnp.min(jnp.where(score >= jnp.max(score), idx, float(V)))
+    top = jnp.min(jnp.where(logits >= jnp.max(logits), idx, float(V)))
+    out_ref[0] = jnp.full(out_ref.shape[1:],
+                          jnp.where(greedy, top, sampled), jnp.int32)
 
 
 def fused_sample(logits: jnp.ndarray, keys, *, temperature=1.0,
@@ -152,40 +168,40 @@ def fused_sample(logits: jnp.ndarray, keys, *, temperature=1.0,
     co-tenants (the engine's fold_in contract).
     """
     B, V = logits.shape
-    temp = jnp.broadcast_to(jnp.asarray(temperature, jnp.float32),
-                            (B,)).reshape(B, 1)
-    k = jnp.broadcast_to(jnp.asarray(top_k, jnp.int32),
-                         (B,)).reshape(B, 1)
-    p = jnp.broadcast_to(jnp.asarray(top_p, jnp.float32),
-                         (B,)).reshape(B, 1)
+    check_smem_entries(B, "fused_sample rows")  # three (B,) SMEM scalars
+    temp = jnp.broadcast_to(jnp.asarray(temperature, jnp.float32), (B,))
+    k = jnp.broadcast_to(jnp.asarray(top_k, jnp.int32), (B,))
+    p = jnp.broadcast_to(jnp.asarray(top_p, jnp.float32), (B,))
     # per-row Gumbel noise outside the kernel (XLA fuses the PRNG); the
     # kernel's argmax over scaled+gumbel is then exact categorical
     u = jax.vmap(lambda kk: jax.random.uniform(
         kk, (V,), jnp.float32, minval=1e-20, maxval=1.0))(keys)
     g = -jnp.log(-jnp.log(u))
 
-    Vp = -(-V // LANE) * LANE
+    # pad the vocab to whole (8, 128) f32 tiles and fold each row
+    tile = 8 * LANE
+    Vp = -(-V // tile) * tile
     if Vp != V:
         pad = ((0, 0), (0, Vp - V))
         logits = jnp.pad(logits, pad)
         g = jnp.pad(g, pad)
+    R = Vp // LANE
+    logits = logits.reshape(B, R, LANE)
+    g = g.reshape(B, R, LANE)
 
     import functools
 
     import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
+    row = pl.BlockSpec((1, R, LANE), lambda b: (b, 0, 0))
+    scalars = pl.BlockSpec(memory_space=pltpu.SMEM)
     out = pl.pallas_call(
         functools.partial(_fused_sample_kernel, V=V),
         grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, Vp), lambda b: (b, 0)),
-            pl.BlockSpec((1, Vp), lambda b: (b, 0)),
-            pl.BlockSpec((1, 1), lambda b: (b, 0)),
-            pl.BlockSpec((1, 1), lambda b: (b, 0)),
-            pl.BlockSpec((1, 1), lambda b: (b, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda b: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, 1), jnp.int32),
-        interpret=_resolve_interpret(interpret),
-    )(logits, g, temp, k, p)
-    return out[:, 0]
+        in_specs=[scalars, scalars, scalars, row, row],
+        out_specs=pl.BlockSpec((1, 1, LANE), lambda b: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, 1, LANE), jnp.int32),
+        interpret=resolve_interpret(interpret),
+    )(temp, k, p, logits, g)
+    return out[:, 0, 0]

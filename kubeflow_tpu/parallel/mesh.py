@@ -32,14 +32,28 @@ table so a model is written once and resharded by swapping rules.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
-from typing import Optional, Sequence, Tuple, Union
+import json
+import logging
+from typing import (
+    Any,
+    Dict,
+    Iterator,
+    List,
+    Optional,
+    Sequence,
+    Tuple,
+    Union,
+)
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from kubeflow_tpu import compat
+
+log = logging.getLogger(__name__)
 
 MESH_AXES = ("dcn", "dp", "pp", "tp")
 
@@ -229,10 +243,9 @@ def shard_constraint(x, logical_axes, rules: AxisRules = DEFAULT_RULES):
     Axis names the current mesh lacks are dropped (see
     :func:`spec_for_mesh`), as are axes that are *manual* at the current
     trace point: inside a shard_map region a manual axis is already a
-    per-device dim, so a constraint over it is meaningless — and on
-    jax<0.5 it aborts the XLA partitioner outright. A fully-manual
-    region (every mesh axis bound, the legacy-shard_map shape) skips
-    the constraint entirely.
+    per-device dim, so a constraint over it is meaningless. A
+    fully-manual region (every mesh axis bound) skips the constraint
+    entirely.
     """
     spec = logical_to_mesh_axes(logical_axes, rules)
     mesh = compat.current_mesh()
@@ -247,10 +260,111 @@ def shard_constraint(x, logical_axes, rules: AxisRules = DEFAULT_RULES):
     return jax.lax.with_sharding_constraint(x, spec)
 
 
+_PLACEMENT_RECORDERS: List[List[Dict[str, Any]]] = []
+_PLACEMENTS_LOGGED: set = set()
+
+
+@contextlib.contextmanager
+def record_kernel_placements() -> Iterator[List[Dict[str, Any]]]:
+    """Collect how ``shard_kernel`` placed every kernel traced inside
+    the block: one entry per distinct (kernel, placement) — over how
+    many devices, which logical axes split it how many ways, which it
+    named but had to drop. Empty when nothing was traced under a
+    multi-device mesh. The bench rows carry it next to ``tile_config``
+    (``ops/autotune.py:record_resolutions`` is the same shape), and
+    chip_smoke.py asserts on it."""
+    buf: List[Dict[str, Any]] = []
+    _PLACEMENT_RECORDERS.append(buf)
+    try:
+        yield buf
+    finally:
+        _PLACEMENT_RECORDERS.remove(buf)
+
+
+def shard_kernel(name: str, fn, args, arg_axes, out_shape, out_axes,
+                 rules: AxisRules = DEFAULT_RULES):
+    """Run ``fn(*args)`` — a function whose body is the Pallas (Mosaic)
+    kernel ``name`` — under whatever mesh is current.
+
+    XLA cannot partition a Mosaic kernel. On the TPU backend a
+    ``pallas_call`` traced under a multi-device jit raises ("Mosaic
+    kernels cannot be automatically partitioned. Please wrap the call
+    in a shard_map"), and inside a ``shard_map`` it is accepted only
+    when EVERY mesh axis is manual. The CPU interpreter lowers kernels
+    to plain HLO, which XLA partitions without complaint, so the
+    virtual-device tests never saw this; the four-chip v5e host did
+    (CHANGES.md PR 21). So: with no mesh, one device, or every axis
+    already manual, ``fn`` is called directly; otherwise it runs in a
+    ``shard_map`` that makes every not-yet-manual axis manual, each
+    array split by its logical axes (``arg_axes``/``out_axes``: one
+    tuple of logical names per array; ``out_shape``/``out_axes`` for the
+    single output). A logical axis is applied only if it divides EVERY
+    dim that carries it — q heads split without their kv heads would
+    pair the wrong groups — and is dropped everywhere otherwise: every
+    device then runs the kernel whole along that axis behind an
+    all-gather. That costs, so it is never silent: each distinct
+    placement is logged once (a warning when an axis was dropped) and
+    handed to :func:`record_kernel_placements`. The caller vouches that ``fn``
+    is independent along every split dim; nothing is exchanged between
+    devices.
+    """
+    mesh = compat.current_mesh()
+    if mesh.size <= 1:  # no mesh (size 0) or a single device
+        return fn(*args)
+    free = set(mesh.axis_names) - compat.bound_axes(mesh.axis_names)
+    if not free:
+        return fn(*args)
+    table = dict(rules)
+
+    def width(name: str) -> int:
+        entry = table.get(name) or ()
+        entry = (entry,) if isinstance(entry, str) else entry
+        return int(np.prod([mesh.shape[a] for a in entry if a in free]))
+
+    labelled = list(zip(arg_axes, (a.shape for a in args)))
+    labelled.append((out_axes, tuple(out_shape)))
+    named = {a for axes, _ in labelled for a in axes if a is not None}
+    uneven = {a for axes, shape in labelled for a, dim in zip(axes, shape)
+              if a is not None and dim % width(a)}
+    placement = {
+        "kernel": name,
+        "devices": int(mesh.size),
+        "split": {a: width(a) for a in sorted(named - uneven)
+                  if width(a) > 1},
+        "dropped": sorted(a for a in uneven),
+    }
+    for buf in _PLACEMENT_RECORDERS:
+        if placement not in buf:
+            buf.append(placement)
+    seen = json.dumps(placement, sort_keys=True)
+    if seen not in _PLACEMENTS_LOGGED:
+        _PLACEMENTS_LOGGED.add(seen)
+        if uneven:
+            log.warning(
+                "kernel %s on %d devices: %s cannot split every dim "
+                "carrying it (shapes %s) and is dropped, so every device "
+                "runs the kernel whole along it; split %s", name,
+                mesh.size, {a: width(a) for a in placement["dropped"]},
+                [shape for _, shape in labelled], placement["split"])
+        else:
+            log.info("kernel %s on %d devices: split %s", name, mesh.size,
+                     placement["split"])
+
+    def spec(axes) -> PartitionSpec:
+        kept = [None if a in uneven else a for a in axes]
+        return _filter_spec(
+            spec_for_mesh(logical_to_mesh_axes(kept, rules), mesh),
+            free.__contains__)
+
+    # check_vma off: pallas_call outputs carry no varying-axes type
+    return compat.shard_map(
+        fn, mesh=mesh, in_specs=tuple(spec(a) for a in arg_axes),
+        out_specs=spec(out_axes), axis_names=free, check_vma=False)(*args)
+
+
 def mesh_context(mesh: Mesh):
     """Context manager making ``mesh`` current for bare-PartitionSpec
-    sharding constraints; spans the jax 0.8/0.9 use_mesh→set_mesh rename
-    and the jax<0.5 ``with mesh:`` form (see ``kubeflow_tpu/compat``)."""
+    sharding constraints (``kubeflow_tpu/compat`` owns the jax call)."""
     return compat.mesh_context(mesh)
 
 

@@ -144,6 +144,11 @@ _cow_splits_c = DEFAULT_REGISTRY.counter(
     "copy-on-write splits of shared boundary pages (one device-side "
     "page copy each, in place of a boundary re-prefill)")
 
+_recoveries_c = DEFAULT_REGISTRY.counter(
+    "kftpu_engine_recoveries_total",
+    "engine cache rebuild-and-replay events after a failed donating "
+    "device call (each one is a device fault survived, never routine)")
+
 _END = object()  # per-request stream sentinel
 
 
@@ -424,16 +429,19 @@ class DecodeEngine:
         # every compiled engine program runs under it, and the model's
         # logical-axis constraints shard the KV cache over the same axes
         self.mesh = mesh
-        # decode steps executed on-device per host round-trip: >1 hides
-        # dispatch/transfer latency (the dominant cost when the host is
-        # remote from the chip) at the price of admission/EOS reacting
-        # up to that many tokens late — tokens past a row's EOS or
+        # decode steps executed on-device per host round-trip: >1
+        # amortizes the per-dispatch and readback cost over that many
+        # tokens, at the price of admission/EOS reacting up to that
+        # many tokens late — tokens past a row's EOS or
         # budget are computed and discarded
         self.steps_per_sync = max(1, int(steps_per_sync))
         self.name = name or "model"
         # the NORMALIZED name: every engine series must share one model
         # label value or per-model joins (slots vs pages) find no row
         _slots_g.set(self.slots, model=self.name)
+        # exported at 0 from the start: "never recovered" must be a
+        # readable fact, not an absent series
+        _recoveries_c.inc(0, model=self.name)
         # an obs.xprof.HbmSampler sampled once per admit cycle, so the
         # admission decision's watermark (weights + KV + transient
         # prefill spike) is what kftpu_hbm_bytes{model=...} shows; CPU
@@ -470,11 +478,19 @@ class DecodeEngine:
             int32 tokens out; every parameter is per-row."""
             if impl == "fused":
                 from kubeflow_tpu.ops.sampling import fused_sample
+                from kubeflow_tpu.parallel.mesh import shard_kernel
 
                 keys = jax.vmap(lambda s, i: jax.random.fold_in(
                     jax.random.key(s), i))(seeds, idx)
-                return fused_sample(logits, keys, temperature=temps,
-                                    top_k=tks, top_p=tps)
+                # a row needs its whole vocab: on a serving mesh the
+                # logits are gathered and every device samples every row
+                return shard_kernel(
+                    "fused_sampler",
+                    lambda lg, ky, t, k, p: fused_sample(
+                        lg, ky, temperature=t, top_k=k, top_p=p),
+                    (logits, keys, temps, tks, tps),
+                    ((None, None),) + ((None,),) * 4, temps.shape,
+                    (None,), config.rules)
 
             def one(row_logits, seed, i, t, k, p):
                 key = jax.random.fold_in(jax.random.key(seed), i)
@@ -551,11 +567,8 @@ class DecodeEngine:
 
         def _insert_rows(engine_cache, batch_cache, slot_ids, valid):
             """Insert every valid batch-prefill row into its engine slot
-            in ONE device dispatch (a scan of per-row dynamic updates).
-            Burst admission used to pay one dispatch per member; on
-            high-dispatch-latency transports those per-row launches
-            dominated admission wall time (measured round 5: 48 inserts
-            ≈ 1.4 s of the engine bench's 4.2 s). Pad rows (``valid``
+            in ONE device dispatch (a scan of per-row dynamic updates)
+            instead of one dispatch per member. Pad rows (``valid``
             False) write a slot's current contents back — a no-op."""
 
             def put(big, small, row, slot, ok):
@@ -932,7 +945,8 @@ class DecodeEngine:
         snap = {"active_slots": self.active_count,
                 "pending": self.pending_count,
                 "slots": self.slots,
-                "closed": self.closed}
+                "closed": self.closed,
+                "recoveries": self.recoveries}
         if self.paged:
             snap.update({
                 "paged": True,
@@ -1535,6 +1549,7 @@ class DecodeEngine:
                           "closing engine", where)
             return False
         self.recoveries += 1
+        _recoveries_c.inc(model=self.name)
         log.warning("recovered engine cache after %s failure "
                     "(%d recover(s) left)", where, self._recoveries_left)
         return True
@@ -1753,8 +1768,8 @@ class DecodeEngine:
                     jnp.asarray(lens),
                     jnp.asarray(temps), jnp.asarray(tks),
                     jnp.asarray(tps), jnp.asarray(seeds))
-            # force completion (host transfer — block_until_ready is not
-            # enough on every transport) BEFORE the donating inserts: a
+            # force completion (the host needs the tokens anyway) BEFORE
+            # the donating inserts: a
             # device-side prefill failure must surface while self._cache
             # is still intact, so _admit's row-path fallback retries
             # against a live engine instead of a consumed cache

@@ -20,6 +20,7 @@ from __future__ import annotations
 import json
 import logging
 import os
+import signal
 import threading
 import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -63,6 +64,11 @@ _spec_accepted_tokens = DEFAULT_REGISTRY.counter(
 _spec_rate = DEFAULT_REGISTRY.gauge(
     "kftpu_serving_speculative_last_acceptance_rate",
     "acceptance rate (accepted/proposed) of the last speculative request")
+
+_warmup_failures = DEFAULT_REGISTRY.counter(
+    "kftpu_serving_warmup_failures_total",
+    "model-load warm-ups that raised (the version still serves; its "
+    "first requests pay the compiles)")
 
 _PAD_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128)
 
@@ -306,8 +312,8 @@ def _run_generate_speculative(model, draft, arr, lens_arr, *, max_new,
     rate)."""
     # the FUSED variant: the whole propose-verify loop is one compiled
     # program per (configs, draft_len, max_new, shape bucket) — the
-    # host-loop variant pays a device dispatch per round, which
-    # dominates request latency on remote-transport deployments
+    # host-loop variant pays a device dispatch and a readback per
+    # round, which dominates request latency for a small model
     from kubeflow_tpu.models.decode import speculative_generate_jit
 
     true_len = int(lens_arr.max())
@@ -730,11 +736,15 @@ class ModelRepository:
     def _warmup(self, name: str, loaded: LoadedModel) -> None:
         if not self.warmup_batches:
             return
+        # export the series at 0 so "no warm-up failed" is a readable
+        # fact, not an absent line
+        _warmup_failures.inc(0, model=name)
         t0 = time.perf_counter()
         try:
             n = loaded.warmup(self.warmup_batches)
         except Exception:  # noqa: BLE001 — warmup is best-effort
             log.exception("warmup failed for %s v%d", name, loaded.version)
+            _warmup_failures.inc(model=name)
             return
         if n:
             log.info("warmed %d batch buckets for %s v%d in %.1fs",
@@ -1033,40 +1043,17 @@ def parse_pin_version(raw: Optional[str]) -> Optional[int]:
     return int(digits)
 
 
-def enable_compile_cache(base_path: str) -> None:
-    """Persistent XLA compile cache: version reloads and server restarts
-    reuse compiled executables instead of paying cold XLA compiles
-    (SURVEY §7 hard part (d): serving cold-start)."""
-    cache_dir = os.environ.get(
-        "KFTPU_COMPILE_CACHE_DIR",
-        os.path.join(base_path, ".xla-compile-cache"))
-    if not cache_dir or cache_dir.lower() == "off":
-        return
-    import tempfile
-
-    import jax
-
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
-    except OSError:
-        # model volumes are commonly mounted read-only (tf-serving-style
-        # PVC); fall back to local scratch rather than crashlooping —
-        # restarts lose the cache but version reloads within the pod keep it
-        cache_dir = os.path.join(tempfile.gettempdir(), "kftpu-xla-cache")
-        os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    # serving recompiles are per-bucket and small; cache them all
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    log.info("XLA compile cache at %s", cache_dir)
-
-
 def main() -> None:
     logging.basicConfig(level=logging.INFO)
     base = os.environ.get("KFTPU_MODEL_BASE_PATH", "/models")
     port = int(os.environ.get("KFTPU_REST_PORT", "8500"))
     grpc_port = int(os.environ.get("KFTPU_GRPC_PORT", "9000"))
     max_batch = int(os.environ.get("KFTPU_MAX_BATCH_SIZE", "8"))
-    enable_compile_cache(base)
+    # version reloads and pod restarts reuse compiled executables; the
+    # pod places the cache with JAX_COMPILATION_CACHE_DIR
+    from kubeflow_tpu.utils.compile_cache import enable_compile_cache
+
+    log.info("XLA compile cache at %s", enable_compile_cache())
     server = ModelServer(base, port=port, max_batch_size=max_batch,
                          pin_version=parse_pin_version(
                              os.environ.get("KFTPU_MODEL_VERSION")),
@@ -1093,13 +1080,19 @@ def main() -> None:
         except ImportError as e:
             log.warning("gRPC disabled (grpc not importable: %s); "
                         "serving REST only", e)
+    # serve until the pod ends: SIGTERM (kubelet) or Ctrl-C. Handling
+    # SIGTERM — not dying by it — lets the engines close and the
+    # process leave through interpreter exit, which is what hands the
+    # chip back cleanly to the next process
+    done = threading.Event()
+    signal.signal(signal.SIGTERM, lambda *_: done.set())
     try:
-        while True:  # serve forever; Ctrl-C / SIGTERM end the pod
-            time.sleep(3600)  # tpulint: disable=TPU003,TPU005
+        done.wait()
     except KeyboardInterrupt:
-        server.stop()
-        if grpc_server is not None:
-            grpc_server.stop(grace=1.0)
+        pass
+    server.stop()
+    if grpc_server is not None:
+        grpc_server.stop(grace=1.0)
 
 
 if __name__ == "__main__":

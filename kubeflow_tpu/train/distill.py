@@ -120,8 +120,8 @@ def distill_draft(target_config: TransformerConfig, target_params: Any,
 
     # target params enter as a jit ARGUMENT: closing over them would
     # embed the full frozen target as HLO constants — catastrophic at
-    # real model sizes (a 167M-param target is a ~334 MB program body;
-    # remote-compile transports reject it outright)
+    # real model sizes (a 167M-param target is a ~334 MB program body
+    # to compile, cache and ship)
     # one ad-hoc distillation program per make_draft call, closed over
     # this tx/draft pair — billed by the CompileLedger listener; there
     # is no long-lived runner to hang an AOT handle on

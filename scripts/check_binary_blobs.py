@@ -1,5 +1,5 @@
 #!/usr/bin/env python3
-"""Fail if a large binary is staged for commit (PERF.md artifact policy).
+"""Fail if a large binary is staged for commit.
 
 Raw profiler blobs and similar artifacts belong in artifact storage,
 not git: once committed they grow every clone forever. This check walks
@@ -82,7 +82,8 @@ def main(argv: list[str] | None = None) -> int:
         for path, size in offenders:
             print(f"  {path}  ({size / 1e6:.1f} MB)", file=sys.stderr)
         print("Raw profiler/trace blobs belong in artifact storage "
-              "(see PERF.md 'Trace artifact policy').", file=sys.stderr)
+              "(chiprun_out/ on a chip run), not in git: the driver "
+              "copies three checkouts of this tree.", file=sys.stderr)
         return 1
     return 0
 

@@ -22,7 +22,8 @@
 #      dump), join it against the static jit-site inventory and fail
 #      on recompile storms; silently skipped when absent
 #   2. binary-blob guard (scripts/check_binary_blobs.py): no large
-#      binaries staged for commit (PERF.md trace-artifact policy)
+#      binaries staged for commit (traces and caches are made at run
+#      time and git-ignored; the tracked tree stays small)
 #   3. obs smoke test (tests/test_obs.py): traceparent round-trip, span
 #      propagation proxy->server->engine, /api/traces, histograms
 #      (docs/OBSERVABILITY.md)
@@ -73,7 +74,7 @@
 #      legality) plus a CPU-tier parity smoke running the three flash
 #      kernels and the paged kernel with every committed tile config
 #      against the default-tile oracle — a bad table edit fails here
-#      before a bench round burns chip time (PERF.md "Tile autotune")
+#      before a bench round burns chip time
 #  12. compile/HBM profile smoke (scripts/profile_smoke.py): a live
 #      jax.jit compile lands in the CompileLedger via jax.monitoring
 #      exactly once, timed_compile fingerprints the HLO + records the

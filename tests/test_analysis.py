@@ -80,6 +80,34 @@ def test_tpu001_literal_ok_and_broadcast_dim():
     assert check(TileLegalityChecker(), m) == []
 
 
+def test_tpu001_size_one_dim_walked_by_its_index_map():
+    """The ops/sampling.py bug the v5e refused: a (1, Vp) block over a
+    (B, Vp) array — the index map moving along the size-1 dim says the
+    array's dim is larger than 1."""
+    m = mod("""
+        import jax.experimental.pallas as pl
+        def f():
+            specs = [pl.BlockSpec((1, 32000), lambda b: (b, 0)),
+                     pl.BlockSpec((1, 1), lambda b: (b, 0))]
+    """)
+    f = check(TileLegalityChecker(), m)
+    assert [x.rule for x in f] == ["TPU001"] * 2
+    assert all("sublane block dim 1 is walked" in x.message for x in f)
+
+
+def test_tpu001_size_one_dim_that_is_the_whole_array_dim():
+    """...while a size-1 dim indexed by the constant 0 is the array's
+    whole dim (lse/out blocks), and a named index map says nothing."""
+    m = mod("""
+        import jax.experimental.pallas as pl
+        def f():
+            specs = [pl.BlockSpec((1, 1, 128), lambda b: (b, 0, 0)),
+                     pl.BlockSpec((1, 512, 1), lambda b, i, j: (b, i, 0)),
+                     pl.BlockSpec((1, 1, 128), q_map)]
+    """)
+    assert check(TileLegalityChecker(), m) == []
+
+
 def test_tpu001_sublane_violation():
     m = mod("""
         import jax.experimental.pallas as pl

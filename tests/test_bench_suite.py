@@ -64,12 +64,34 @@ def test_run_all_isolates_failures(monkeypatch):
     assert "bert" not in out  # respected the subset
 
 
+class _FakeDevice:
+    def __init__(self, kind):
+        self.device_kind = kind
+
+
 def test_peak_flops_detection(monkeypatch):
+    # CPU devices → 0.0 (MFU meaningless), never a crash; and no run
+    # may assert its own peak any more
     monkeypatch.setenv("KFTPU_PEAK_TFLOPS", "123.5")
-    assert suite.peak_flops_per_chip() == 123.5e12
-    monkeypatch.delenv("KFTPU_PEAK_TFLOPS")
-    # CPU devices → 0.0 (MFU meaningless), never a crash
     assert suite.peak_flops_per_chip() == 0.0
+    # the string this repo's v5e reports (chip run, CHANGES.md PR 21)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: [_FakeDevice("TPU v5 lite")])
+    assert suite.peak_flops_per_chip() == 197.0e12
+    assert suite._by_device_kind(suite._HBM_GBPS) == 819.0
+
+
+def test_unknown_tpu_device_kind_is_an_error(monkeypatch):
+    """A TPU the peaks table does not know must stop the row, not
+    produce MFU-less (or zero-peak) numbers in silence."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: [_FakeDevice("TPU v9 mystery")])
+    with pytest.raises(LookupError, match="v9 mystery"):
+        suite.peak_flops_per_chip()
+    with pytest.raises(LookupError):
+        suite._mfu(1e12, 1.0, 1)
 
 
 def test_mfu_math():
@@ -107,6 +129,29 @@ def test_decode_engine_config_tiny():
     assert out["paged_prefix_pages_shared"] >= out["paged_prefix_hits"]
 
 
+# the configs no other test here executes, at the tiny shapes the
+# deleted run_cpu_smoke tier used: that tier only ever ran beside a chip
+# round (bench.py), so this is its CPU coverage kept as a test
+_TINY_ARGS = {
+    "resnet50": {"batch_per_chip": 2, "steps": 2, "warmup": 1},
+    "bert": {"batch_per_chip": 1, "seq_len": 128, "steps": 2, "warmup": 1},
+    "decode": {"batch": 2, "prompt_len": 16, "new_tokens": 8,
+               "d_model": 128, "n_layers": 2, "n_heads": 4, "d_ff": 256},
+    "edge_fleet": {"replicas": 3, "prefixes": 2, "repeats": 4,
+                   "page_size": 4, "burst": 12},
+}
+
+
+@pytest.mark.slow  # whole-model XLA compiles on CPU
+@pytest.mark.parametrize("name", sorted(_TINY_ARGS))
+def test_config_runs_at_tiny_shapes_on_cpu(name):
+    row = suite.CONFIGS[name](**_TINY_ARGS[name])
+    assert "error" not in row
+    rates = [v for k, v in row.items()
+             if k.endswith(("_per_sec", "_per_sec_per_chip", "_hit_rate"))]
+    assert rates and all(v > 0 for v in rates), row
+
+
 @pytest.mark.slow  # multi-second XLA compiles; tier-1 runs the fast twin paths
 def test_longcontext_config_on_virtual_mesh():
     # tiny model: the CPU tier checks the path, the chip checks the speed
@@ -118,136 +163,142 @@ def test_longcontext_config_on_virtual_mesh():
     assert out["seq_len"] == 512
 
 
-def test_run_all_isolated_survives_hung_config(monkeypatch, tmp_path):
-    """A config that never returns must time out to an error entry, not
-    hang the bench (the wedged-device-transport contract)."""
-    import json as _json
+def _fake_suite_children(monkeypatch, tmp_path, body):
+    """Stand-in for ``python -m kubeflow_tpu.bench.suite <config>``:
+    every child run_all_isolated starts runs ``body`` instead."""
+    import subprocess as _sp
     import sys
 
     fake = tmp_path / "fake_suite.py"
-    # stand-in for `python -m kubeflow_tpu.bench.suite <config>`
-    fake.write_text(
+    fake.write_text(body)
+    real_run = _sp.run
+
+    def fake_run(cmd, **kw):
+        name = cmd[cmd.index("kubeflow_tpu.bench.suite") + 1]
+        return real_run([sys.executable, str(fake), name], **kw)
+
+    monkeypatch.setattr(_sp, "run", fake_run)
+
+
+def test_run_all_isolated_survives_hung_config(monkeypatch, tmp_path):
+    """A config that never returns times out to an error row, and the
+    configs after it still run (plain timeout; no probing, no skipping
+    of the rest)."""
+    _fake_suite_children(
+        monkeypatch, tmp_path,
         "import sys, time, json\n"
         "name = sys.argv[1]\n"
         "if name == 'mnist':\n"
-        "    print(json.dumps({'mnist': {'images_per_sec': 1.0}}))\n"
-        "else:\n"
-        "    time.sleep(60)\n")
-    import subprocess as _sp
-
-    real_run = _sp.run
-
-    def fake_run(cmd, **kw):
-        cmd = [sys.executable, str(fake), cmd[cmd.index("kubeflow_tpu.bench.suite") + 1]]
-        return real_run(cmd, **kw)
-
-    monkeypatch.setattr(_sp, "run", fake_run)
-    monkeypatch.setattr(suite, "_device_alive", lambda timeout_s=60.0: True)
-    out = suite.run_all_isolated(only=["mnist", "resnet50"], timeout_s=10.0)
-    assert out["mnist"] == {"images_per_sec": 1.0}
-    assert "timeout" in out["resnet50"]["error"]
-    # the structured field bench.py keys its exit code on (the free
-    # text above may be reworded; this must not be)
-    assert out["resnet50"]["error_kind"] == "transport_timeout"
-
-
-def test_run_all_isolated_skips_rest_when_transport_wedged(monkeypatch,
-                                                           tmp_path):
-    """After a timeout, a failing device probe marks the remaining configs
-    skipped instead of burning the full timeout on each."""
-    import subprocess as _sp
-    import sys
-
-    fake = tmp_path / "fake_suite.py"
-    fake.write_text("import time; time.sleep(60)\n")
-    real_run = _sp.run
-
-    def fake_run(cmd, **kw):
-        cmd = [sys.executable, str(fake), "x"]
-        return real_run(cmd, **kw)
-
-    monkeypatch.setattr(_sp, "run", fake_run)
-    # alive at pre-flight, wedged after the first config's timeout
-    calls = iter([True, False])
-    monkeypatch.setattr(suite, "_device_alive",
-                        lambda timeout_s=60.0: next(calls))
+        "    time.sleep(60)\n"
+        "print(json.dumps({name: {'images_per_sec': 1.0}}))\n")
     out = suite.run_all_isolated(only=["mnist", "resnet50", "bert"],
                                  timeout_s=3.0)
-    assert "timeout" in out["mnist"]["error"]
-    assert "wedged" in out["resnet50"]["error"]
-    assert "wedged" in out["bert"]["error"]
-    assert out["mnist"]["error_kind"] == "transport_timeout"
-    assert out["resnet50"]["error_kind"] == "transport_wedged"
-    assert out["bert"]["error_kind"] == "transport_wedged"
+    assert out["mnist"] == {"error": "timeout after 3s"}
+    assert out["resnet50"] == {"images_per_sec": 1.0}
+    assert out["bert"] == {"images_per_sec": 1.0}
 
 
-def test_run_all_isolated_preflight_skips_everything(monkeypatch):
-    """A transport already wedged by an earlier session must not burn
-    the first config's full timeout either."""
-    monkeypatch.setattr(suite, "_device_alive", lambda timeout_s=60.0: False)
-    probes = []
-    monkeypatch.setattr(suite.time, "sleep", lambda s: probes.append(s))
-    out = suite.run_all_isolated(only=["mnist", "resnet50"],
-                                 timeout_s=60.0, probe_retries=3,
-                                 probe_wait_s=0.01)
-    assert all("unreachable at bench start (3 probes)" in v["error"]
-               for v in out.values())
-    assert all(v["error_kind"] == "transport_unreachable"
-               for v in out.values())
-    assert probes == [0.01, 0.01]  # retried with spacing, then gave up
-    # retries <= 0 still probes once and reports the real count
-    out = suite.run_all_isolated(only=["mnist"], timeout_s=60.0,
-                                 probe_retries=0)
-    assert "(1 probes)" in out["mnist"]["error"]
+def test_run_all_isolated_keeps_rows_of_a_failed_child(monkeypatch,
+                                                       tmp_path):
+    """``suite.main`` exits non-zero when a config raised, but it
+    printed its rows first — the parent keeps them, error and all."""
+    _fake_suite_children(
+        monkeypatch, tmp_path,
+        "import sys, json\n"
+        "print(json.dumps({sys.argv[1]: {'error': 'RuntimeError: kaput'}}))\n"
+        "sys.exit('bench configs raised')\n")
+    out = suite.run_all_isolated(only=["mnist"], timeout_s=30.0)
+    assert out == {"mnist": {"error": "RuntimeError: kaput"}}
 
 
-def test_bench_artifact_stamps_tier_and_transport(monkeypatch, capsys):
-    """Artifact hygiene (ISSUE 6): a transport-skipped round must stamp
-    ``device_transport``/``tier`` at the top level AND exit nonzero
-    (with the artifact already emitted), so r03/r04-style all-skip
-    rounds can never read as a flat perf trajectory."""
+def test_suite_main_exits_nonzero_when_a_config_raised(monkeypatch,
+                                                       capsys):
+    import json as _json
+
+    def boom():
+        raise RuntimeError("kaput")
+
+    monkeypatch.setitem(suite.CONFIGS, "resnet50", boom)
+    monkeypatch.setattr("sys.argv", ["suite", "mnist", "resnet50"])
+    monkeypatch.setitem(suite.CONFIGS, "mnist",
+                        lambda: {"images_per_sec": 5.0})
+    with pytest.raises(SystemExit) as e:
+        suite.main()
+    assert e.value.code not in (0, None)
+    assert "resnet50" in str(e.value.code)
+    rows = _json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert "kaput" in rows["resnet50"]["error"]
+    # every good row says what ran it
+    assert rows["mnist"]["platform"] == "cpu"
+    assert rows["mnist"]["device_kind"] == jax.devices()[0].device_kind
+    # a clean run returns normally
+    monkeypatch.setattr("sys.argv", ["suite", "mnist"])
+    suite.main()
+
+
+def test_suite_import_touches_no_device():
+    """bench.py's parent imports the suite and then starts children
+    that need the chip: the import must not initialize a backend."""
+    import subprocess
+    import sys
+
+    prog = ("import kubeflow_tpu.bench.suite\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge._backends, xla_bridge._backends\n")
+    proc = subprocess.run([sys.executable, "-c", prog],
+                          capture_output=True, text=True, timeout=120,
+                          cwd=suite._REPO_ROOT)
+    assert proc.returncode == 0, proc.stderr[-500:]
+
+
+def _run_bench(monkeypatch, capsys, rows):
     import json as _json
 
     import bench
 
-    # no error_kind on purpose: pins the substring FALLBACK for results
-    # from an older suite; the structured path is pinned below
-    skipped = {name: {"error": "skipped: device transport unreachable "
-                               "at bench start (3 probes)"}
-               for name in ("mnist", "resnet50")}
-    monkeypatch.setattr(suite, "run_all_isolated",
-                        lambda **kw: dict(skipped))
-    monkeypatch.setattr(suite, "run_cpu_smoke",
-                        lambda **kw: {"mnist": {"tier": "cpu",
-                                                "images_per_sec": 1.0}})
+    monkeypatch.setattr(suite, "run_all_isolated", lambda **kw: dict(rows))
     monkeypatch.setattr("sys.argv", ["bench.py"])
-    with pytest.raises(SystemExit) as e:
+    code = 0
+    try:
         bench.main()
-    assert e.value.code == 1                     # nonzero-with-artifact
+    except SystemExit as e:
+        code = e.code
     line = _json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert line["device_transport"] == "unreachable"
-    assert line["tier"] == "cpu-smoke"           # smoke ran, chips didn't
-    assert line["cpu_smoke"]["mnist"]["tier"] == "cpu"
+    return code, line
 
-    # healthy round: transport ok, tier reflects what ran, exit 0 path
-    ok = {"mnist": {"images_per_sec": 5.0, "platform": "cpu"},
-          "resnet50": {"images_per_sec_per_chip": 100.0,
-                       "platform": "tpu"}}
-    monkeypatch.setattr(suite, "run_all_isolated", lambda **kw: dict(ok))
-    bench.main()
-    line = _json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert line["device_transport"] == "ok"
-    assert line["tier"] == "tpu"
 
-    # structured path: classification keys on error_kind alone — a
-    # reworded free-text message must not re-enable the silent skip
-    reworded = {name: {"error": "skipped: PJRT link down",
-                       "error_kind": "transport_unreachable"}
-                for name in ("mnist", "resnet50")}
-    monkeypatch.setattr(suite, "run_all_isolated",
-                        lambda **kw: dict(reworded))
-    with pytest.raises(SystemExit) as e:
-        bench.main()
-    assert e.value.code == 1
-    line = _json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert line["device_transport"] == "unreachable"
+TPU_ROW = {"platform": "tpu", "device_kind": "TPU v5 lite"}
+
+
+def test_bench_exits_nonzero_on_a_dead_headline(monkeypatch, capsys):
+    """A headline that died on the chip is a failed round — whatever
+    else passed. No CPU tier stands in for it any more: CPU rows are a
+    test, not part of a chip artifact."""
+    code, line = _run_bench(monkeypatch, capsys, {
+        "mnist": {"images_per_sec": 5.0, **TPU_ROW},
+        "resnet50": {"error": "timeout after 900s"}})
+    assert code == 1
+    assert line["value"] == 0.0
+    assert line["errored"] == ["resnet50"]
+    assert "cpu_smoke" not in line and "tier" not in line
+    assert not hasattr(suite, "run_cpu_smoke")
+
+
+def test_bench_exits_nonzero_on_a_non_tpu_row(monkeypatch, capsys):
+    code, line = _run_bench(monkeypatch, capsys, {
+        "mnist": {"images_per_sec": 5.0, "platform": "cpu",
+                  "device_kind": "cpu"},
+        "resnet50": {"images_per_sec_per_chip": 100.0, **TPU_ROW}})
+    assert code == 1
+    assert line["not_on_tpu"] == ["mnist"] and line["errored"] == []
+
+
+def test_bench_clean_chip_round_exits_zero(monkeypatch, capsys):
+    code, line = _run_bench(monkeypatch, capsys, {
+        "mnist": {"images_per_sec": 5.0, **TPU_ROW},
+        "resnet50": {"images_per_sec_per_chip": 100.0, "mfu": 0.2,
+                     "tflops_per_chip": 39.4, **TPU_ROW}})
+    assert code == 0
+    assert line["value"] == 100.0 and line["mfu"] == 0.2
+    assert line["device_kind"] == "TPU v5 lite"
+    assert line["errored"] == [] and line["not_on_tpu"] == []

@@ -464,6 +464,12 @@ def test_cache_invalidated_recovery_replays_slots(lm, paged):
     real, state = _inject_step_failure(eng)
     eng.run_once(timeout=0.01)          # fails mid-decode + recovers
     assert state["fired"] and eng.recoveries == 1 and not eng.closed
+    # survived, but never silently: the snapshot and the exported
+    # counter both say so (chip_smoke.py asserts they read 0)
+    assert eng.snapshot()["recoveries"] == 1
+    from kubeflow_tpu.serving import engine as engine_mod
+
+    assert engine_mod._recoveries_c.get(model=eng.name) >= 1
     eng._step_greedy, eng._step = real
     _drain(eng, 30)
     assert r.result() == want           # replayed, stream intact
@@ -543,3 +549,38 @@ def test_paged_close_fails_waiting_and_prefilling(lm):
     for req in (held, waiting):
         with pytest.raises(EngineClosed):
             req.result()
+
+
+# -- the Pallas kernels under a serving mesh ---------------------------------
+
+
+def test_paged_kernel_and_fused_sampler_on_a_tp_mesh(lm):
+    """XLA cannot partition a Mosaic kernel, so under a mesh the paged
+    decode kernel and the fused sampler run inside a full-manual
+    shard_map (parallel/mesh.py:shard_kernel — a multi-device jit on
+    the TPU backend refuses a bare pallas_call outright; the CPU
+    interpreter does not, which is why this path went unseen until the
+    four-chip host). tp=2 divides both head counts: the kernel runs per
+    head group. Greedy tokens must match the unsharded oracle; a
+    sampled request must reproduce itself."""
+    from conftest import shard_params
+    from kubeflow_tpu.parallel import MeshConfig, create_mesh
+
+    config, params = lm
+    mesh = create_mesh(MeshConfig(tp=2), devices=jax.devices()[:2])
+    eng = _paged(config, shard_params(params, mesh), slots=2, mesh=mesh,
+                 paged_attention_impl="kernel", sampler_impl="fused",
+                 sampler_bound=0)
+    r1 = eng.submit([5, 11, 17], max_new=8)
+    r2 = eng.submit([3, 2, 9, 23, 41], max_new=6, temperature=0.8,
+                    top_k=5, seed=3)
+    _drain(eng)
+    assert r1.result() == _oracle(config, params, [5, 11, 17], 8)
+    sampled = r2.result()
+    assert len(sampled) == 6 and all(0 <= t < 97 for t in sampled)
+    r3 = eng.submit([3, 2, 9, 23, 41], max_new=6, temperature=0.8,
+                    top_k=5, seed=3)
+    _drain(eng)
+    assert r3.result() == sampled
+    assert eng.recoveries == 0 and not eng.closed
+    eng.close()

@@ -350,6 +350,26 @@ class TestFlashAutotuneAndPadding:
                     g_fl, g_ref, atol=1e-4,
                     err_msg=f"d{name} causal={causal}")
 
+    def test_smem_resident_control_vectors_are_bounded(self):
+        """The kv lengths (and the fused sampler's per-row scalars) sit
+        in SMEM whole; the interpreter has no SMEM, so the size is held
+        to what compiled on a chip and fails by name past it."""
+        from kubeflow_tpu.ops.attention import MAX_SMEM_CONTROL_ENTRIES
+        from kubeflow_tpu.ops.sampling import fused_sample
+
+        B = MAX_SMEM_CONTROL_ENTRIES // 2 + 1  # x 2 heads: one too many
+        x = jnp.zeros((B, 16, 2, 8), jnp.float32)
+        with pytest.raises(ValueError, match="flash_attention kv_len"):
+            flash_attention(x, x, x, False, 16, 16, None, None,
+                            jnp.full((B,), 16, jnp.int32))
+        rows = MAX_SMEM_CONTROL_ENTRIES + 1
+        with pytest.raises(ValueError, match="fused_sample rows"):
+            jax.eval_shape(
+                lambda l, k: fused_sample(l, k, temperature=1.0),
+                jax.ShapeDtypeStruct((rows, 128), jnp.float32),
+                jax.eval_shape(lambda: jax.random.split(
+                    jax.random.key(0), rows)))
+
     def test_padding_mask_with_uneven_blocks(self):
         """Mask correctness must not depend on the tile shape — a
         length landing mid-block masks the partial block exactly."""

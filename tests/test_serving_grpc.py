@@ -140,6 +140,36 @@ def test_warmup_precompiles_buckets(tmp_path, mnist_params):
     server.stop()
 
 
+def test_failed_warmup_still_serves_but_is_counted(tmp_path, mnist_params,
+                                                    monkeypatch):
+    """A warm-up that raises is logged and the version still serves —
+    production behaviour — but /metrics says so, and says 0 when none
+    failed (chip_smoke.py asserts on the series)."""
+    from kubeflow_tpu.serving import server as server_mod
+    from kubeflow_tpu.serving.model_store import LoadedModel
+
+    _, params = mnist_params
+    export_model(str(tmp_path / "ok" / "mnist"), "mnist", params, version=1)
+    export_model(str(tmp_path / "bad" / "mnist2"), "mnist", params,
+                 version=1)
+    ok = ModelServer(str(tmp_path / "ok"), port=0, poll_interval_s=3600,
+                     max_batch_size=2, warmup=True)
+    assert server_mod._warmup_failures.get(model="mnist") == 0.0
+    assert ('kftpu_serving_warmup_failures_total{model="mnist"} 0'
+            in server_mod._warmup_failures.expose())
+    ok.stop()
+
+    def boom(self, batch_sizes):
+        raise RuntimeError("injected warm-up failure")
+
+    monkeypatch.setattr(LoadedModel, "warmup", boom)
+    bad = ModelServer(str(tmp_path / "bad"), port=0, poll_interval_s=3600,
+                      max_batch_size=2, warmup=True)
+    assert server_mod._warmup_failures.get(model="mnist2") == 1.0
+    assert bad.repo.get("mnist2") is not None   # still served
+    bad.stop()
+
+
 def test_export_records_input_shape(tmp_path, mnist_params):
     _, params = mnist_params
     export_model(str(tmp_path / "m"), "mnist", params, version=2,

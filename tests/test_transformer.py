@@ -212,3 +212,75 @@ def test_bad_tile_knob_rejected():
         tiny_config(attention_block_q=0).validate()
     with pytest.raises(ValueError, match="paged_head_block"):
         tiny_config(paged_head_block=-1).validate()
+
+
+def test_flash_under_a_dp_tp_mesh_matches_one_device():
+    """A Mosaic kernel cannot be partitioned by XLA, so under a mesh the
+    flash kernels run inside a full-manual shard_map — batch rows over
+    dp, heads over tp (parallel/mesh.py:shard_kernel). Loss and every
+    gradient must match the mesh-free run; the masked (kv_len) variant
+    rides the same wrapper through BERT."""
+    from kubeflow_tpu.parallel import MeshConfig, create_mesh
+    from kubeflow_tpu.parallel.mesh import mesh_context
+
+    config = tiny_config(attention_impl="flash", n_kv_heads=4)
+    model, params, _ = _init(config, batch=4, seq=32)
+    tokens = jax.random.randint(jax.random.key(3), (4, 32), 0,
+                                config.vocab_size)
+
+    def loss(p):
+        return jnp.mean(model.apply({"params": p}, tokens) ** 2)
+
+    want_l, want_g = jax.jit(jax.value_and_grad(loss))(params)
+    mesh = create_mesh(MeshConfig(dp=2, tp=2), devices=jax.devices()[:4])
+    with mesh_context(mesh):
+        got_l, got_g = jax.jit(jax.value_and_grad(loss))(params)
+    # the mesh reorders the loss reduction, nothing else
+    np.testing.assert_allclose(float(got_l), float(want_l), rtol=1e-5)
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), atol=1e-5), got_g, want_g)
+
+
+def test_shard_kernel_drops_an_axis_that_does_not_divide_every_dim(caplog):
+    """q heads split without their kv heads would pair the wrong
+    groups: a logical axis is applied only if it divides every dim
+    that carries it."""
+    from jax.sharding import PartitionSpec as P
+
+    from kubeflow_tpu.parallel import MeshConfig, create_mesh
+    from kubeflow_tpu.parallel.mesh import (
+        mesh_context,
+        record_kernel_placements,
+        shard_kernel,
+    )
+
+    mesh = create_mesh(MeshConfig(tp=4), devices=jax.devices()[:4])
+    seen = {}
+
+    def fn(q, k):
+        seen["shapes"] = (q.shape, k.shape)
+        return q + k.sum(axis=1, keepdims=True)
+
+    q = jnp.ones((2, 8, 4))
+    axes = ((None, "heads", None),) * 2
+
+    def run(q, k):
+        return shard_kernel("test_kernel", fn, (q, k), axes, q.shape,
+                            (None, "heads", None))
+
+    with mesh_context(mesh), record_kernel_placements() as placed:
+        out = jax.jit(run)(q, jnp.ones((2, 8, 4)))
+        assert seen["shapes"] == ((2, 2, 4), (2, 2, 4))  # 8 heads / tp=4
+        assert placed == [{"kernel": "test_kernel", "devices": 4,
+                           "split": {"heads": 4}, "dropped": []}]
+        with caplog.at_level("WARNING", logger="kubeflow_tpu.parallel.mesh"):
+            jax.jit(run)(q, jnp.ones((2, 2, 4)))
+        assert seen["shapes"] == ((2, 8, 4), (2, 2, 4))  # 2 kv heads: whole
+        # never silently: recorded for the bench row, and logged
+        assert placed[1:] == [{"kernel": "test_kernel", "devices": 4,
+                               "split": {}, "dropped": ["heads"]}]
+        assert "test_kernel" in caplog.text and "dropped" in caplog.text
+    assert out.shape == (2, 8, 4)
+    # no mesh: a plain call
+    assert run(q, q).shape == (2, 8, 4)
