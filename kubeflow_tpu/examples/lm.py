@@ -128,6 +128,7 @@ def main(argv=None) -> float:
         if ckpt and (step % args.checkpoint_every == 0 or step == args.steps):
             ckpt.save(step, state)
     prof.close()
+    telem.close()  # the last step's record closes once it has completed
     if ckpt:
         ckpt.wait()
         ckpt.close()

@@ -267,6 +267,18 @@ class StepTelemetry:
     timestamp (and extracts float-able outputs into the record's
     metrics) — right for tests and log-cadence loops; leave False on
     the hot path so async dispatch keeps pipelining.
+
+    Under ``sync=False`` a step that returns device arrays has only
+    been ENQUEUED when ``run`` returns, so its completion is stamped
+    one step late: after enqueueing step *i* the wrapper blocks on step
+    *i − 1*'s outputs (one of its smallest that the next call was not
+    given to reuse) and closes that record there. The record's
+    duration is the interval between consecutive completions (from the
+    step's own start where the device had drained: the first step, or
+    the one after a flush), and the device queue stays one step deep.
+    :meth:`summary` and :meth:`close` flush the last step. A callable
+    that returns no device array has completed when it returns and is
+    stamped at once, as under ``sync=True``.
     """
 
     def __init__(
@@ -346,6 +358,12 @@ class StepTelemetry:
         self._rate_window = max(2, rate_window)
         self._last_dump_step = -(10 ** 9)
         self._probed_cost = False
+        # sync=False: the step that is enqueued and not yet seen to
+        # complete (its start, the outputs to block on, whether the call
+        # compiled), and when the step before it completed
+        self._pending: Optional[Tuple[float, List[Any],
+                                      Optional[bool]]] = None
+        self._last_done = float("-inf")
 
         lbl = {"job": job} if job else {}
         self._labels = lbl
@@ -381,19 +399,28 @@ class StepTelemetry:
                 if self.sync:
                     out = _block(out)
             except BaseException as e:
+                self._close_pending()  # records stay in step order
                 end = self.clock()
-                self._on_step(start, end, cache_before, jitted,
+                self._on_step(start, end,
+                              self._note_compile(cache_before, jitted),
                               status=f"ERROR: {type(e).__name__}",
                               out=None)
                 raise
-            end = self.clock()
+            compiled = self._note_compile(cache_before, jitted)
+            handles = [] if self.sync else _completion_handles(out)
+            # the step before this one: enqueued a call ago, closed now
+            # that its successor keeps the device fed
+            self._close_pending()
+            end = None if handles else self.clock()
             # probe AFTER the step (and after taking ``end``): the step
             # just compiled this exact program, so the AOT re-lower hits
             # the backend compile cache instead of doubling a minutes-
             # long startup compile — the bench roofline's pattern
             self._maybe_probe_flops(jitted, args)
-            self._on_step(start, end, cache_before, jitted, status="OK",
-                          out=out)
+            if handles:
+                self._pending = (start, handles, compiled)
+            else:
+                self._on_step(start, end, compiled, status="OK", out=out)
             return out
 
         instrumented.telemetry = self  # introspection/bench handle
@@ -410,15 +437,41 @@ class StepTelemetry:
         self._probed_cost = True
         self.flops_per_step = cost_analysis_flops(jitted, *args)
 
+    def _close_pending(self) -> None:
+        """Wait for the enqueued step (``sync=False``) and close its
+        record at the moment it was seen complete. It began when it was
+        enqueued or when the step before it completed, whichever came
+        later: with the device fed, that is the interval between two
+        completions."""
+        if self._pending is None:
+            return
+        (start, handles, compiled), self._pending = self._pending, None
+        for handle in handles:
+            try:
+                handle.block_until_ready()
+                break
+            except Exception:  # noqa: BLE001 — donated to the next step
+                continue       # (deleted): another output of it will do
+        end = self.clock()
+        self._on_step(max(start, self._last_done), end, compiled,
+                      status="OK", out=None)
+        self._last_done = end
+
+    def close(self) -> None:
+        """Flush the last step (``sync=False`` closes each record one
+        step late): call when the loop ends."""
+        self._close_pending()
+
     def _on_step(self, start: float, end: float,
-                 cache_before: Optional[int], jitted: Any, *,
-                 status: str, out: Any) -> None:
+                 compiled: Optional[bool], *, status: str,
+                 out: Any) -> None:
         self.step += 1
         dur = max(end - start, 0.0)
-        recompile = self._detect_recompile(cache_before, jitted, dur)
-        if recompile:
-            self.recompiles += 1
-            self._c_recompiles.inc(**self._labels)
+        recompile = compiled
+        if recompile is None:  # opaque callable: judge by the duration
+            recompile = self._is_outlier(dur)
+            if recompile:
+                self._count_recompile()
         rec = StepRecord(step=self.step, start=start, end=end,
                          tokens=self.tokens_per_step,
                          examples=self.examples_per_step,
@@ -463,15 +516,28 @@ class StepTelemetry:
             except Exception:  # noqa: BLE001 — beacons never fail a step
                 log.debug("beacon sink failed (continuing)", exc_info=True)
 
-    def _detect_recompile(self, cache_before: Optional[int], jitted: Any,
-                          dur: float) -> bool:
+    def _count_recompile(self) -> None:
+        self.recompiles += 1
+        self._c_recompiles.inc(**self._labels)
+
+    def _note_compile(self, cache_before: Optional[int],
+                      jitted: Any) -> Optional[bool]:
+        """Whether the call just made grew the jit cache — counted here,
+        when the compile happened, though the step's record may close a
+        call later. None where the callable shows no cache."""
         cache_after = _jit_cache_size(jitted)
-        if cache_before is not None and cache_after is not None:
-            # includes the first fill (0 -> 1): the initial compile is a
-            # compile — the flight record for step 1 should say so
-            return cache_after > cache_before
-        # fallback: a step-time outlier against the rolling median —
-        # recompiles stall the host for seconds while neighbors take ms
+        if cache_before is None or cache_after is None:
+            return None
+        # includes the first fill (0 -> 1): the initial compile is a
+        # compile — the flight record for step 1 should say so
+        if cache_after > cache_before:
+            self._count_recompile()
+        return cache_after > cache_before
+
+    def _is_outlier(self, dur: float) -> bool:
+        """The recompile fallback: a step-time outlier against the
+        rolling median — recompiles stall the host for seconds while
+        neighbors take ms."""
         history = self._durations
         if len(history) < self.min_slow_history:
             return False
@@ -551,7 +617,9 @@ class StepTelemetry:
 
     def summary(self) -> Dict[str, Any]:
         """Step-regularity summary (the BENCH-artifact shape): p50/p99
-        step time, recompile count, MFU."""
+        step time, recompile count, MFU. Waits for the last enqueued
+        step first (``sync=False``), so call it at log cadence."""
+        self._close_pending()
         durs = sorted(r.duration for r in self.recorder.records())
         out: Dict[str, Any] = {
             "steps": self.step,
@@ -639,6 +707,28 @@ def _block(out: Any) -> Any:
         return jax.block_until_ready(out)
     except Exception:  # noqa: BLE001 — pure-python callables in tests
         return out
+
+
+def _completion_handles(out: Any) -> List[Any]:
+    """The smallest device arrays among a step's outputs (its scalars,
+    as a rule), last first; empty where it returned none. Blocking on
+    one output of a program waits for the program. Holding only the
+    smallest keeps no state alive a step longer; holding every one of
+    that size leaves something to wait on where the next step was given
+    some of them to reuse (a donated ``state.step`` is deleted by then,
+    the metrics beside it are not), and those come last in a
+    ``(state, metrics)`` result."""
+    try:
+        import jax
+
+        leaves = jax.tree_util.tree_leaves(out)
+    except Exception:  # noqa: BLE001 — no jax: nothing is asynchronous
+        return []
+    arrays = [x for x in leaves if hasattr(x, "block_until_ready")]
+    if not arrays:
+        return []
+    least = min(getattr(x, "size", 0) for x in arrays)
+    return [x for x in reversed(arrays) if getattr(x, "size", 0) == least]
 
 
 def _extract_metrics(out: Any) -> Dict[str, float]:

@@ -99,6 +99,10 @@ _steps_total = DEFAULT_REGISTRY.counter(
     "kftpu_engine_steps_total", "shared decode steps executed")
 _tokens_total = DEFAULT_REGISTRY.counter(
     "kftpu_engine_tokens_total", "tokens produced by the decode engine")
+_round_seconds = DEFAULT_REGISTRY.counter(
+    "kftpu_engine_round_seconds_total",
+    "engine-thread seconds by round phase (wait, admit, step, sync, "
+    "emit): the rate by phase is where the thread's time goes")
 _occupancy = DEFAULT_REGISTRY.gauge(
     "kftpu_engine_active_slots", "active slots in the decode batch")
 _slots_g = DEFAULT_REGISTRY.gauge(
@@ -150,6 +154,10 @@ _recoveries_c = DEFAULT_REGISTRY.counter(
     "device call (each one is a device fault survived, never routine)")
 
 _END = object()  # per-request stream sentinel
+
+# an ``engine.round`` span's ``<phase>_s`` attrs and the ``phase`` label
+# of kftpu_engine_round_seconds_total, in the order a round passes them
+_ROUND_PHASES = ("wait", "admit", "step", "sync", "emit")
 
 
 class EngineClosed(RuntimeError):
@@ -326,6 +334,16 @@ class DecodeEngine:
         # during a capture (docs/OBSERVABILITY.md)
         self.tracer = tracer if tracer is not None else Tracer(
             clock=self.clock, annotator=profiler_annotator())
+        # what the engine THREAD did with its time hangs off one
+        # ``engine.run`` root per engine (recorded at close()): the
+        # per-round ``engine.round`` / ``engine.step`` children then
+        # never crowd the collector's root list. Ids are made here, not
+        # taken from the constructing thread's span — an engine built
+        # inside its first request must not join that request's trace
+        self._run_ctx = SpanContext(os.urandom(16).hex(),
+                                    os.urandom(8).hex())
+        self._t_run0: Optional[float] = self.clock()
+        self._admitted = 0  # requests admitted in the round in progress
         # the request-lifecycle ledger (docs/OBSERVABILITY.md "Request
         # lifecycle"): phase marks ride the clock reads this file
         # already takes; the process-wide default joins edge-side
@@ -764,6 +782,7 @@ class DecodeEngine:
         self._topk = np.zeros((slots,), np.int32)
         self._topp = np.ones((slots,), np.float32)
         self.steps_total = 0
+        self.rounds_total = 0  # run_once cycles that did work
         self.tokens_total = 0
         self.greedy_steps = 0  # steps served by the argmax fast path
         self.batch_prefills = 0  # burst admissions served batched
@@ -907,6 +926,14 @@ class DecodeEngine:
             req.out.put(_END)
             # the stream is over for its client: fold what we know
             self.rledger.finish(req.rid, t_close)
+        if self._t_run0 is not None:  # the rounds' root, once
+            self.tracer.record(
+                "engine.run", start=self._t_run0, end=t_close,
+                trace_id=self._run_ctx.trace_id,
+                span_id=self._run_ctx.span_id,
+                attrs={"model": self.name, "rounds": self.rounds_total,
+                       "steps": self.steps_total})
+            self._t_run0 = None
 
     @property
     def closed(self) -> bool:
@@ -1035,7 +1062,8 @@ class DecodeEngine:
         S = req.prompt.size
         with self.tracer.span("engine.admit", parent=req.ctx, attrs={
                 "model": self.name, "slot": slot,
-                "prompt_tokens": int(S), "batched": False}), \
+                "prompt_tokens": int(S), "batched": False,
+                "round": self.rounds_total}), \
                 self._mesh_ctx():
             # prefill phase opens here (prefix-row prep IS prefill
             # work); admission was the gap since _note_queue_wait
@@ -1135,57 +1163,84 @@ class DecodeEngine:
         call it directly (``autostart=False``) for deterministic
         schedules. A donating device call that fails mid-decode is
         recovered in place (cache rebuild + slot replay) while the
-        recovery budget lasts."""
-        if self.paged:
-            # admission arms slots (donating) and chunks donate the
-            # cache: every paged device call recovers under the same
-            # budget. Dense admission keeps its own per-request error
-            # handling (and _CacheInvalidated keeps the close protocol).
-            try:
-                worked = self._admit(timeout)
-                worked = self._prefill_tick() or worked
-            except _CacheInvalidated:
-                raise
-            except Exception:  # noqa: BLE001 — donated cache consumed
-                log.exception("paged admission/prefill failed")
-                if self._maybe_recover("paged admission/prefill"):
-                    return True
-                raise
-        else:
-            worked = self._admit(timeout)
-        with self._lock:
-            active = [(i, s) for i, s in enumerate(self._active)
-                      if s is not None]
-        if not active:
-            return worked
-        # greedy rows ignore seeds/filters entirely, so when EVERY
-        # active slot is greedy the cheap argmax step is bit-identical
-        # — and skips the per-row sampler (vocab sort) each token
-        all_greedy = all(s.req.temperature <= 0.0 for _, s in active)
-        t_step0 = self.clock()
-        try:
+        recovery budget lasts.
+
+        A cycle that did work is one ``engine.round`` span: the loop
+        reads its phase boundaries itself (``marks``: admit, then step /
+        sync / emit as the round reaches them) and names the same
+        phases on the profiler's host timeline, so every instant of the
+        engine thread lies inside one ``engine.*`` annotation."""
+        marks = [self.clock()]
+        self._admitted = 0
+        # the wait phase: only an engine with nothing to step or
+        # prefill may block on its queue, and that time is no work
+        head, wait_s = (self._wait_pending(timeout) if self._idle()
+                        else (None, 0.0))
+        with self._annotate("engine.admit"):
             if self.paged:
-                # page growth arms device rows (donating) — same
-                # recovery scope as the step itself
-                self._ensure_pages(i for i, _ in active)
-            with self._mesh_ctx():
-                if all_greedy:
-                    self._cache, toks = self._step_greedy(
-                        self._params, self._cache,
-                        jnp.asarray(self._tokens))
-                else:
-                    self._cache, toks = self._step(
-                        self._params, self._cache,
-                        jnp.asarray(self._tokens),
-                        jnp.asarray(self._seeds),
-                        jnp.asarray(self._stepidx),
-                        jnp.asarray(self._temps), jnp.asarray(self._topk),
-                        jnp.asarray(self._topp))
-            toks = np.asarray(toks)  # (K, B); the transfer surfaces
-            # device-side failures HERE, while recovery can still replay
+                # admission arms slots (donating) and chunks donate the
+                # cache: every paged device call recovers under the same
+                # budget. Dense admission keeps its own per-request
+                # error handling (and _CacheInvalidated keeps the close
+                # protocol).
+                try:
+                    worked = self._admit(head)
+                    worked = self._prefill_tick() or worked
+                except _CacheInvalidated:
+                    raise
+                except Exception:  # noqa: BLE001 — donated cache consumed
+                    log.exception("paged admission/prefill failed")
+                    if self._maybe_recover("paged admission/prefill"):
+                        self._record_round(marks, wait_s)
+                        return True
+                    raise
+            else:
+                worked = self._admit(head)
+            with self._lock:
+                active = [(i, s) for i, s in enumerate(self._active)
+                          if s is not None]
+            # greedy rows ignore seeds/filters entirely, so when EVERY
+            # active slot is greedy the cheap argmax step is
+            # bit-identical — and skips the per-row sampler (vocab
+            # sort) each token
+            all_greedy = all(s.req.temperature <= 0.0 for _, s in active)
+        if not active:
+            if worked:
+                self._record_round(marks, wait_s)
+            return worked
+        t_step0 = self.clock()
+        marks.append(t_step0)
+        try:
+            with self._annotate("engine.step"):
+                if self.paged:
+                    # page growth arms device rows (donating) — same
+                    # recovery scope as the step itself
+                    self._ensure_pages(i for i, _ in active)
+                with self._mesh_ctx():
+                    if all_greedy:
+                        self._cache, toks = self._step_greedy(
+                            self._params, self._cache,
+                            jnp.asarray(self._tokens))
+                    else:
+                        self._cache, toks = self._step(
+                            self._params, self._cache,
+                            jnp.asarray(self._tokens),
+                            jnp.asarray(self._seeds),
+                            jnp.asarray(self._stepidx),
+                            jnp.asarray(self._temps),
+                            jnp.asarray(self._topk),
+                            jnp.asarray(self._topp))
+            # the K steps are enqueued; what follows is the wait for
+            # them and the device→host read
+            marks.append(self.clock())
+            with self._annotate("engine.sync"):
+                toks = np.asarray(toks)  # (K, B); the transfer surfaces
+                # device-side failures HERE, while recovery can still
+                # replay
         except Exception:  # noqa: BLE001 — donated cache consumed
             log.exception("decode step failed")
             if self._maybe_recover("decode step"):
+                self._record_round(marks, wait_s, rows=len(active))
                 return True
             raise
         # ONE wall-clock read per sync batch, after the host transfer:
@@ -1193,95 +1248,163 @@ class DecodeEngine:
         # emit loop below stamps K×B tokens with it — per-token emit
         # takes zero additional clock reads (the ledger contract)
         t_step_end = self.clock()
-        K = toks.shape[0]
-        self.steps_total += K
-        if all_greedy:
-            self.greedy_steps += K
-        _steps_total.inc(K, model=self.name)
-        self._stepidx += K
-        self._tokens = toks[-1].copy()
-        if self.paged:
-            self._pos_host[[i for i, _ in active]] += K
-            # one span per shared step: the burst-interleave evidence
-            # (chunk spans between step spans bound any decode stall)
-            self.tracer.record(
-                "engine.step", start=t_step0, end=t_step_end,
-                attrs={"model": self.name, "rows": len(active), "k": K})
-        retired: List[int] = []
-        for i, slot in active:
-            for t in range(K):
-                tok = int(toks[t, i])
-                self._emit(slot, tok, t_step_end)
-                if self._finished(slot, tok, t_step_end):
-                    # tokens past EOS/budget in this chunk are discarded
-                    with self._lock:
-                        self._active[i] = None
-                    if self.paged:
-                        retired.append(i)
-                    # the request's decode phase is over: one span with
-                    # the token count — the per-request cost record
-                    self.tracer.record(
-                        "engine.decode", start=slot.t_decode0,
-                        end=t_step_end, parent=slot.req.ctx,
-                        attrs={"model": self.name,
-                               "tokens": slot.produced})
-                    break
-        if retired:
-            # retirement disarms rows with a donating _arm call: run
-            # the batch's retirements AFTER the emit loop so a device
-            # failure lands with emitted/fold accounting already
-            # complete — recovery replays the surviving streams instead
-            # of the close protocol failing them all
-            try:
-                for i in retired:
-                    self._retire_paged(i)
-            except Exception:  # noqa: BLE001 — donated cache consumed
-                log.exception("paged retirement failed")
-                if not self._maybe_recover("paged retirement"):
-                    raise
-        _occupancy.set(self.active_count, model=self.name)
+        marks.append(t_step_end)
+        with self._annotate("engine.emit"):
+            K = toks.shape[0]
+            self.steps_total += K
+            if all_greedy:
+                self.greedy_steps += K
+            _steps_total.inc(K, model=self.name)
+            self._stepidx += K
+            self._tokens = toks[-1].copy()
+            if self.paged:
+                self._pos_host[[i for i, _ in active]] += K
+                # one span per shared step: the burst-interleave
+                # evidence (chunk spans between step spans bound any
+                # decode stall)
+                self.tracer.record(
+                    "engine.step", start=t_step0, end=t_step_end,
+                    parent=self._run_ctx,
+                    attrs={"model": self.name, "rows": len(active),
+                           "k": K})
+            retired: List[int] = []
+            for i, slot in active:
+                for t in range(K):
+                    tok = int(toks[t, i])
+                    self._emit(slot, tok, t_step_end)
+                    if self._finished(slot, tok, t_step_end):
+                        # tokens past EOS/budget in this chunk are
+                        # discarded
+                        with self._lock:
+                            self._active[i] = None
+                        if self.paged:
+                            retired.append(i)
+                        # the request's decode phase is over: one span
+                        # with the token count — the per-request cost
+                        # record
+                        self.tracer.record(
+                            "engine.decode", start=slot.t_decode0,
+                            end=t_step_end, parent=slot.req.ctx,
+                            attrs={"model": self.name,
+                                   "tokens": slot.produced})
+                        break
+            if retired:
+                # retirement disarms rows with a donating _arm call: run
+                # the batch's retirements AFTER the emit loop so a
+                # device failure lands with emitted/fold accounting
+                # already complete — recovery replays the surviving
+                # streams instead of the close protocol failing them all
+                try:
+                    for i in retired:
+                        self._retire_paged(i)
+                except Exception:  # noqa: BLE001 — donated cache consumed
+                    log.exception("paged retirement failed")
+                    if not self._maybe_recover("paged retirement"):
+                        raise
+            _occupancy.set(self.active_count, model=self.name)
+        self._record_round(marks, wait_s, rows=len(active), k=K,
+                           greedy=all_greedy)
         return True
 
-    def _admit(self, timeout: float) -> bool:
+    def _annotate(self, name: str):
+        """``name`` on the profiler's host timeline while the block
+        runs (the tracer's bridge; nothing where it has none)."""
+        ann = self.tracer.annotator
+        return ann(name) if ann is not None else contextlib.nullcontext()
+
+    def _record_round(self, marks: List[float], wait_s: float, *,
+                      rows: int = 0, k: int = 0,
+                      greedy: bool = False) -> None:
+        """Close the round that ``marks`` opened: one ``engine.round``
+        span whose five phase durations tile ``[start, end]`` (a phase
+        the round never reached is 0; one that a recovery cut short runs
+        to the end; ``wait_s`` is carved out of admission's stretch),
+        and the same seconds into
+        ``kftpu_engine_round_seconds_total{phase}``."""
+        bounds = marks + [self.clock()]
+        secs = dict.fromkeys(_ROUND_PHASES, 0.0)
+        for phase, t_a, t_b in zip(_ROUND_PHASES[1:], bounds, bounds[1:]):
+            secs[phase] = t_b - t_a
+        secs["wait"] = wait_s
+        secs["admit"] -= wait_s
+        attrs = {"model": self.name, "round": self.rounds_total,
+                 "rows": rows, "k": k, "admitted": self._admitted,
+                 "greedy": greedy}
+        for phase, sec in secs.items():
+            attrs[f"{phase}_s"] = sec
+            _round_seconds.inc(sec, model=self.name, phase=phase)
+        self.tracer.record("engine.round", start=bounds[0],
+                           end=bounds[-1], parent=self._run_ctx,
+                           attrs=attrs)
+        self.rounds_total += 1
+
+    def _idle(self) -> bool:
+        """Nothing to step and nothing mid-admission: the one state in
+        which the engine thread may block on its queue."""
+        with self._lock:
+            if any(s is not None for s in self._active):
+                return False
+        return not (self.paged and (self._prefilling or self._waiting))
+
+    def _wait_pending(self, timeout: float) -> tuple:
+        """An idle engine's first arrival (None once ``timeout`` has
+        passed) and the seconds it blocked for it. Only a read that
+        really blocks is the round's wait phase (``wait_s``,
+        ``engine.wait`` on the profiler's timeline): a queue that
+        already holds a request costs no clock read."""
+        try:
+            return self._pending.get_nowait(), 0.0
+        except queue.Empty:
+            pass
+        t0 = self.clock()
+        try:
+            with self._annotate("engine.wait"):
+                head = self._pending.get(timeout=timeout)
+        except queue.Empty:
+            head = None
+        return head, self.clock() - t0
+
+    def _admit(self, head: Optional[_Request]) -> bool:
+        """Admission, never blocking: ``head`` is what the wait phase
+        took off the queue, the rest is whatever is pending now."""
         if self.hbm_sampler is not None:
             try:
                 self.hbm_sampler.sample()
             except Exception:  # noqa: BLE001 — watermarks never gate admits
                 log.debug("hbm sample failed (continuing)", exc_info=True)
         if self.paged:
-            return self._admit_paged(timeout)
-        return self._admit_dense(timeout)
+            return self._admit_paged(head)
+        return self._admit_dense(head)
 
     # -- paged engine internals --------------------------------------------
 
-    def _admit_paged(self, timeout: float) -> bool:
+    def _admit_paged(self, head: Optional[_Request]) -> bool:
         """Paged admission: placing a request is page-map surgery (a
         reservation + one tiny arm program), then the prompt streams
         into the pool through the chunked-prefill scheduler — there is
         no whole-row insert and no per-prompt-bucket program. FIFO is
         strict: a request that cannot reserve pages yet holds the line
         (head-of-line wait) rather than being overtaken."""
-        admitted = False
         with self._lock:
             busy = {i for i, s in enumerate(self._active)
                     if s is not None}
         busy |= set(self._prefilling)
         free = [i for i in range(self.slots) if i not in busy]
-        block = not busy and not self._waiting
+        if head is not None:  # only an idle engine waits: the line is empty
+            self._waiting.append(head)
         for slot in free:
             if not self._waiting:
                 try:
-                    self._waiting.append(self._pending.get(
-                        block=block and not admitted, timeout=timeout))
+                    self._waiting.append(self._pending.get_nowait())
                 except queue.Empty:
                     break
             if not self._place_paged(self._waiting[0], slot):
                 break  # no pages yet: keep FIFO, retry next cycle
             self._waiting.popleft()
-            admitted = True
+            self._admitted += 1
         _queue_depth.set(self.pending_count, model=self.name)
         _occupancy.set(self.active_count, model=self.name)
-        return admitted
+        return self._admitted > 0
 
     def _place_paged(self, req: _Request, slot: int) -> bool:
         """Reserve + map pages for a request and arm its slot; False
@@ -1454,7 +1577,8 @@ class DecodeEngine:
             "engine.admit", start=job.t_admit, end=now, parent=req.ctx,
             attrs={"model": self.name, "slot": slot,
                    "prompt_tokens": int(req.prompt.size),
-                   "chunked": True, "chunks": job.chunks})
+                   "chunked": True, "chunks": job.chunks,
+                   "round": self.rounds_total})
         st = _Slot(req=req, produced=job.produced0, t_decode0=now,
                    emitted=[int(t) for t in
                             job.tokens[req.prompt.size:]])
@@ -1654,25 +1778,25 @@ class DecodeEngine:
             with self._lock:
                 self._active[slot] = st
 
-    def _admit_dense(self, timeout: float) -> bool:
+    def _admit_dense(self, head: Optional[_Request]) -> bool:
         """Move pending requests into free slots.
 
         A BURST of pending requests sharing a prompt bucket admits
         through ONE compiled batch prefill (``_admit_batch``) instead of
         sequential row prefills; singletons and prefix-cached requests
         keep the row path (its compiled programs already exist)."""
-        admitted = False
         with self._lock:
             free = [i for i, s in enumerate(self._active) if s is None]
-        block = not any(s is not None for s in self._active)
         batchable: List[tuple] = []  # (req, slot) — no prefix reuse
         for slot in free:
-            try:
-                req = self._pending.get(block=block and not admitted,
-                                        timeout=timeout)
-            except queue.Empty:
-                break
-            admitted = True
+            if head is not None:
+                req, head = head, None
+            else:
+                try:
+                    req = self._pending.get_nowait()
+                except queue.Empty:
+                    break
+            self._admitted += 1
             if req.prefix_len or self.admit_batch_max <= 1:
                 self._admit_row_safe(req, slot)
             else:
@@ -1707,7 +1831,7 @@ class DecodeEngine:
                             self._admit_row_safe(req, slot)
         _queue_depth.set(self._pending.qsize(), model=self.name)
         _occupancy.set(self.active_count, model=self.name)
-        return admitted
+        return self._admitted > 0
 
     def _admit_row_safe(self, req: _Request, slot: int) -> None:
         """Row-path admission that surfaces failure to THIS caller only."""
@@ -1754,15 +1878,12 @@ class DecodeEngine:
             # each admit span (a context-managed span here would be an
             # orphan root — the engine thread has no active span — and
             # would crowd the dashboard's trace list)
-            ann = (self.tracer.annotator("engine.prefill")
-                   if self.tracer.annotator is not None
-                   else contextlib.nullcontext())
             p0 = self.clock()
             for req, _slot in members:
                 # the shared device call opens every member's prefill
                 # phase on the same already-read timestamp
                 self.rledger.mark(req.rid, reqobs.PREFILL, p0)
-            with ann:
+            with self._annotate("engine.prefill"):
                 toks, bcache = self._prefill_batch(
                     self._params, jnp.asarray(prompts),
                     jnp.asarray(lens),
@@ -1797,7 +1918,8 @@ class DecodeEngine:
                 "engine.admit", start=t0, end=t1, parent=req.ctx,
                 attrs={"model": self.name, "slot": slot,
                        "prompt_tokens": int(lens[i]),
-                       "batched": True, "batch": k})
+                       "batched": True, "batch": k,
+                       "round": self.rounds_total})
             # the shared prefill's time range, nested in THIS member's
             # trace (same shape as the row path's admit→prefill)
             self.tracer.record(

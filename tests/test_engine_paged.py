@@ -584,3 +584,69 @@ def test_paged_kernel_and_fused_sampler_on_a_tp_mesh(lm):
     assert r3.result() == sampled
     assert eng.recoveries == 0 and not eng.closed
     eng.close()
+
+
+# -- the round record on the paged path --------------------------------------
+
+
+def test_paged_rounds_tile_and_steps_share_the_run(lm):
+    """Paged rounds tile like dense ones (chunked prefill is admission
+    time; a round of chunks alone has k 0), the per-step span the
+    interleave test reads keeps its shape and gains the run's root as
+    parent, and a chunked ``engine.admit`` names the round that finished
+    it."""
+    from kubeflow_tpu.obs import SpanCollector, Tracer
+    from kubeflow_tpu.utils import DEFAULT_REGISTRY
+
+    config, params = lm
+    t = [0.0]
+
+    def clock():
+        t[0] += 1.0
+        return t[0]
+
+    collector = SpanCollector()
+    eng = _paged(config, params, slots=4, prefill_chunk_tokens=4,
+                 clock=clock, name="rounds-paged",
+                 tracer=Tracer(collector=collector, clock=clock))
+    r0 = eng.submit([5, 11, 17], max_new=12)
+    for _ in range(3):
+        eng.run_once(timeout=0.01)
+    # two chunks of prompt while r0 decodes: admission spans two rounds
+    r1 = eng.submit([1, 2, 3, 4, 5, 6, 7, 8], max_new=2)
+    while eng.run_once(timeout=0.01):
+        pass
+    assert r0.result() == _oracle(config, params, [5, 11, 17], 12)
+    assert r1.result() == _oracle(config, params,
+                                  [1, 2, 3, 4, 5, 6, 7, 8], 2)
+    eng.close()
+    spans = collector.spans()
+    (run,) = [s for s in spans if s.name == "engine.run"]
+    rounds = [s for s in spans if s.name == "engine.round"]
+    steps = [s for s in spans if s.name == "engine.step"]
+    phases = ("wait_s", "admit_s", "step_s", "sync_s", "emit_s")
+    assert len(rounds) == eng.rounds_total
+    for r in rounds:
+        assert sum(r.attrs[p] for p in phases) == r.end - r.start
+        assert (r.trace_id, r.parent_id) == (run.trace_id, run.span_id)
+    counter = DEFAULT_REGISTRY.counter("kftpu_engine_round_seconds_total")
+    for p in phases:
+        assert counter.get(model="rounds-paged", phase=p[:-2]) == \
+            sum(r.attrs[p] for r in rounds)
+    assert sum(r.attrs["admitted"] for r in rounds) == 2
+    stepped = [r for r in rounds if r.attrs["k"]]
+    assert len(stepped) == len(steps) > 0
+    for r, st in zip(stepped, steps):
+        assert (st.trace_id, st.parent_id) == (run.trace_id, run.span_id)
+        # the step span is the round's step + sync phases
+        assert st.end - st.start == r.attrs["step_s"] + r.attrs["sync_s"]
+        assert st.attrs["rows"] == r.attrs["rows"]
+    by_round = {r.attrs["round"]: r for r in rounds}
+    admits = [s for s in spans if s.name == "engine.admit"]
+    assert len(admits) == 2
+    for a in admits:
+        r = by_round[a.attrs["round"]]
+        assert r.start < a.end < r.end      # finished inside that round
+    late = max(admits, key=lambda a: a.end)
+    assert late.attrs["chunks"] == 2
+    assert late.start < by_round[late.attrs["round"]].start

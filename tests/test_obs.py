@@ -618,3 +618,71 @@ def test_trace_collector_component_renders():
     assert svc["spec"]["ports"][0]["port"] == 8095
     annotations = svc["metadata"]["annotations"]
     assert annotations["prometheus.io/scrape"] == "true"
+
+
+def test_engine_round_phases_on_the_profiler_timeline(lm):
+    """With a recording annotator in the profiler bridge's place: every
+    round is ``engine.admit`` (the row path's admit and prefill nested
+    in it), ``engine.step``, ``engine.sync``, ``engine.emit`` in that
+    order, and no clock read of ``run_once`` falls outside one of them
+    except the round's own phase boundaries."""
+    import contextlib
+
+    from kubeflow_tpu.serving.engine import DecodeEngine
+
+    config, params = lm
+    tick = FakeClock(start=0.0, step=1.0)
+    events = []                      # ("B"|"E", name, depth before / after)
+    reads = []                       # (t, annotation depth at the read)
+    depth = [0]
+
+    def clock():
+        t = tick()
+        reads.append((t, depth[0]))
+        return t
+
+    @contextlib.contextmanager
+    def annotator(name):
+        events.append(("B", name, depth[0]))
+        depth[0] += 1
+        try:
+            yield
+        finally:
+            depth[0] -= 1
+            events.append(("E", name, depth[0]))
+
+    collector = SpanCollector()
+    eng = DecodeEngine(config, params, slots=2, autostart=False,
+                       clock=clock, name="rounds-annotated",
+                       tracer=Tracer(collector=collector, clock=clock,
+                                     annotator=annotator))
+    eng.submit([5, 11, 17], max_new=4)
+    t_first = tick.t
+    for _ in range(3):
+        assert eng.run_once(timeout=0.01)
+    rounds = [s for s in collector.spans() if s.name == "engine.round"]
+    assert len(rounds) == 3
+    top = [n for kind, n, d in events if kind == "B" and d == 0]
+    assert top == ["engine.admit", "engine.step", "engine.sync",
+                   "engine.emit"] * 3
+    nested = [n for kind, n, d in events if kind == "B" and d > 0]
+    assert nested == ["engine.admit", "engine.prefill"]   # round 0's row
+    assert depth[0] == 0
+    boundaries = set()
+    for r in rounds:
+        t = r.start
+        boundaries.add(t)
+        for p in ("admit_s", "step_s", "sync_s", "emit_s"):
+            assert r.attrs["wait_s"] == 0.0 and r.attrs[p] > 0
+            t += r.attrs[p]
+            boundaries.add(t)
+        assert t == r.end
+    outside = {t for t, d in reads if d == 0 and t > t_first}
+    assert outside == boundaries
+    # an engine with nothing to step blocks on its queue under its own
+    # name, before (not inside) the admission phase
+    while eng.run_once(timeout=0.01):
+        pass
+    top = [n for kind, n, d in events if kind == "B" and d == 0]
+    assert top[-2:] == ["engine.wait", "engine.admit"]
+    assert "engine.wait" not in top[:-2]

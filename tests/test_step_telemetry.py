@@ -114,6 +114,190 @@ def test_wrap_passes_through_and_extracts_sync_metrics():
     assert telem.objective_series("loss") == [(1, 2.5)]
 
 
+# -- sync=False: completion is stamped one step late ---------------------------
+
+
+class _FakeDevice:
+    """One queue of programs on a manual clock. A step costs the host
+    ``enqueue_s`` and the device ``device_s``; its outputs are ready once
+    the device has finished the step before it and then this one. Like a
+    trainer's step it returns ``(state, metrics)`` and is given the state
+    to reuse: the state's arrays of the step before are deleted."""
+
+    def __init__(self, clock, enqueue_s=0.03, device_s=0.2):
+        self.clock, self.enqueue_s, self.device_s = clock, enqueue_s, device_s
+        self.done_at = []            # per step enqueued, when it completes
+        self.depth_at_enqueue = []   # steps still running as one more joins
+        self.state = None
+
+    def step(self, state=0):
+        now = self.clock.t
+        self.depth_at_enqueue.append(sum(t > now for t in self.done_at))
+        self.clock.t = now + self.enqueue_s
+        free = max([self.clock.t] + self.done_at[-1:])
+        self.done_at.append(free + self.device_s)
+        if self.state is not None:
+            self.state.deleted = True            # donated to this step
+        self.state = _FakeArray(self.clock, self.done_at[-1])
+        return ({"step": self.state},
+                {"loss": _FakeArray(self.clock, self.done_at[-1])})
+
+
+class _FakeArray:
+    size = 1
+
+    def __init__(self, clock, done_at, deleted=False):
+        self.clock, self.done_at, self.deleted = clock, done_at, deleted
+
+    def block_until_ready(self):
+        if self.deleted:
+            raise RuntimeError("Array has been deleted.")
+        self.clock.t = max(self.clock.t, self.done_at)
+        return self
+
+
+def test_async_steps_are_stamped_at_their_completion():
+    """The defect this pins: under ``sync=False`` a record used to end at
+    the enqueue (0.03 s here) for a step that takes the device 0.2 s."""
+    clock = FakeClock(start=0.0, step=0.0)
+    reg = Registry()
+    dev = _FakeDevice(clock)
+    telem = make_telemetry(clock=clock, registry=reg, tokens_per_step=100)
+    step = telem.wrap(dev.step)
+    for i in range(5):
+        step(i)
+        assert telem.step == i       # the step just enqueued is still open
+    telem.close()
+    recs = telem.recorder.records()
+    assert [r.step for r in recs] == [1, 2, 3, 4, 5]
+    assert [r.end for r in recs] == pytest.approx(dev.done_at)
+    # the first from its own start (it had to be enqueued first), the
+    # rest from completion to completion
+    assert recs[0].start == 0.0
+    assert recs[0].duration == pytest.approx(0.23)
+    for prev, rec in zip(recs, recs[1:]):
+        assert rec.start == prev.end
+        assert rec.duration == pytest.approx(0.2)
+    assert reg.histogram("train_step_seconds").sum(job="train") == \
+        pytest.approx(1.03)
+    assert reg.gauge("train_tokens_per_sec").get(job="train") == \
+        pytest.approx(500 / 1.03)
+    # the device queue is one deep: a step joins at most one still running
+    assert max(dev.depth_at_enqueue) == 1
+    telem.close()                     # nothing left to flush
+    assert telem.step == 5
+
+
+def test_summary_flushes_and_a_drained_device_restarts_the_interval():
+    clock = FakeClock(start=0.0, step=0.0)
+    dev = _FakeDevice(clock)
+    telem = make_telemetry(clock=clock)
+    step = telem.wrap(dev.step)
+    for i in range(3):
+        step(i)
+    assert telem.summary()["steps"] == 3          # waited for the third
+    assert telem.summary()["p50_step_s"] == pytest.approx(0.2)
+    clock.t += 5.0                                # the loop logs, saves …
+    t_resume = clock.t
+    step(3)
+    step(4)
+    telem.close()
+    recs = telem.recorder.records()
+    # the idle 5 s are no part of step 4: it began when it was enqueued
+    assert recs[3].start == t_resume
+    assert recs[3].duration == pytest.approx(0.23)
+    assert recs[4].duration == pytest.approx(0.2)
+
+
+def test_failure_closes_the_enqueued_step_first():
+    clock = FakeClock(start=0.0, step=0.0)
+    dev = _FakeDevice(clock)
+    calls = {"n": 0}
+
+    def run(state):
+        calls["n"] += 1
+        if calls["n"] == 3:
+            raise FloatingPointError("nan")
+        return dev.step(state)
+
+    telem = make_telemetry(clock=clock)
+    step = telem.wrap(run)
+    step(0)
+    step(1)
+    with pytest.raises(FloatingPointError):
+        step(2)
+    recs = telem.recorder.records()
+    assert [(r.step, r.status) for r in recs] == [
+        (1, "OK"), (2, "OK"), (3, "ERROR: FloatingPointError")]
+    assert recs[1].end == pytest.approx(dev.done_at[1])
+    assert telem.last_dump[0] == "failure"
+
+
+def test_sync_true_still_blocks_inside_the_step():
+    clock = FakeClock(start=0.0, step=0.0)
+    dev = _FakeDevice(clock)
+    telem = make_telemetry(clock=clock, sync=True)
+    step = telem.wrap(dev.step)
+    for i in range(3):
+        step(i)
+        assert telem.step == i + 1
+    # enqueue, then the whole device time, nothing overlapped
+    assert [r.duration for r in telem.recorder.records()] == \
+        pytest.approx([0.23] * 3)
+
+
+def test_donating_jit_step_is_waited_on_through_its_metrics():
+    """A real trainer's shape: the state (its scalar ``n`` first among
+    the smallest outputs) is donated to the next call, so the wait has to
+    fall on the metrics beside it."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from kubeflow_tpu.obs.steps import _completion_handles
+
+    @functools.partial(jax.jit, donate_argnums=0)
+    def train(state):
+        return ({"n": state["n"] + 1, "w": state["w"] * 2.0},
+                {"loss": state["w"].sum()})
+
+    telem = make_telemetry()
+    step = telem.wrap(train)
+    state = {"n": jnp.zeros((), jnp.int32), "w": jnp.ones((8, 8))}
+    outs = []
+    for _ in range(3):
+        state, metrics = step(state)
+        outs.append((state, metrics))
+    handles = _completion_handles(outs[0])
+    assert [h.is_deleted() for h in handles] == [False, True]   # loss, n
+    telem.close()
+    assert telem.step == 3 and int(state["n"]) == 3
+    assert [r.status for r in telem.recorder.records()] == ["OK"] * 3
+
+
+def test_output_given_away_to_the_next_step_never_fails_a_step():
+    """Where the only output was donated to the next call there is
+    nothing left to wait on: the record closes without the wait."""
+    clock = FakeClock(start=0.0, step=0.0)
+    outs = []
+
+    def run():
+        clock.t += 0.03
+        for arr in outs:
+            arr.deleted = True
+        outs.append(_FakeArray(clock, clock.t + 0.2))
+        return outs[-1]
+
+    telem = make_telemetry(clock=clock)
+    step = telem.wrap(run)
+    for _ in range(3):
+        step()
+    telem.close()
+    assert telem.step == 3
+    assert [r.status for r in telem.recorder.records()] == ["OK"] * 3
+
+
 # -- recompile detection -----------------------------------------------------
 
 
@@ -130,6 +314,7 @@ def test_recompile_via_jit_cache_delta():
     assert telem.recompiles == 1
     step(jnp.ones((8,)))          # new shape: recompile
     assert telem.recompiles == 2
+    telem.close()                 # device arrays: the last closes late
     recs = telem.recorder.records()
     assert [r.recompile for r in recs] == [True, False, True]
 
@@ -517,6 +702,7 @@ def test_mfu_from_cost_analysis_real_jit():
                            peak_flops_per_chip=1e12)
     step = telem.wrap(f)
     step(x)
+    telem.close()                 # a device-array step closes a call late
     # the probe read real FLOPs off the compiled executable
     assert telem.flops_per_step and telem.flops_per_step > 0
     assert telem.mfu() is not None and telem.mfu() > 0
