@@ -14,10 +14,14 @@ Shapes are the whole design:
   tail is dead weight that the next real tokens overwrite before any
   attention can see it (masking is by absolute position);
 - the per-step state is the flax ``cache`` collection the decode-mode
-  :class:`~kubeflow_tpu.models.transformer.Transformer` maintains
-  (K/V ``(L, B, max_seq_len, KH, Dh)`` + write index, stacked over
-  layers by ``nn.scan``) — donated through the scan so XLA updates it
-  in place;
+  :class:`~kubeflow_tpu.models.transformer.Transformer` declares (K/V
+  ``(L, B, max_seq_len, KH, Dh)`` + per-row write positions ``(L, B)``).
+  It is a loop CARRY all the way down: of the K-step scan here, and of
+  the layer scan inside the model, where each layer scatters its tokens
+  into ``[layer, row, position]`` and reads its rows back out of the
+  same buffer. A cache scanned over by layer (a scanned input and a
+  stacked output, two buffers that a loop cannot alias) was copied
+  whole on every step, whatever the caller donated;
 - sampling is greedy (``temperature=0``) or temperature-scaled
   categorical with a threaded PRNG key.
 """

@@ -24,6 +24,7 @@ Design notes (TPU-first):
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -198,12 +199,46 @@ def apply_rope(x: jnp.ndarray, sin: jnp.ndarray, cos: jnp.ndarray) -> jnp.ndarra
                    cos[None, :, None, :].astype(x.dtype))
 
 
+def _declare_cache(module: nn.Module, c: TransformerConfig, batch: int,
+                   stack: Tuple[int, ...] = ()):
+    """The decode cache's leaves as ``cache`` variables of ``module``,
+    by name: per-row write ``positions``, ``k`` / ``v`` as
+    ``(B, max_seq_len, KH, Dh)`` rows or, paged, a ``(kv_pages,
+    kv_page_size, KH, Dh)`` pool beside the per-row ``pages`` table
+    (every entry the unmapped sentinel ``kv_pages``). ``stack`` is
+    ``(n_layers,)`` where one owner holds every layer's leaves."""
+    Smax, KH, Dh = c.max_seq_len, c.n_kv_heads, c.head_dim
+    if c.kv_page_size:
+        P, ps = c.kv_pages, c.kv_page_size
+        leaves = {"positions": ((batch,), 0, jnp.int32),
+                  "pages": ((batch, Smax // ps), P, jnp.int32),
+                  "k": ((P, ps, KH, Dh), 0, c.dtype),
+                  "v": ((P, ps, KH, Dh), 0, c.dtype)}
+    else:
+        leaves = {"positions": ((batch,), 0, jnp.int32),
+                  "k": ((batch, Smax, KH, Dh), 0, c.dtype),
+                  "v": ((batch, Smax, KH, Dh), 0, c.dtype)}
+    return {name: module.variable("cache", name, jnp.full, stack + shape,
+                                  fill, dtype)
+            for name, (shape, fill, dtype) in leaves.items()}
+
+
+def _layer_slice(stacked: jnp.ndarray, layer) -> jnp.ndarray:
+    return jax.lax.dynamic_index_in_dim(stacked, layer, 0, keepdims=False)
+
+
 class Attention(nn.Module):
     config: TransformerConfig
     decode: bool = False
 
     @nn.compact
-    def __call__(self, x, sin, cos, kv_len=None):
+    def __call__(self, x, sin, cos, kv_len=None, cache=None, layer=None):
+        """Attention output ``(B, S, D)``; in decode mode ``(output,
+        cache)``. There ``cache`` holds the ``[L, ...]`` leaves of every
+        layer and ``layer`` says which slice is this block's (the carry
+        and the scanned index of ``Transformer``'s layer scan); with
+        ``cache`` None the block owns its buffers (``scan_layers=False``)
+        and runs the same code over them as a stack of one."""
         c = self.config
         B, S, D = x.shape
         H, KH, Dh = c.n_heads, c.n_kv_heads, c.head_dim
@@ -219,9 +254,19 @@ class Attention(nn.Module):
         v = jnp.einsum("bsd,dhk->bshk", x, wv.astype(c.dtype))
 
         if self.decode:
-            out = self._decode_attend(q, k, v, sin, cos)
+            own = None
+            if cache is None:
+                own = _declare_cache(self, c, B)
+                cache = {name: var.value[None] for name, var in own.items()}
+                layer = 0
+            out, cache = self._decode_attend(q, k, v, sin, cos, cache,
+                                             layer)
+            if own is not None:
+                for name, var in own.items():
+                    var.value = cache[name][0]
+                cache = None
             out = jnp.einsum("bshk,hkd->bsd", out, wo.astype(c.dtype))
-            return _constrain(out, c.rules, "batch", "seq", None)
+            return _constrain(out, c.rules, "batch", "seq", None), cache
 
         if c.attention_impl in ("ring", "ulysses"):
             # sequence stays sharded through attention (SP paths); heads
@@ -244,7 +289,7 @@ class Attention(nn.Module):
         out = jnp.einsum("bshk,hkd->bsd", out, wo.astype(c.dtype))
         return _constrain(out, c.rules, "batch", "seq", None)
 
-    def _decode_attend(self, q, k, v, sin_full, cos_full):
+    def _decode_attend(self, q, k, v, sin_full, cos_full, cache, layer):
         """Autoregressive attention with a KV cache (static shapes).
 
         ``sin_full``/``cos_full`` span ``max_seq_len``. The cache carries
@@ -262,20 +307,21 @@ class Attention(nn.Module):
           tail is masked (kv_pos > its positions) until the generated
           tokens overwrite it;
         - step (S == 1): per-row scatter write + per-row rope position.
+
+        ``cache`` is the dict of ``[L, ...]`` leaves and stays ONE
+        buffer per leaf: the new tokens are written into it at
+        ``[layer, row, position]`` and this layer's rows are read back
+        out of it, so a loop that carries it updates it in place.
+        Returns ``(output, cache)``.
         """
         c = self.config
         if c.kv_page_size:
-            return self._paged_decode_attend(q, k, v, sin_full, cos_full)
+            return self._paged_decode_attend(q, k, v, sin_full, cos_full,
+                                             cache, layer)
         B, S, KH, Dh = k.shape
         Smax = c.max_seq_len
-
-        pos_var = self.variable("cache", "positions",
-                                lambda: jnp.zeros((B,), jnp.int32))
-        ck = self.variable("cache", "k", jnp.zeros, (B, Smax, KH, Dh),
-                           c.dtype)
-        cv = self.variable("cache", "v", jnp.zeros, (B, Smax, KH, Dh),
-                           c.dtype)
-        pos = pos_var.value  # (B,)
+        ck, cv = cache["k"], cache["v"]
+        pos = _layer_slice(cache["positions"], layer)  # (B,)
 
         from kubeflow_tpu.ops.attention import NEG_INF, gqa_repeat
 
@@ -288,8 +334,8 @@ class Attention(nn.Module):
             q = _rotate(q, sin, cos)
             k = _rotate(k, sin, cos)
             rows = jnp.arange(B)
-            ck.value = ck.value.at[rows, pos].set(k[:, 0])
-            cv.value = cv.value.at[rows, pos].set(v[:, 0])
+            ck = ck.at[layer, rows, pos].set(k[:, 0])
+            cv = cv.at[layer, rows, pos].set(v[:, 0])
             q_pos = pos[:, None]  # (B, 1)
         elif c.ragged_decode:
             # multi-token with per-row starts (speculative verify,
@@ -304,8 +350,8 @@ class Attention(nn.Module):
             q = _rotate(q, sin, cos)
             k = _rotate(k, sin, cos)
             rows2d = jnp.broadcast_to(jnp.arange(B)[:, None], (B, S))
-            ck.value = ck.value.at[rows2d, q_pos].set(k)
-            cv.value = cv.value.at[rows2d, q_pos].set(v)
+            ck = ck.at[layer, rows2d, q_pos].set(k)
+            cv = cv.at[layer, rows2d, q_pos].set(v)
         else:
             # prefill: rows share a start (a fresh cache starts at 0;
             # the engine's 1-row prefix continuation shares trivially)
@@ -314,14 +360,16 @@ class Attention(nn.Module):
             cos = jax.lax.dynamic_slice_in_dim(cos_full, idx, S, 0)
             q = apply_rope(q, sin, cos)
             k = apply_rope(k, sin, cos)
-            ck.value = jax.lax.dynamic_update_slice_in_dim(ck.value, k,
-                                                           idx, axis=1)
-            cv.value = jax.lax.dynamic_update_slice_in_dim(cv.value, v,
-                                                           idx, axis=1)
+            at = (layer, 0, idx, 0, 0)
+            ck = jax.lax.dynamic_update_slice(ck, k[None], at)
+            cv = jax.lax.dynamic_update_slice(cv, v[None], at)
             q_pos = (idx + jnp.arange(S))[None, :]  # (1, S) → rows share
-        pos_var.value = pos + S
+        cache = dict(cache, k=ck, v=cv,
+                     positions=jax.lax.dynamic_update_index_in_dim(
+                         cache["positions"], pos + S, layer, 0))
 
-        kc, vc = gqa_repeat(q, ck.value, cv.value)
+        kc, vc = gqa_repeat(q, _layer_slice(ck, layer),
+                            _layer_slice(cv, layer))
         logits = jnp.einsum("bshd,bthd->bhst", q, kc).astype(jnp.float32)
         logits = logits * (Dh ** -0.5)
         kv_pos = jnp.arange(Smax)
@@ -329,9 +377,10 @@ class Attention(nn.Module):
         mask = kv_pos[None, None, :] <= q_pos[:, :, None]
         logits = jnp.where(mask[:, None], logits, NEG_INF)
         probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-        return jnp.einsum("bhst,bthd->bshd", probs, vc)
+        return jnp.einsum("bhst,bthd->bshd", probs, vc), cache
 
-    def _paged_decode_attend(self, q, k, v, sin_full, cos_full):
+    def _paged_decode_attend(self, q, k, v, sin_full, cos_full, cache,
+                             layer):
         """Autoregressive attention over a PAGED KV pool.
 
         The cache is a pool of ``kv_pages`` HBM blocks of
@@ -363,19 +412,11 @@ class Attention(nn.Module):
         B, S, KH, Dh = k.shape
         Smax = c.max_seq_len
         ps = c.kv_page_size
-        n_log = Smax // ps
         P = c.kv_pages
 
-        pos_var = self.variable("cache", "positions",
-                                lambda: jnp.zeros((B,), jnp.int32))
-        pages_var = self.variable(
-            "cache", "pages", lambda: jnp.full((B, n_log), P, jnp.int32))
-        ck = self.variable("cache", "k", jnp.zeros, (P, ps, KH, Dh),
-                           c.dtype)
-        cv = self.variable("cache", "v", jnp.zeros, (P, ps, KH, Dh),
-                           c.dtype)
-        pos = pos_var.value        # (B,)
-        pages = pages_var.value    # (B, n_log)
+        ck, cv = cache["k"], cache["v"]                  # (L, P, ps, KH, Dh)
+        pos = _layer_slice(cache["positions"], layer)    # (B,)
+        pages = _layer_slice(cache["pages"], layer)      # (B, n_log)
 
         from kubeflow_tpu.ops.attention import NEG_INF, gqa_repeat
 
@@ -392,9 +433,12 @@ class Attention(nn.Module):
         pg = jnp.take_along_axis(pages, safe_pos // ps, axis=1)  # (B, S)
         pg = jnp.where(q_pos < Smax, pg, P)
         off = q_pos % ps
-        ck.value = ck.value.at[pg, off].set(k, mode="drop")
-        cv.value = cv.value.at[pg, off].set(v, mode="drop")
-        pos_var.value = pos + S
+        ck = ck.at[layer, pg, off].set(k, mode="drop")
+        cv = cv.at[layer, pg, off].set(v, mode="drop")
+        cache = dict(cache, k=ck, v=cv,
+                     positions=jax.lax.dynamic_update_index_in_dim(
+                         cache["positions"], pos + S, layer, 0))
+        k_pool, v_pool = _layer_slice(ck, layer), _layer_slice(cv, layer)
 
         impl = c.paged_attention_impl
         if S == 1 and (impl == "kernel" or (impl == "auto"
@@ -418,17 +462,17 @@ class Attention(nn.Module):
                 lambda q1, kp, vp, pg, ps_: paged_decode_attention(
                     q1, kp, vp, pg, ps_, sm_scale=Dh ** -0.5,
                     head_block=c.paged_head_block),
-                (q[:, 0], ck.value, cv.value, pages, pos),
+                (q[:, 0], k_pool, v_pool, pages, pos),
                 ((None, "heads", None), pool, pool, (None, None), (None,)),
                 q[:, 0].shape, (None, "heads", None), c.rules)
-            return out[:, None]
+            return out[:, None], cache
 
         # gather each row's logical view: (B, n_log, ps, KH, Dh) ->
         # (B, Smax, KH, Dh); sentinel entries clamp to a real page and
         # are masked below
-        kc = jnp.take(ck.value, pages, axis=0,
+        kc = jnp.take(k_pool, pages, axis=0,
                       mode="clip").reshape(B, Smax, KH, Dh)
-        vc = jnp.take(cv.value, pages, axis=0,
+        vc = jnp.take(v_pool, pages, axis=0,
                       mode="clip").reshape(B, Smax, KH, Dh)
         kc, vc = gqa_repeat(q, kc, vc)
         logits = jnp.einsum("bshd,bthd->bhst", q, kc).astype(jnp.float32)
@@ -437,7 +481,7 @@ class Attention(nn.Module):
         mask = kv_pos[None, None, :] <= q_pos[:, :, None]   # (B, S, Smax)
         logits = jnp.where(mask[:, None], logits, NEG_INF)
         probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-        return jnp.einsum("bhst,bthd->bshd", probs, vc)
+        return jnp.einsum("bhst,bthd->bshd", probs, vc), cache
 
     def _attend(self, q, k, v, kv_len=None):
         """Dispatch to the configured attention core (causal per config).
@@ -511,8 +555,6 @@ class Attention(nn.Module):
             return att.blockwise_attention(
                 q, k, v, causal=c.causal, block_k=block_k
             )
-        import functools
-
         from jax.sharding import PartitionSpec as P
 
         if c.attention_impl == "ulysses":
@@ -626,27 +668,39 @@ class Block(nn.Module):
     decode: bool = False
 
     @nn.compact
-    def __call__(self, x, aux):
+    def __call__(self, x, aux, layer=None):
         # aux is (sin, cos) or (sin, cos, kv_len) — the optional third
         # element is the per-row valid-length padding mask the BERT
         # encoder threads through every block (models/bert.py)
         sin, cos = aux[0], aux[1]
         kv_len = aux[2] if len(aux) > 2 else None
         c = self.config
+        cache = None
+        if self.decode:
+            # the carry is (hidden, stacked cache or None) and ``layer``
+            # the scanned index: see Attention.__call__
+            x, cache = x
         h = RMSNorm(param_dtype=c.param_dtype, name="attn_norm")(x)
-        x = x + Attention(c, decode=self.decode, name="attn")(h, sin, cos,
-                                                              kv_len)
+        attn = Attention(c, decode=self.decode, name="attn")(
+            h, sin, cos, kv_len, cache, layer)
+        if self.decode:
+            attn, cache = attn
+        x = x + attn
         h = RMSNorm(param_dtype=c.param_dtype, name="mlp_norm")(x)
         mlp = MoeMlp(c, name="moe") if c.n_experts else Mlp(c, name="mlp")
         x = x + mlp(h)
-        return x, None
+        return ((x, cache) if self.decode else x), None
 
 
 class Transformer(nn.Module):
     config: TransformerConfig
-    # autoregressive mode: attention maintains a "cache" collection (KV
-    # cache + write index, stacked over layers by nn.scan); apply with
-    # mutable=["cache"] — see kubeflow_tpu/models/decode.py
+    # autoregressive mode: a "cache" collection (K/V + per-row write
+    # positions; apply with mutable=["cache"], see models/decode.py).
+    # Under scan_layers the [L, ...] leaves are declared here and travel
+    # through the layer scan as part of its CARRY, each block writing and
+    # reading its own [layer] slice: a loop's scanned input and stacked
+    # output are two buffers that cannot alias, so a cache scanned over
+    # (variable_axes) was copied whole on every step of an outer loop
     decode: bool = False
     # return the post-final-norm hidden states (B, S, D) instead of
     # logits: the long-context training path computes the vocab
@@ -680,18 +734,31 @@ class Transformer(nn.Module):
         if c.remat and not self.decode:
             block_cls = nn.remat(Block, prevent_cse=False)
         if c.scan_layers:
-            x, _ = nn.scan(
-                block_cls,
-                variable_axes={"params": 0, "losses": 0, "cache": 0},
+            scan = functools.partial(
+                nn.scan,
+                variable_axes={"params": 0, "losses": 0},
                 split_rngs={"params": True},
-                in_axes=nn.broadcast,
                 length=c.n_layers,
-                metadata_params={nn.PARTITION_NAME: "layers"},
-            )(c, decode=self.decode, name="blocks")(x, (sin, cos))
+                metadata_params={nn.PARTITION_NAME: "layers"})
+            if self.decode:
+                leaves = _declare_cache(self, c, B, (c.n_layers,))
+                cache = {name: var.value for name, var in leaves.items()}
+                (x, cache), _ = scan(Block, in_axes=(nn.broadcast, 0))(
+                    c, decode=True, name="blocks")(
+                        (x, cache), (sin, cos), jnp.arange(c.n_layers))
+                for name, var in leaves.items():
+                    var.value = cache[name]
+            else:
+                x, _ = scan(block_cls, in_axes=nn.broadcast)(
+                    c, name="blocks")(x, (sin, cos))
         else:
+            if self.decode:
+                x = (x, None)  # each block owns its buffers
             for i in range(c.n_layers):
                 x, _ = block_cls(c, decode=self.decode,
                                  name=f"block_{i}")(x, (sin, cos))
+            if self.decode:
+                x, _ = x
 
         x = RMSNorm(param_dtype=c.param_dtype, name="final_norm")(x)
         if self.return_hidden:

@@ -195,7 +195,7 @@ def pow2_bucket(n: int, cap: int) -> int:
 def _batch_axis(leaf: jnp.ndarray) -> int:
     """Cache leaves are ``positions`` (B,)|(L, B) or ``k``/``v``
     (B, S, KH, Dh)|(L, B, S, KH, Dh) depending on whether layers are
-    stacked by ``nn.scan`` — the batch axis is determined by rank."""
+    stacked (``scan_layers``) — the batch axis is determined by rank."""
     return {1: 0, 2: 1, 4: 0, 5: 1}[leaf.ndim]
 
 

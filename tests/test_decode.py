@@ -19,6 +19,7 @@ from kubeflow_tpu.models.decode import (
     generate,
     make_generate,
     prefill,
+    prefill_continue,
 )
 from kubeflow_tpu.serving import transformer_export_config
 
@@ -153,6 +154,50 @@ def test_unscanned_layers_decode(setup):
     want = full_forward_greedy(model, params, prompt, 4)
     got = generate(config, params, prompt, max_new_tokens=4)
     np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("scan_layers", [True, False],
+                         ids=["carried", "per_layer"])
+def test_prefix_then_suffix_then_steps_continue_one_cache(scan_layers):
+    """prefill → prefill_continue → decode steps hand ONE cache along:
+    same layout at every stage, and the tokens and live K/V of a
+    prompt prefilled whole."""
+    config = small_config(scan_layers=scan_layers)
+    prompt = jax.random.randint(jax.random.key(1), (1, 9), 0,
+                                config.vocab_size)
+    params = Transformer(config).init(jax.random.key(0), prompt)["params"]
+
+    def steps(logits, cache):
+        toks = []
+        for _ in range(3):
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            toks.append(int(tok[0]))
+            logits, cache = jax.jit(decode_step, static_argnums=0)(
+                config, params, cache, tok)
+        return toks, logits, cache
+
+    def layout(c):
+        return jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), c)
+
+    whole = steps(*prefill(config, params, prompt))
+    _, cache = prefill(config, params, prompt[:, :5])
+    suffix = jnp.pad(prompt[:, 5:], ((0, 0), (0, 4)))   # 4 real + 4 pad
+    logits, cont = prefill_continue(config, params, cache, suffix, 4, 9)
+    assert layout(cont) == layout(cache)
+    split = steps(logits, cont)
+    assert split[0] == whole[0]
+    np.testing.assert_allclose(split[1], whole[1], rtol=1e-4, atol=1e-5)
+    assert layout(split[2]) == layout(cache)
+    for (path, got), want in zip(
+            jax.tree_util.tree_leaves_with_path(split[2]),
+            jax.tree_util.tree_leaves(whole[2])):
+        if path[-1].key == "positions":
+            np.testing.assert_array_equal(got, np.full(got.shape, 12))
+            np.testing.assert_array_equal(got, want)
+        else:   # (..., B, Smax, KH, Dh): the 12 written positions
+            np.testing.assert_allclose(got[..., :12, :, :],
+                                       want[..., :12, :, :],
+                                       rtol=1e-4, atol=1e-5)
 
 
 def test_moe_decode(setup):

@@ -11,6 +11,8 @@ scheduler (``/root/reference/kubeflow/tf-serving/tf-serving-template.libsonnet:3
 which cannot interleave autoregressive requests at the step level.
 """
 
+import dataclasses
+import re
 import threading
 
 import jax
@@ -989,3 +991,163 @@ def test_program_names_the_benchmark_reads(lm, attr, program):
     config, params = lm
     eng = DecodeEngine(config, params, slots=2, autostart=False)
     assert getattr(eng, attr).__name__ == program
+
+
+# -- the carried cache (PR 28) ----------------------------------------------
+
+_HLO_INSTR = re.compile(
+    r"^\s+(?:ROOT )?%?[\w.\-]+ = (\(.*?\)|\S+) ([\w\-]+)\(")
+_HLO_CALLEE = re.compile(
+    r"(?:body|condition|calls|to_apply)=%?([\w.\-]+)")
+
+
+def _hlo_computations(text):
+    """``{computation: [(shape, op, line)]}`` of a compiled module's
+    text, with each computation's callees under ``(None, "calls", …)``."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            name = line.split("(")[0].split()[-1].lstrip("%")
+            cur = comps.setdefault(name, [])
+        elif cur is not None:
+            m = _HLO_INSTR.match(line)
+            if m:
+                cur.append((m.group(1).split("{")[0], m.group(2), line))
+                for callee in _HLO_CALLEE.findall(line):
+                    cur.append((None, "calls", callee))
+    return comps
+
+
+def _layout(cache):
+    return jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), cache)
+
+
+def _by_name(cache):
+    """The stacked cache's leaves by leaf name, wherever they nest."""
+    return {path[-1].key: leaf for path, leaf
+            in jax.tree_util.tree_leaves_with_path(cache)}
+
+
+def _step_args(eng):
+    B = eng.slots
+    toks = jnp.zeros((B,), jnp.int32)
+    ones = jnp.ones((B,), jnp.float32)
+    return {"_step_greedy": (toks,),
+            "_step": (toks, toks, toks, ones, toks, ones)}
+
+
+@pytest.mark.parametrize("program", ["_step", "_step_greedy"])
+def test_step_program_updates_the_cache_in_place(lm, program):
+    """The K-step program holds ONE buffer per stacked cache leaf from
+    entry to exit: nothing inside a loop copies or re-creates a buffer
+    of the stack's shape, and outside the fused computations exactly one
+    instruction per leaf (the token scatter) produces one. An f32 cache:
+    the CPU backend widens bf16 scatters through whole-cache converts
+    that the TPU backend does not have."""
+    config, params = lm
+    eng = DecodeEngine(config, params, slots=4, steps_per_sync=4,
+                       autostart=False)
+    stack = _by_name(eng._cache)["k"]
+    assert stack.ndim == 5 and stack.dtype == jnp.float32
+    shape = "f32[%s]" % ",".join(str(d) for d in stack.shape)
+    text = getattr(eng, program).lower(
+        eng._params, eng._cache, *_step_args(eng)[program]
+    ).compile().as_text()
+    comps = _hlo_computations(text)
+
+    def callees(name):
+        return [x for s, op, x in comps.get(name, ()) if op == "calls"]
+
+    loops = [c for instrs in comps.values() for s, op, line in instrs
+             if op == "while" for c in _HLO_CALLEE.findall(line)]
+    assert loops, "no while loop in the step program"
+    inside, todo = set(), list(loops)
+    while todo:
+        name = todo.pop()
+        if name not in inside:
+            inside.add(name)
+            todo.extend(callees(name))
+    moved = [line.strip()[:120] for name in inside
+             for s, op, line in comps.get(name, ())
+             if s == shape and op in ("copy", "broadcast")]
+    assert not moved, moved
+
+    fused = {c for instrs in comps.values() for s, op, line in instrs
+             if op == "fusion" for c in _HLO_CALLEE.findall(line)}
+    passes = ("parameter", "get-tuple-element", "tuple", "bitcast",
+              "while", "call", "conditional")
+    written = [line.strip()[:120] for name, instrs in comps.items()
+               if name not in fused for s, op, line in instrs
+               if s == shape and op not in passes]
+    assert len(written) <= 2, written     # one for k, one for v
+
+
+def _per_layer_params(params, n_layers):
+    """``scan_layers=True`` params as ``scan_layers=False`` names them."""
+    out = {k: v for k, v in params.items() if k != "blocks"}
+    for i in range(n_layers):
+        out[f"block_{i}"] = jax.tree_util.tree_map(lambda a, i=i: a[i],
+                                                   params["blocks"])
+    return out
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.8],
+                         ids=["greedy", "sampled"])
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+def test_carried_cache_equals_per_layer_buffers(lm, paged, temperature):
+    """Ragged prompts through K-step rounds: the layer scan that carries
+    the stacked cache and the unrolled layers that own one buffer each
+    give the same tokens and the same cache (positions and page tables
+    exactly, K and V to rounding)."""
+    config, params = lm
+    prompts = [[5, 11, 17], [3, 2, 9, 23, 41, 8, 1], [7]]
+    kw = (dict(paged=True, kv_page_size=8, prefill_chunk_tokens=8)
+          if paged else {})
+    ran = []
+    for cfg, p in ((config, params),
+                   (dataclasses.replace(config, scan_layers=False),
+                    _per_layer_params(params, config.n_layers))):
+        eng = DecodeEngine(cfg, p, slots=4, steps_per_sync=4,
+                           autostart=False, **kw)
+        before = _layout(eng._cache)
+        reqs = [eng.submit(pr, max_new=10, temperature=temperature,
+                           top_k=7, top_p=0.9, seed=3 + i)
+                for i, pr in enumerate(prompts)]
+        for _ in range(30):
+            eng.run_once(timeout=0.01)
+        assert before == _layout(eng._cache)
+        ran.append(([r.result() for r in reqs], eng._cache, eng))
+    (toks, stacked, eng), (loop_toks, per_layer, loop_eng) = ran
+    stacked = _by_name(stacked)
+    assert toks == loop_toks
+    assert eng.steps_total == loop_eng.steps_total > 0
+    assert sorted(stacked) == sorted(per_layer["block_0"]["attn"])
+    for i in range(config.n_layers):
+        for name, leaf in per_layer[f"block_{i}"]["attn"].items():
+            got = stacked[name][i]
+            assert (got.shape, got.dtype) == (leaf.shape, leaf.dtype), name
+            # the two programs fuse differently: float leaves agree to
+            # rounding (seen: 14 of 3072 elements one ulp apart)
+            np.testing.assert_allclose(np.asarray(got), np.asarray(leaf),
+                                       rtol=1e-5, atol=1e-6, err_msg=name)
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+@pytest.mark.parametrize("program", ["_step", "_step_greedy"])
+def test_step_returns_the_cache_layout_it_was_given(lm, program, paged):
+    """The pytree the engine builds at construction (from ``prefill``'s
+    ``eval_shape``) is the one every step hands back: same treedef, leaf
+    names, shapes and dtypes, layers stacked on axis 0."""
+    config, params = lm
+    kw = dict(paged=True, kv_page_size=8) if paged else {}
+    eng = DecodeEngine(config, params, slots=4, steps_per_sync=2,
+                       autostart=False, **kw)
+    out, _ = jax.eval_shape(getattr(eng, program), eng._params, eng._cache,
+                            *_step_args(eng)[program])
+    assert _layout(out) == _layout(eng._cache)
+    spec = _by_name(out)
+    L, B = config.n_layers, eng.slots
+    assert spec["positions"].shape == (L, B)
+    assert spec["k"].shape[0] == spec["v"].shape[0] == L
+    assert sorted(spec) == (["k", "pages", "positions", "v"] if paged
+                            else ["k", "positions", "v"])
