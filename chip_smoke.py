@@ -748,7 +748,9 @@ def compare_prefill_programs(cfg, params, prompts: List[List[int]],
     import numpy as np
 
     from kubeflow_tpu.models.decode import decode_step, prefill
-    from kubeflow_tpu.serving.engine import _batch_axis, pow2_bucket
+    from kubeflow_tpu.serving.engine import pow2_bucket
+
+    leaves = cfg.cache_leaves(1)      # each cache leaf's row axis, by name
 
     cfg32 = dc.replace(cfg, dtype=jnp.float32)
 
@@ -776,16 +778,18 @@ def compare_prefill_programs(cfg, params, prompts: List[List[int]],
         return jnp.asarray(rows), jnp.asarray(lens)
 
     def row0(cache):
-        return jax.tree_util.tree_map(
-            lambda x: jax.lax.slice_in_dim(x, 0, 1, axis=_batch_axis(x)),
+        return jax.tree_util.tree_map_with_path(
+            lambda path, x: jax.lax.slice_in_dim(
+                x, 0, 1, axis=leaves[path[-1].key].batch_axis),
             cache)
 
     def live_kv(cache, n: int):
         """Row 0's written k/v at the prompt's real positions."""
         return np.concatenate([
-            np.asarray(jnp.take(x, 0, axis=_batch_axis(x))[..., :n, :, :],
-                       np.float32).ravel()
-            for x in jax.tree_util.tree_leaves(cache) if x.ndim >= 4])
+            np.asarray(jnp.take(x, 0, axis=leaves[path[-1].key].batch_axis)
+                       [..., :n, :, :], np.float32).ravel()
+            for path, x in jax.tree_util.tree_leaves_with_path(cache)
+            if leaves[path[-1].key].heads_axis is not None])
 
     out: Dict[str, Any] = {"prompts": {}}
     worst16 = worst32 = 0.0

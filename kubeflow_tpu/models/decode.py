@@ -36,12 +36,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from kubeflow_tpu.models.transformer import Transformer, TransformerConfig
+from kubeflow_tpu.models.transformer import TransformerConfig
 from kubeflow_tpu.ops.attention import NEG_INF
 
 
-def _decode_model(config: TransformerConfig) -> Transformer:
-    return Transformer(config, decode=True)
+def _decode_model(config):
+    """The model's decode-mode module; the config knows which."""
+    return config.decoder()
 
 
 def prefill(config: TransformerConfig, params, tokens: jnp.ndarray,
@@ -53,8 +54,11 @@ def prefill(config: TransformerConfig, params, tokens: jnp.ndarray,
     RAGGED batches (defaults to S). Each row's write position resets to
     its own length, so its generated tokens land contiguously after its
     prompt; a shorter row's pad tail stays causally masked until
-    overwritten. Returns (next_token_logits, cache) where logits are
-    each row's LAST REAL token's.
+    overwritten. A recurrent state cannot be reset that way (the pad
+    tokens would be folded into it), so a model that has one is given
+    the lengths and freezes each row's state at its own. Returns
+    (next_token_logits, cache) where logits are each row's LAST REAL
+    token's.
     """
     model = _decode_model(config)
     B, S = tokens.shape
@@ -66,6 +70,7 @@ def prefill(config: TransformerConfig, params, tokens: jnp.ndarray,
     lens = jnp.broadcast_to(true_len, (B,))
 
     logits, variables = model.apply({"params": params}, tokens,
+                                    *_row_lengths(config, lens),
                                     mutable=["cache"])
     cache = variables["cache"]
     # the write positions advanced to S (the padded bucket); pull each
@@ -99,7 +104,8 @@ def prefill_continue(config: TransformerConfig, params, cache,
     suffix = jnp.broadcast_to(jnp.asarray(suffix_len, jnp.int32), (B,))
     total = jnp.broadcast_to(jnp.asarray(total_len, jnp.int32), (B,))
     logits, variables = model.apply({"params": params, "cache": cache},
-                                    tokens, mutable=["cache"])
+                                    tokens, *_row_lengths(config, suffix),
+                                    mutable=["cache"])
     new_cache = jax.tree_util.tree_map_with_path(
         lambda path, leaf: (jnp.broadcast_to(total, leaf.shape)
                             .astype(leaf.dtype)
@@ -108,6 +114,12 @@ def prefill_continue(config: TransformerConfig, params, cache,
     last = jnp.take_along_axis(
         logits, (suffix - 1)[:, None, None], axis=1)[:, 0]
     return last, new_cache
+
+
+def _row_lengths(config, lens) -> tuple:
+    """The extra positional argument of a model with a recurrent state:
+    each row's real tokens in this multi-token apply."""
+    return (lens,) if config.has_recurrent_state else ()
 
 
 def _is_key(path, name: str) -> bool:
@@ -246,14 +258,24 @@ def prefill_chunk(config: TransformerConfig, params, cache,
     return last, new_cache
 
 
-def decode_step(config: TransformerConfig, params, cache,
-                token: jnp.ndarray):
-    """One token in, one token's logits out; cache advances by one."""
+def decode_step_stats(config, params, cache, token: jnp.ndarray):
+    """One token in, one token's logits out; cache advances by one.
+    Returns (logits, cache, stats): ``stats`` holds what the model's
+    routed layers counted in this step (``experts_hit`` and
+    ``routed_pairs``, one entry a routed layer) and is empty, adding
+    nothing to the program, for a model that has none."""
     model = _decode_model(config)
     logits, variables = model.apply(
         {"params": params, "cache": cache}, token[:, None],
-        mutable=["cache"])
-    return logits[:, 0], variables["cache"]
+        mutable=["cache", "moe_stats"])
+    stats = {name: sown[0]
+             for name, sown in variables.get("moe_stats", {}).items()}
+    return logits[:, 0], variables["cache"], stats
+
+
+def decode_step(config, params, cache, token: jnp.ndarray):
+    """:func:`decode_step_stats` without the stats."""
+    return decode_step_stats(config, params, cache, token)[:2]
 
 
 def sample_logits(logits: jnp.ndarray, rng: jax.Array, *,
@@ -530,6 +552,12 @@ def _spec_validate(config: TransformerConfig,
     ``generate()``)."""
     if k < 1:
         raise ValueError("draft_len must be >= 1")
+    for name, c in (("target", config), ("draft", draft_config)):
+        if c.has_recurrent_state:
+            raise ValueError(
+                f"speculative decoding rolls a rejected draft back by "
+                f"resetting positions; the {name} model keeps a recurrent "
+                "state that cannot be rolled back")
     if config.vocab_size != draft_config.vocab_size:
         raise ValueError("draft and target must share a vocabulary")
     if true_len is None:

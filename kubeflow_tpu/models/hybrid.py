@@ -1,0 +1,596 @@
+"""A decoder whose layers differ: linear-attention (KDA) and latent
+attention (MLA) mixers by a per-layer pattern, a dense SwiGLU MLP in the
+leading layers and a sigmoid-routed expert MLP with a shared expert after
+them, an untied output head. Served through the same
+``models/decode.py`` / ``DecodeEngine`` path as :class:`Transformer`.
+
+It exists in decode mode only: every apply reads and writes the ``cache``
+collection, whose leaves the model declares
+(``HybridConfig.cache_leaves``; the engine takes each leaf's batch axis
+and idle value from there, never from its rank):
+
+    positions     (B,)                       tokens each row holds
+    latent        (L_mla, B, S_max, W)       a token's normalised kv latent
+                                             (r) | rotated shared rope key
+                                             (p) | 1 / rms of each head's
+                                             key (H) | zeros up to W, the
+                                             next multiple of 128 lanes
+    kda_state     (L_kda, B, H, dk, dv) f32  the delta-rule state
+    kda_conv      (L_kda, B, K - 1, 3 H dk)  last conv inputs of q | k | v
+
+A recurrent state cannot be pulled back the way ``positions`` can, so
+``true_len`` reaches the mixers: past a row's own length a multi-token
+apply leaves ``kda_state`` and ``kda_conv`` as they were.
+
+The layers are few and unlike, so they are unrolled, not scanned; each
+reads and writes its own ``[index]`` slice of the stacked leaves, which
+travel through the engine's K-step scan as its carry.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Optional, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from kubeflow_tpu.models.transformer import (
+    CacheLeaf,
+    RMSNorm,
+    _rotate,
+    rope_tables,
+)
+from kubeflow_tpu.ops.attention import NEG_INF
+from kubeflow_tpu.ops.kda import kda_step
+from kubeflow_tpu.parallel.mesh import DEFAULT_RULES, AxisRules
+
+HIGHEST = jax.lax.Precision.HIGHEST
+L2_EPS = 1e-6
+
+
+@dataclasses.dataclass(frozen=True)
+class HybridConfig:
+    vocab_size: int = 1024            # rows of the vocabulary held here
+    d_model: int = 64
+    n_heads: int = 4
+    head_dim: int = 16                # KDA's dk = dv; a field, not d / H
+    layer_types: Tuple[str, ...] = ("kda", "kda", "kda", "kda", "mla",
+                                    "kda", "kda")
+    first_k_dense: int = 1            # leading layers with the dense MLP
+    d_ff: int = 128                   # dense MLP width
+    max_seq_len: int = 256
+    # MLA
+    kv_lora_rank: int = 32
+    qk_nope_dim: int = 16
+    qk_rope_dim: int = 8
+    v_head_dim: int = 16
+    rope_theta: float = 10000.0
+    # KDA
+    conv_kernel: int = 4
+    kda_lower_bound: float = -5.0
+    kda_chunk: int = 64
+    # routed MLP: the router scores all ``n_experts``; this chip holds
+    # ``experts_held`` = (lo, n), the contiguous range [lo, lo + n), and
+    # computes the part of the result those give (None: all of them)
+    n_experts: int = 16
+    experts_per_token: int = 2
+    n_group: int = 4
+    topk_group: int = 2
+    routed_scaling: float = 2.5
+    norm_topk_prob: bool = True
+    d_expert: int = 32
+    d_shared: int = 32
+    experts_held: Optional[Tuple[int, int]] = None
+    dtype: Any = jnp.bfloat16         # activations
+    param_dtype: Any = jnp.float32
+    rules: AxisRules = DEFAULT_RULES
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        return self.experts_held or (0, self.n_experts)
+
+    @property
+    def latent_width(self) -> int:
+        """A cached token of an MLA layer: the latent, the shared rope
+        key and the per-head key scales, padded to whole 128-lane tiles
+        (the chip lays a leaf whose last axis does not fill the lanes
+        out otherwise than the step reads it, and re-lays it each round)."""
+        return -(-(self.kv_lora_rank + self.qk_rope_dim + self.n_heads)
+                 // 128) * 128
+
+    @property
+    def n_kda(self) -> int:
+        return self.layer_types.count("kda")
+
+    @property
+    def n_mla(self) -> int:
+        return self.layer_types.count("mla")
+
+    @property
+    def n_moe(self) -> int:
+        return len(self.layer_types) - self.first_k_dense
+
+    @property
+    def has_recurrent_state(self) -> bool:
+        """A per-row state that no position indexes (KDA's): such a
+        cache cannot be paged, sliced at a prefix or rolled back, so the
+        paths that do those refuse the model."""
+        return self.n_kda > 0
+
+    def decoder(self) -> "HybridDecoder":
+        """The decode-mode module that ``models/decode.py`` applies."""
+        return HybridDecoder(self)
+
+    def cache_leaves(self, batch: int) -> dict:
+        H, dk = self.n_heads, self.head_dim
+        return {
+            "positions": CacheLeaf((batch,), jnp.int32, 0),
+            "latent": CacheLeaf(
+                (self.n_mla, batch, self.max_seq_len, self.latent_width),
+                self.dtype, 1),
+            "kda_state": CacheLeaf((self.n_kda, batch, H, dk, dk),
+                                   jnp.float32, 1),
+            "kda_conv": CacheLeaf(
+                (self.n_kda, batch, self.conv_kernel - 1, 3 * H * dk),
+                self.dtype, 1),
+        }
+
+    def validate(self) -> None:
+        if set(self.layer_types) - {"kda", "mla"}:
+            raise ValueError(f"unknown mixer in {self.layer_types!r}")
+        lo, n = self.held
+        if lo < 0 or n < 1 or lo + n > self.n_experts:
+            raise ValueError(f"experts_held {self.held} outside the "
+                             f"router's {self.n_experts} outputs")
+        if -self.kda_lower_bound * (SUB - 1) > MAX_EXPONENT:
+            raise ValueError("kda_lower_bound too low for the chunk-wise "
+                             "form's float32 decay products")
+        if self.n_experts % self.n_group or self.qk_rope_dim % 2:
+            raise ValueError("n_group must divide n_experts and the rope "
+                             "dims be even")
+
+
+def _dense(x, w, dtype):
+    return jnp.dot(x.astype(dtype), w.astype(dtype),
+                   preferred_element_type=jnp.float32)
+
+
+def _l2_norm(x):
+    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), -1, keepdims=True)
+                             + L2_EPS)
+
+
+def _swiglu(x, w_gate, w_up, w_down, dtype):
+    h = jax.nn.silu(_dense(x, w_gate, dtype)) * _dense(x, w_up, dtype)
+    return _dense(h, w_down, dtype).astype(dtype)
+
+
+# -- KDA -------------------------------------------------------------------------
+
+def kda_recurrent_step(state, q, k, v, a, beta):
+    """One token of the delta rule with per-channel decay. ``state``
+    (B, H, dk, dv) f32; q, k, a (B, H, dk); v (B, H, dv); beta (B, H).
+    Returns (state, o (B, H, dv))."""
+    state = state * jnp.exp(a)[..., None]
+    # S~^T [k | q] in one pass over the state: o = S^T q = S~^T q + u (k.q)
+    kq = jnp.stack([k, q], axis=-2)                          # (B, H, 2, dk)
+    r = jnp.einsum("bhnk,bhkv->bhnv", kq, state, precision=HIGHEST)
+    u = beta[..., None] * (v - r[..., 0, :])
+    state = state + k[..., None] * u[..., None, :]
+    o = r[..., 1, :] + u * jnp.sum(k * q, -1, keepdims=True)
+    return state, o
+
+
+SUB = 16          # tokens a decay reference serves
+MAX_EXPONENT = 80.0   # exp() of it is finite in float32
+
+
+def kda_chunked(state, q, k, v, a, beta, chunk: int):
+    """The same recurrence over T tokens, chunk by chunk (the WY / UT
+    form): inside a chunk of C tokens with cumulative log-decay G,
+
+        (I + A) U = beta (V - (K exp G) S0),  A[t,s] = beta_t sum_c
+                    k_tc k_sc exp(G_tc - G_sc)  for s < t
+        O  = (Q exp G) S0 + (QK masked s <= t, same decay) U
+        S' = diag(exp G_C) S0 + (K exp(G_C - G))^T U
+
+    With decays down to exp(-5) a token, exp(-G) alone overflows
+    float32, so the decayed products are taken against a reference: rows
+    t of a sub-block of ``SUB`` tokens that starts at token r use
+    (k_t exp(G_t - G_r)) . (k_s exp(G_r - G_s)); the first exponent is
+    <= 0, the second at most (SUB - 1) |lower bound| for the s <= t that
+    count (``HybridConfig.validate`` holds that under MAX_EXPONENT).
+    q, k, a (B, T, H, dk); v (B, T, H, dv); beta (B, T, H); all f32. A
+    token with beta = 0 and a = 0 leaves the state as it was.
+    Returns (state, o (B, T, H, dv))."""
+    B, T, H, dk = q.shape
+    pad = -T % chunk
+    if pad:
+        q, k, v, a = (jnp.pad(y, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                      for y in (q, k, v, a))
+        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
+    nc = (T + pad) // chunk
+    sub = SUB if chunk % SUB == 0 else chunk
+    nb = chunk // sub
+
+    def chunks(y):   # (B, T, H, ...) -> (nc, B, H, C, ...)
+        y = y.reshape((B, nc, chunk) + y.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(y, 1, 0), 3, 2)
+
+    tri = jnp.tril(jnp.ones((chunk, chunk), bool))
+    strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
+    eye = jnp.eye(chunk, dtype=jnp.float32)
+    mm = lambda spec, x, y: jnp.einsum(spec, x, y, precision=HIGHEST)  # noqa: E731
+
+    def body(s0, xs):
+        qc, kc, vc, ac, bc = xs                # (B, H, C, dk) ... (B, H, C)
+        g = jnp.cumsum(ac, axis=-2)
+        blocks = lambda y: y.reshape(B, H, nb, sub, dk)  # noqa: E731
+        g_ref = blocks(g)[..., :1, :]                      # (B, H, nb, 1, dk)
+        rows = jnp.exp(blocks(g) - g_ref)                  # t against its r
+        # every s against r; past MAX_EXPONENT lie only pairs s > t, which
+        # the masks below drop
+        cols = kc[:, :, None] * jnp.exp(jnp.minimum(
+            g_ref - g[:, :, None], MAX_EXPONENT))          # (B, H, nb, C, dk)
+        kk = mm("bhitc,bhisc->bhits", blocks(kc) * rows, cols)
+        qk = mm("bhitc,bhisc->bhits", blocks(qc) * rows, cols)
+        kk = jnp.where(strict, kk.reshape(B, H, chunk, chunk), 0.0)
+        qk = jnp.where(tri, qk.reshape(B, H, chunk, chunk), 0.0)
+        eg = jnp.exp(g)
+        rhs = bc[..., None] * (vc - mm("bhtk,bhkv->bhtv", kc * eg, s0))
+        u = jax.lax.linalg.triangular_solve(
+            eye + bc[..., None] * kk, rhs, left_side=True, lower=True,
+            unit_diagonal=True)
+        o = mm("bhtk,bhkv->bhtv", qc * eg, s0) + mm("bhts,bhsv->bhtv", qk, u)
+        g_end = g[..., -1:, :]
+        s1 = (jnp.exp(g_end[..., 0, :])[..., None] * s0
+              + mm("bhsk,bhsv->bhkv", kc * jnp.exp(g_end - g), u))
+        return s1, o
+
+    state, o = jax.lax.scan(
+        body, state, (chunks(q), chunks(k), chunks(v), chunks(a),
+                      chunks(beta)))
+    o = jnp.moveaxis(jnp.moveaxis(o, 2, 3), 0, 1)       # (B, nc, C, H, dv)
+    return state, o.reshape(B, nc * chunk, H, -1)[:, :T]
+
+
+class KdaMixer(nn.Module):
+    config: HybridConfig
+
+    @nn.compact
+    def __call__(self, x, cache, index: int, lens=None):
+        """x (B, T, D); ``cache`` the dict of stacked leaves, ``index``
+        this layer's place among the KDA layers; ``lens`` (B,) the real
+        tokens of each row (None: all T). Returns (out, cache)."""
+        c = self.config
+        B, T, D = x.shape
+        H, dk = c.n_heads, c.head_dim
+        C, taps = H * dk, c.conv_kernel
+        init = nn.initializers.normal(stddev=D ** -0.5)
+        w_qkv = self.param("qkv_proj", init, (D, 3 * C), c.param_dtype)
+        w_decay = self.param("decay_proj", init, (D, C), c.param_dtype)
+        w_gate = self.param("gate_proj", init, (D, C), c.param_dtype)
+        w_beta = self.param("beta_proj", init, (D, H), c.param_dtype)
+        w_o = self.param("o_proj", init, (C, D), c.param_dtype)
+        w_conv = self.param("conv", nn.initializers.normal(0.4),
+                            (taps, 3 * C), c.param_dtype)
+        a_log = self.param("a_log", nn.initializers.zeros, (H,), jnp.float32)
+        dt_bias = self.param("dt_bias", nn.initializers.zeros, (C,),
+                             jnp.float32)
+
+        # short conv over [the cached tail | this call's inputs]
+        pre = _dense(x, w_qkv, c.dtype).astype(c.dtype)        # (B, T, 3C)
+        seq = jnp.concatenate([cache["kda_conv"][index], pre], axis=1)
+        conv = sum(w_conv[j].astype(jnp.float32) * seq[:, j:j + T]
+                   for j in range(taps))
+        if lens is None:
+            tail = seq[:, T:]
+        else:   # inputs len-3 .. len-1 of the row sit at len .. len+2
+            tail = jax.vmap(lambda row, n: jax.lax.dynamic_slice_in_dim(
+                row, n, taps - 1, 0))(seq, lens)
+        q, k, v = (y.reshape(B, T, H, dk) for y in jnp.split(
+            jax.nn.silu(conv.astype(jnp.float32)), 3, axis=-1))
+        q = _l2_norm(q) * dk ** -0.5
+        k = _l2_norm(k)
+        gate = (_dense(x, w_decay, c.dtype) + dt_bias).reshape(B, T, H, dk)
+        a = c.kda_lower_bound * jax.nn.sigmoid(
+            jnp.exp(a_log)[:, None] * gate)
+        beta = jax.nn.sigmoid(_dense(x, w_beta, c.dtype))       # (B, T, H)
+        if lens is not None:
+            live = jnp.arange(T)[None, :] < lens[:, None]
+            a = jnp.where(live[..., None, None], a, 0.0)
+            beta = jnp.where(live[..., None], beta, 0.0)
+
+        if T == 1:
+            # the kernel updates layer ``index`` of the stacked leaf in place
+            with jax.named_scope("kda.step"):
+                states, o = kda_step(cache["kda_state"], index, q[:, 0],
+                                     k[:, 0], v[:, 0], a[:, 0], beta[:, 0])
+                o = o[:, None]
+        else:
+            with jax.named_scope("kda.prefill"):
+                state, o = kda_chunked(cache["kda_state"][index], q, k, v,
+                                       a, beta, c.kda_chunk)
+            states = cache["kda_state"].at[index].set(state)
+        cache = dict(cache, kda_state=states,
+                     kda_conv=cache["kda_conv"].at[index].set(tail))
+
+        o = RMSNorm(param_dtype=c.param_dtype, name="o_norm")(o)
+        o = jax.nn.sigmoid(_dense(x, w_gate, c.dtype)).reshape(o.shape) * o
+        out = _dense(o.reshape(B, T, C), w_o, c.dtype)
+        return out.astype(c.dtype), cache
+
+
+# -- MLA -------------------------------------------------------------------------
+
+class MlaAttention(nn.Module):
+    config: HybridConfig
+
+    @nn.compact
+    def __call__(self, x, cache, index: int, fresh: bool):
+        """x (B, T, D). ``fresh`` (static): the rows' caches are empty,
+        so the T tokens attend among themselves in the expanded form;
+        otherwise each row's tokens are written at its own position and
+        attend to the whole cached row in the absorbed form (``kv_b``
+        folded into the query and the output), which reads nothing but
+        the latent. Returns (out, cache)."""
+        c = self.config
+        B, T, D = x.shape
+        H, r = c.n_heads, c.kv_lora_rank
+        n, p, vd = c.qk_nope_dim, c.qk_rope_dim, c.v_head_dim
+        init = nn.initializers.normal(stddev=D ** -0.5)
+        w_q = self.param("q_proj", init, (D, H, n + p), c.param_dtype)
+        w_kva = self.param("kv_a_proj", init, (D, r + p), c.param_dtype)
+        w_kvb = self.param("kv_b_proj", nn.initializers.normal(r ** -0.5),
+                           (r, H, n + vd), c.param_dtype)
+        w_gate = self.param("gate_proj", init, (D, H), c.param_dtype)
+        w_o = self.param("o_proj", init, (H, vd, D), c.param_dtype)
+        w_kn = self.param("k_norm", nn.initializers.ones, (n + p,),
+                          c.param_dtype).astype(jnp.float32)
+        eps = RMSNorm.eps
+        ein = lambda spec, a, b: jnp.einsum(  # noqa: E731
+            spec, a.astype(c.dtype), b.astype(c.dtype),
+            preferred_element_type=jnp.float32)
+
+        pos = cache["positions"]                                    # (B,)
+        q_pos = pos[:, None] + jnp.arange(T)[None, :]               # (B, T)
+        sin_t, cos_t = rope_tables(c.max_seq_len, p, c.rope_theta)
+        # an idle row's position runs past the table: clip, its output is
+        # read by nobody
+        sin = jnp.take(sin_t, q_pos, axis=0, mode="clip")[:, :, None, :]
+        cos = jnp.take(cos_t, q_pos, axis=0, mode="clip")[:, :, None, :]
+
+        q = RMSNorm(param_dtype=c.param_dtype, name="q_norm")(
+            ein("btd,dhk->bthk", x, w_q))
+        q_nope, q_rope = q[..., :n], _rotate(q[..., n:], sin, cos)
+        kva = _dense(x, w_kva, c.dtype)
+        lat = RMSNorm(param_dtype=c.param_dtype, name="kv_norm")(
+            kva[..., :r]).astype(c.dtype)                        # (B, T, r)
+        k_r = kva[..., r:]                                       # (B, T, p)
+        k_nope = ein("btr,rhk->bthk", lat, w_kvb[..., :n])       # new tokens
+        inv_rms = jax.lax.rsqrt(
+            (jnp.sum(jnp.square(k_nope), -1)
+             + jnp.sum(jnp.square(k_r), -1)[..., None]) / (n + p) + eps)
+        k_rot = _rotate((k_r * w_kn[n:])[:, :, None, :], sin, cos)[:, :, 0]
+        W = c.latent_width
+        row = jnp.concatenate(
+            [lat, k_rot.astype(c.dtype), inv_rms.astype(c.dtype),
+             jnp.zeros((B, T, W - r - p - H), c.dtype)], -1)
+        scale = (n + p) ** -0.5
+
+        with jax.named_scope("mla.attend"):
+            if fresh:
+                # rows start at 0 and share the slice
+                at = (index, 0, 0, 0)
+                latent = jax.lax.dynamic_update_slice(
+                    cache["latent"], row[None], at)
+                k = jnp.concatenate(
+                    [k_nope * w_kn[:n],
+                     jnp.broadcast_to(k_rot[:, :, None, :], (B, T, H, p))],
+                    -1) * inv_rms[..., None]
+                v = ein("btr,rhv->bthv", lat, w_kvb[..., n:])
+                qf = jnp.concatenate([q_nope, q_rope], -1)
+                s = ein("bqhd,bkhd->bhqk", qf, k) * scale
+                mask = jnp.arange(T)[None, :] <= jnp.arange(T)[:, None]
+                prob = jax.nn.softmax(jnp.where(mask, s, NEG_INF), axis=-1)
+                o = ein("bhqk,bkhv->bqhv", prob, v)
+            else:
+                rows = jnp.arange(B)[:, None]
+                latent = cache["latent"].at[index, rows, q_pos].set(row)
+                q_lat = ein("bthk,rhk->bthr", q_nope * w_kn[:n],
+                            w_kvb[..., :n])
+                # zeros against the scale and pad columns: the products
+                # run over whole cached rows, never over a slice of them
+                qf = jnp.concatenate(
+                    [q_lat, q_rope, jnp.zeros((B, T, H, W - r - p))], -1)
+                s = ein("bqhl,bkl->bhqk", qf, latent[index])
+                k_scale = latent[index][..., r + p:r + p + H]  # (B, S, H)
+                s = s * scale * jnp.swapaxes(
+                    k_scale, 1, 2)[:, :, None, :].astype(jnp.float32)
+                mask = (jnp.arange(c.max_seq_len)[None, None, :]
+                        <= q_pos[:, :, None])                   # (B, T, S)
+                prob = jax.nn.softmax(
+                    jnp.where(mask[:, None], s, NEG_INF), axis=-1)
+                o_lat = ein("bhqk,bkl->bqhl", prob, latent[index])[..., :r]
+                o = ein("bqhr,rhv->bqhv", o_lat, w_kvb[..., n:])
+        cache = dict(cache, latent=latent)
+        gate = jax.nn.sigmoid(_dense(x, w_gate, c.dtype))[..., None]
+        out = ein("bqhv,hvd->bqd", gate * o, w_o)
+        return out.astype(c.dtype), cache
+
+
+# -- routed MLP ------------------------------------------------------------------
+
+def route(scores_logits, bias, c: HybridConfig):
+    """Sigmoid scores, group-limited selection on score + bias, weights
+    from the scores. (N, E) f32 -> (ids (N, K), weights (N, K))."""
+    s = jax.nn.sigmoid(scores_logits)
+    sel = s + bias
+    E = sel.shape[-1]
+    grouped = sel.reshape(-1, c.n_group, E // c.n_group)
+    g_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+    _, g_idx = jax.lax.top_k(g_score, c.topk_group)
+    g_keep = jnp.sum(jax.nn.one_hot(g_idx, c.n_group, dtype=jnp.int32),
+                     axis=1) > 0
+    keep = jnp.repeat(g_keep, E // c.n_group, axis=-1)
+    _, idx = jax.lax.top_k(jnp.where(keep, sel, -jnp.inf),
+                           c.experts_per_token)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    if c.norm_topk_prob:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return idx, c.routed_scaling * w
+
+
+class RoutedMlp(nn.Module):
+    """Routes over all ``n_experts``, holds ``experts_held`` of them and
+    computes what those add for the tokens routed to them, plus the
+    shared expert. Tokens are grouped by expert (one sort, then
+    ``ragged_dot`` over the groups): no token is dropped, and the work
+    follows the routed (token, expert) pairs held here."""
+
+    config: HybridConfig
+
+    @nn.compact
+    def __call__(self, x, live=None):
+        """x (B, T, D); ``live`` (B, T) bool marks real tokens (pad
+        tokens are routed nowhere). Returns (y, experts hit, pairs)."""
+        c = self.config
+        B, T, D = x.shape
+        K, F = c.experts_per_token, c.d_expert
+        lo, n = c.held
+        init = nn.initializers.normal(stddev=D ** -0.5)
+        w_router = self.param("router", init, (D, c.n_experts), jnp.float32)
+        bias = self.param("router_bias", nn.initializers.zeros,
+                          (c.n_experts,), jnp.float32)
+        w_gate = self.param("gate_proj", init, (n, D, F), c.param_dtype)
+        w_up = self.param("up_proj", init, (n, D, F), c.param_dtype)
+        w_down = self.param("down_proj", init, (n, F, D), c.param_dtype)
+        sh = [self.param(f"shared_{name}", init, shape, c.param_dtype)
+              for name, shape in (("gate", (D, c.d_shared)),
+                                  ("up", (D, c.d_shared)),
+                                  ("down", (c.d_shared, D)))]
+        flat = x.reshape(B * T, D)
+        N = B * T
+
+        with jax.named_scope("moe.route"):
+            idx, w = route(jnp.dot(flat.astype(jnp.float32), w_router,
+                                   precision=HIGHEST), bias, c)
+            local = idx - lo
+            held = (local >= 0) & (local < n)
+            if live is not None:
+                held = held & live.reshape(N, 1)
+            key = jnp.where(held, local, n).reshape(N * K)
+            order = jnp.argsort(key)              # held pairs first, by expert
+            sizes = jnp.bincount(key, length=n + 1)[:n].astype(jnp.int32)
+            hit = jnp.sum(sizes > 0).astype(jnp.int32)
+            pairs = jnp.sum(sizes).astype(jnp.int32)
+
+        with jax.named_scope("moe.experts"):
+            xs = jnp.take(flat, order // K, axis=0).astype(c.dtype)
+            rd = lambda a, b: jax.lax.ragged_dot(  # noqa: E731
+                a, b.astype(c.dtype), sizes,
+                preferred_element_type=jnp.float32)
+            h = (jax.nn.silu(rd(xs, w_gate)) * rd(xs, w_up)).astype(c.dtype)
+            ys = rd(h, w_down)                                   # (N K, D)
+            back = jnp.take(ys, jnp.argsort(order), axis=0).reshape(N, K, D)
+            y = jnp.sum(jnp.where(held[..., None],
+                                  back * w[..., None], 0.0), axis=1)
+
+        with jax.named_scope("moe.shared"):
+            y = y.astype(c.dtype) + _swiglu(flat, *sh, c.dtype)
+        return y.reshape(B, T, D), hit, pairs
+
+
+class DenseMlp(nn.Module):
+    config: HybridConfig
+
+    @nn.compact
+    def __call__(self, x):
+        c = self.config
+        D, F = c.d_model, c.d_ff
+        init = nn.initializers.normal(stddev=D ** -0.5)
+        ws = [self.param(name, init, shape, c.param_dtype)
+              for name, shape in (("gate_proj", (D, F)), ("up_proj", (D, F)),
+                                  ("down_proj", (F, D)))]
+        return _swiglu(x, *ws, c.dtype)
+
+
+# -- the decoder -----------------------------------------------------------------
+
+class HybridLayer(nn.Module):
+    config: HybridConfig
+    mixer: str
+    routed: bool
+
+    @nn.compact
+    def __call__(self, x, cache, index: int, lens, fresh: bool):
+        c = self.config
+        h = RMSNorm(param_dtype=c.param_dtype, name="attn_norm")(x)
+        if self.mixer == "kda":
+            out, cache = KdaMixer(c, name="mixer")(h, cache, index, lens)
+        else:
+            out, cache = MlaAttention(c, name="mixer")(h, cache, index,
+                                                       fresh)
+        x = x + out
+        h = RMSNorm(param_dtype=c.param_dtype, name="mlp_norm")(x)
+        if not self.routed:
+            return x + DenseMlp(c, name="mlp")(h), cache, None
+        live = None
+        if lens is not None:
+            live = jnp.arange(x.shape[1])[None, :] < lens[:, None]
+        y, hit, pairs = RoutedMlp(c, name="mlp")(h, live)
+        return x + y, cache, (hit, pairs)
+
+
+class HybridDecoder(nn.Module):
+    """tokens (B, T) -> logits (B, T, V) float32, through the cache."""
+
+    config: HybridConfig
+
+    @nn.compact
+    def __call__(self, tokens: jnp.ndarray,
+                 true_len: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+        c = self.config
+        c.validate()
+        B, T = tokens.shape
+        # a cache made by this very apply is empty: the rows start at 0
+        fresh = not self.has_variable("cache", "positions")
+        leaves = {
+            name: self.variable("cache", name, jnp.full, leaf.shape,
+                                leaf.fill, leaf.dtype)
+            for name, leaf in c.cache_leaves(B).items()}
+        cache = {name: var.value for name, var in leaves.items()}
+        lens = None
+        if true_len is not None:
+            lens = jnp.broadcast_to(jnp.asarray(true_len, jnp.int32), (B,))
+
+        embed = self.param("token_embed",
+                           nn.initializers.normal(c.d_model ** -0.5),
+                           (c.vocab_size, c.d_model), c.param_dtype)
+        head = self.param("lm_head",
+                          nn.initializers.normal(c.d_model ** -0.5),
+                          (c.vocab_size, c.d_model), c.param_dtype)
+        x = jnp.take(embed.astype(c.dtype), tokens, axis=0)
+        seen = {"kda": 0, "mla": 0}
+        stats = []
+        for i, mixer in enumerate(c.layer_types):
+            x, cache, stat = HybridLayer(
+                c, mixer, routed=i >= c.first_k_dense, name=f"layer_{i}")(
+                    x, cache, seen[mixer], lens, fresh)
+            seen[mixer] += 1
+            if stat is not None:
+                stats.append(stat)
+        cache["positions"] = cache["positions"] + (
+            T if lens is None else lens)
+        for name, var in leaves.items():
+            var.value = cache[name]
+        if stats:
+            self.sow("moe_stats", "experts_hit",
+                     jnp.stack([s[0] for s in stats]))
+            self.sow("moe_stats", "routed_pairs",
+                     jnp.stack([s[1] for s in stats]))
+        x = RMSNorm(param_dtype=c.param_dtype, name="final_norm")(x)
+        return jnp.einsum("btd,vd->btv", x, head.astype(c.dtype),
+                          preferred_element_type=jnp.float32)

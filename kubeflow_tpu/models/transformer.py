@@ -42,6 +42,24 @@ from kubeflow_tpu.parallel.mesh import (
 
 
 @dataclasses.dataclass(frozen=True)
+class CacheLeaf:
+    """One leaf of a decode model's ``cache`` collection, as the model
+    declares it. The serving engine takes a leaf's row axis and idle
+    value from here, by the leaf's name, never from its rank."""
+    shape: Tuple[int, ...]
+    dtype: Any
+    batch_axis: Optional[int]      # None: a pool that every row shares
+    fill: int = 0                  # what a cache the model makes holds
+    idle: Optional[int] = None     # what a row with no request holds
+    #                                in the engine (None: ``fill``)
+    heads_axis: Optional[int] = None   # sharded over heads on a mesh
+
+    @property
+    def idle_value(self) -> int:
+        return self.fill if self.idle is None else self.idle
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32000
     d_model: int = 512
@@ -117,6 +135,40 @@ class TransformerConfig:
     def head_dim(self) -> int:
         assert self.d_model % self.n_heads == 0
         return self.d_model // self.n_heads
+
+    has_recurrent_state = False   # every cache leaf is indexed by position
+
+    def decoder(self) -> "Transformer":
+        """The decode-mode module that ``models/decode.py`` applies."""
+        return Transformer(self, decode=True)
+
+    def cache_leaves(self, batch: int,
+                     stack: Optional[Tuple[int, ...]] = None) -> dict:
+        """The decode cache's leaves by name: per-row write
+        ``positions``, ``k`` / ``v`` as ``(B, max_seq_len, KH, Dh)`` rows
+        or, paged, a ``(kv_pages, kv_page_size, KH, Dh)`` pool beside the
+        per-row ``pages`` table (every entry the unmapped sentinel
+        ``kv_pages``; an idle row's position is ``max_seq_len``,
+        disarmed: its writes scatter-drop). ``stack`` is ``(n_layers,)``
+        where one owner holds every layer's leaves (the default under
+        ``scan_layers``)."""
+        if stack is None:
+            stack = (self.n_layers,) if self.scan_layers else ()
+        Smax, KH, Dh = self.max_seq_len, self.n_kv_heads, self.head_dim
+        off = len(stack)
+        if self.kv_page_size:
+            P, ps = self.kv_pages, self.kv_page_size
+            kv = CacheLeaf(stack + (P, ps, KH, Dh), self.dtype, None,
+                           heads_axis=off + 2)
+            return {"positions": CacheLeaf(stack + (batch,), jnp.int32,
+                                           off, idle=Smax),
+                    "pages": CacheLeaf(stack + (batch, Smax // ps),
+                                       jnp.int32, off, fill=P),
+                    "k": kv, "v": kv}
+        kv = CacheLeaf(stack + (batch, Smax, KH, Dh), self.dtype, off,
+                       heads_axis=off + 2)
+        return {"positions": CacheLeaf(stack + (batch,), jnp.int32, off),
+                "k": kv, "v": kv}
 
     def validate(self) -> None:
         if self.n_heads % max(self.n_kv_heads, 1):
@@ -201,26 +253,10 @@ def apply_rope(x: jnp.ndarray, sin: jnp.ndarray, cos: jnp.ndarray) -> jnp.ndarra
 
 def _declare_cache(module: nn.Module, c: TransformerConfig, batch: int,
                    stack: Tuple[int, ...] = ()):
-    """The decode cache's leaves as ``cache`` variables of ``module``,
-    by name: per-row write ``positions``, ``k`` / ``v`` as
-    ``(B, max_seq_len, KH, Dh)`` rows or, paged, a ``(kv_pages,
-    kv_page_size, KH, Dh)`` pool beside the per-row ``pages`` table
-    (every entry the unmapped sentinel ``kv_pages``). ``stack`` is
-    ``(n_layers,)`` where one owner holds every layer's leaves."""
-    Smax, KH, Dh = c.max_seq_len, c.n_kv_heads, c.head_dim
-    if c.kv_page_size:
-        P, ps = c.kv_pages, c.kv_page_size
-        leaves = {"positions": ((batch,), 0, jnp.int32),
-                  "pages": ((batch, Smax // ps), P, jnp.int32),
-                  "k": ((P, ps, KH, Dh), 0, c.dtype),
-                  "v": ((P, ps, KH, Dh), 0, c.dtype)}
-    else:
-        leaves = {"positions": ((batch,), 0, jnp.int32),
-                  "k": ((batch, Smax, KH, Dh), 0, c.dtype),
-                  "v": ((batch, Smax, KH, Dh), 0, c.dtype)}
-    return {name: module.variable("cache", name, jnp.full, stack + shape,
-                                  fill, dtype)
-            for name, (shape, fill, dtype) in leaves.items()}
+    """``c.cache_leaves`` as ``cache`` variables of ``module``."""
+    return {name: module.variable("cache", name, jnp.full, leaf.shape,
+                                  leaf.fill, leaf.dtype)
+            for name, leaf in c.cache_leaves(batch, stack).items()}
 
 
 def _layer_slice(stacked: jnp.ndarray, layer) -> jnp.ndarray:
@@ -648,9 +684,11 @@ class MoeMlp(nn.Module):
         u = jnp.einsum("bsd,edf->bsef", x, w_up.astype(c.dtype))
         h = jax.nn.silu(h) * u
         # batch keeps the dp axis here (expert weights are dp-sharded, so
-        # XLA gathers expert shards within the dp group); a capacity-based
-        # all_to_all dispatch that truly keeps experts resident is the
-        # planned fast path.
+        # XLA gathers expert shards within the dp group). The paths that
+        # keep experts resident exist: capacity dispatch above (drops
+        # tokens past capacity), and models/hybrid.py:RoutedMlp, which
+        # holds a share of the experts and groups routed tokens by expert
+        # (ragged_dot) without dropping any.
         h = _constrain(h, c.rules, "batch", None, None, "expert_mlp")
         y = jnp.einsum("bsef,efd->bsed", h, w_down.astype(c.dtype))
         y = jnp.einsum("bsed,bse->bsd", y, combine)

@@ -72,7 +72,7 @@ import numpy as np
 from kubeflow_tpu.models.decode import (
     arm_slot,
     copy_page,
-    decode_step,
+    decode_step_stats,
     prefill,
     prefill_chunk,
     prefill_continue,
@@ -153,6 +153,15 @@ _recoveries_c = DEFAULT_REGISTRY.counter(
     "engine cache rebuild-and-replay events after a failed donating "
     "device call (each one is a device fault survived, never routine)")
 
+_moe_pairs_c = DEFAULT_REGISTRY.counter(
+    "kftpu_moe_routed_pairs_total",
+    "routed (token, expert) pairs that fell on experts held here, summed "
+    "over the routed layers and the rows of every decode step")
+_moe_hit_c = DEFAULT_REGISTRY.counter(
+    "kftpu_moe_experts_hit_total",
+    "distinct held experts hit, summed over the routed layers of every "
+    "decode step: the expert weights a step had to read")
+
 _END = object()  # per-request stream sentinel
 
 # an ``engine.round`` span's ``<phase>_s`` attrs and the ``phase`` label
@@ -192,11 +201,9 @@ def pow2_bucket(n: int, cap: int) -> int:
     return min(b, cap)
 
 
-def _batch_axis(leaf: jnp.ndarray) -> int:
-    """Cache leaves are ``positions`` (B,)|(L, B) or ``k``/``v``
-    (B, S, KH, Dh)|(L, B, S, KH, Dh) depending on whether layers are
-    stacked (``scan_layers``) — the batch axis is determined by rank."""
-    return {1: 0, 2: 1, 4: 0, 5: 1}[leaf.ndim]
+def _leaf_name(path) -> str:
+    """The name under which the model declared a cache leaf."""
+    return path[-1].key
 
 
 @dataclasses.dataclass
@@ -319,6 +326,10 @@ class DecodeEngine:
         if paged is None:
             paged = os.environ.get("KFTPU_PAGED", "0") not in ("0", "")
         self.paged = bool(paged)
+        if self.paged and config.has_recurrent_state:
+            raise ValueError(
+                "paged=True needs a cache that positions index; this "
+                "model keeps a recurrent state per slot")
         # cache-recovery budget: a donated-cache failure rebuilds the
         # pool and replays in-flight slots this many times before the
         # engine gives up and self-closes (the old, always-close path)
@@ -432,6 +443,9 @@ class DecodeEngine:
             self.kv_pages = 0
             self.paged_attention_impl = "gather"
             self._cfg = config
+        # the model's declaration of its cache leaves: each leaf's row
+        # axis, idle value and head axis, by name
+        self._leaves = self._cfg.cache_leaves(1)
         # burst admission: same-bucket pending requests prefill as ONE
         # batch of up to this many rows. The cap bounds the transient
         # HBM spike (a batch prefill materializes that many extra
@@ -589,8 +603,8 @@ class DecodeEngine:
             instead of one dispatch per member. Pad rows (``valid``
             False) write a slot's current contents back — a no-op."""
 
-            def put(big, small, row, slot, ok):
-                ax = _batch_axis(big)
+            def put(path, big, small, row, slot, ok):
+                ax = self._leaves[_leaf_name(path)].batch_axis
                 piece = jax.lax.dynamic_slice_in_dim(
                     small, row, 1, axis=ax).astype(big.dtype)
                 idx = tuple(slot if a == ax else 0
@@ -601,8 +615,9 @@ class DecodeEngine:
 
             def body(cache, xs):
                 row, slot, ok = xs
-                return jax.tree_util.tree_map(
-                    lambda big, small: put(big, small, row, slot, ok),
+                return jax.tree_util.tree_map_with_path(
+                    lambda path, big, small: put(path, big, small, row,
+                                                 slot, ok),
                     cache, batch_cache), None
 
             cache, _ = jax.lax.scan(
@@ -628,12 +643,14 @@ class DecodeEngine:
         self.prefix_cache_bytes = 0  # bytes currently held
 
         def _insert(engine_cache, row_cache, slot):
-            return jax.tree_util.tree_map(
-                lambda big, row: jax.lax.dynamic_update_slice(
+            def put(path, big, row):
+                ax = self._leaves[_leaf_name(path)].batch_axis
+                return jax.lax.dynamic_update_slice(
                     big, row.astype(big.dtype),
-                    tuple(slot if a == _batch_axis(big) else 0
-                          for a in range(big.ndim))),
-                engine_cache, row_cache)
+                    tuple(slot if a == ax else 0 for a in range(big.ndim)))
+
+            return jax.tree_util.tree_map_with_path(put, engine_cache,
+                                                    row_cache)
 
         self._insert = jax.jit(_insert, donate_argnums=(0,))
 
@@ -641,19 +658,23 @@ class DecodeEngine:
 
         def _step(params, cache, tokens, seeds, step_idx, temps, top_k,
                   top_p):
-            """K decode steps under one jit; returns (cache, (K, B))."""
+            """K decode steps under one jit; returns (cache, (K, B)
+            tokens, stats): what a model's routed layers counted,
+            ``experts_hit`` and ``routed_pairs``, (K, L_moe) each; an
+            empty dict, no output of the program, for a model with
+            none."""
 
             def body(carry, t):
                 cache, tokens = carry
-                logits, cache = decode_step(self._cfg, params, cache,
-                                            tokens)
+                logits, cache, stats = decode_step_stats(
+                    self._cfg, params, cache, tokens)
                 nxt = sample_rows(logits, seeds, step_idx + t, temps,
                                   top_k, top_p)
-                return (cache, nxt), nxt
+                return (cache, nxt), (nxt, stats)
 
-            (cache, _), toks = jax.lax.scan(
+            (cache, _), (toks, stats) = jax.lax.scan(
                 body, (cache, tokens), jnp.arange(K))
-            return cache, toks
+            return cache, toks, stats
 
         def _step_greedy(params, cache, tokens):
             """The all-greedy fast path: no per-row sampler, no vocab
@@ -663,14 +684,14 @@ class DecodeEngine:
 
             def body(carry, _):
                 cache, tokens = carry
-                logits, cache = decode_step(self._cfg, params, cache,
-                                            tokens)
+                logits, cache, stats = decode_step_stats(
+                    self._cfg, params, cache, tokens)
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                return (cache, nxt), nxt
+                return (cache, nxt), (nxt, stats)
 
-            (cache, _), toks = jax.lax.scan(
+            (cache, _), (toks, stats) = jax.lax.scan(
                 body, (cache, tokens), None, length=K)
-            return cache, toks
+            return cache, toks, stats
 
         self._step = jax.jit(_step, donate_argnums=(1,))
         self._step_greedy = jax.jit(_step_greedy, donate_argnums=(1,))
@@ -685,31 +706,20 @@ class DecodeEngine:
         shapes = jax.eval_shape(
             lambda p: prefill(self._cfg, p, probe)[1], params)
 
-        def _leaf_kind(path) -> str:
-            key = getattr(path[-1], "key", None)
-            return key if key in ("positions", "pages") else "kv"
-
         def _engine_shape(path, s):
-            if self.paged:
-                kind = _leaf_kind(path)
-                if kind == "positions":
-                    return s.shape[:-1] + (slots,)
-                if kind == "pages":
-                    return s.shape[:-2] + (slots,) + s.shape[-1:]
-                return s.shape
-            return tuple(slots if a == _batch_axis(s) else d
+            """The leaf at ``slots`` rows (a pool every row shares keeps
+            its shape: that is how paged cache memory decouples from
+            slots x max_len)."""
+            ax = self._leaves[_leaf_name(path)].batch_axis
+            return tuple(slots if a == ax else d
                          for a, d in enumerate(s.shape))
 
         def _init_leaf(path, s):
-            shape = _engine_shape(path, s)
-            if self.paged:
-                kind = _leaf_kind(path)
-                if kind == "positions":
-                    # disarmed: writes past max_seq_len scatter-drop
-                    return jnp.full(shape, Smax, s.dtype)
-                if kind == "pages":
-                    return jnp.full(shape, self.kv_pages, s.dtype)
-            return jnp.zeros(shape, s.dtype)
+            # every row idle (paged: disarmed, writes past max_seq_len
+            # scatter-drop, and no page mapped)
+            return jnp.full(_engine_shape(path, s),
+                            self._leaves[_leaf_name(path)].idle_value,
+                            s.dtype)
 
         def _zeros_tree():
             return jax.tree_util.tree_map_with_path(_init_leaf, shapes)
@@ -721,7 +731,7 @@ class DecodeEngine:
                 int(np.prod(s.shape)) // self.kv_pages
                 * jnp.dtype(s.dtype).itemsize
                 for p, s in jax.tree_util.tree_leaves_with_path(shapes)
-                if _leaf_kind(p) == "kv"))
+                if self._leaves[_leaf_name(p)].batch_axis is None))
             self._prefix_row_bytes = self._page_bytes * self._n_logical
         else:
             # a stored prefix row IS this batch-1 full-context cache —
@@ -742,7 +752,7 @@ class DecodeEngine:
             self._fresh_cache = _zeros_tree
             self._cache = _zeros_tree()
         else:
-            # k/v leaves shard their kv-heads axis (rank-2 from the end)
+            # leaves that declare a heads axis (k/v) shard it
             # per the model's logical rules, so the full-context cache
             # never materializes on one device; shape_aware_spec drops
             # the axis when it doesn't divide (GQA kv heads < tp)
@@ -756,8 +766,9 @@ class DecodeEngine:
             def _sharding(path, s):
                 shape = _engine_shape(path, s)
                 names = [None] * len(shape)
-                if len(shape) >= 4:
-                    names[-2] = "heads"
+                heads = self._leaves[_leaf_name(path)].heads_axis
+                if heads is not None:
+                    names[heads] = "heads"
                 spec = shape_aware_spec(
                     logical_to_mesh_axes(names, config.rules), shape,
                     mesh)
@@ -822,9 +833,9 @@ class DecodeEngine:
         vec_i = jnp.zeros((B,), jnp.int32)
         ones_f = jnp.ones((B,), jnp.float32)
         with self._mesh_ctx():
-            self._cache, _ = self._step_greedy(
+            self._cache, _, _ = self._step_greedy(
                 self._params, self._cache, toks)
-            self._cache, _ = self._step(
+            self._cache, _, _ = self._step(
                 self._params, self._cache, toks, vec_i, vec_i, ones_f,
                 vec_i, ones_f)
 
@@ -858,6 +869,11 @@ class DecodeEngine:
             raise ValueError(
                 f"prefix_len {prefix_len} must be in (0, prompt length "
                 f"{prompt.size}) — the suffix may not be empty")
+        if prefix_len and self.config.has_recurrent_state:
+            raise ValueError(
+                "prefix reuse continues a row from a stored prefix; this "
+                "model keeps a recurrent state, which the prefix store "
+                "does not snapshot")
         if (not self.paged
                 and self._prefix_budget_bytes < self._prefix_row_bytes):
             # cache disabled, or one full-context row alone would bust
@@ -1218,11 +1234,11 @@ class DecodeEngine:
                     self._ensure_pages(i for i, _ in active)
                 with self._mesh_ctx():
                     if all_greedy:
-                        self._cache, toks = self._step_greedy(
+                        self._cache, toks, stats = self._step_greedy(
                             self._params, self._cache,
                             jnp.asarray(self._tokens))
                     else:
-                        self._cache, toks = self._step(
+                        self._cache, toks, stats = self._step(
                             self._params, self._cache,
                             jnp.asarray(self._tokens),
                             jnp.asarray(self._seeds),
@@ -1234,9 +1250,16 @@ class DecodeEngine:
             # them and the device→host read
             marks.append(self.clock())
             with self._annotate("engine.sync"):
-                toks = np.asarray(toks)  # (K, B); the transfer surfaces
-                # device-side failures HERE, while recovery can still
-                # replay
+                # (K, B); the transfer surfaces device-side failures
+                # HERE, while recovery can still replay. The routed
+                # layers' counts come in the SAME readback: device_get
+                # starts every leaf's copy before it waits for one, where
+                # an np.asarray a leaf pays the device-to-host round
+                # trip once each, one after another (and np.sum of a
+                # device array would run, and first build, a reduction
+                # on the device)
+                toks, stats = jax.device_get((toks, stats))
+                moe = {name: int(v.sum()) for name, v in stats.items()}
         except Exception:  # noqa: BLE001 — donated cache consumed
             log.exception("decode step failed")
             if self._maybe_recover("decode step"):
@@ -1303,7 +1326,7 @@ class DecodeEngine:
                         raise
             _occupancy.set(self.active_count, model=self.name)
         self._record_round(marks, wait_s, rows=len(active), k=K,
-                           greedy=all_greedy)
+                           greedy=all_greedy, moe=moe)
         return True
 
     def _annotate(self, name: str):
@@ -1313,14 +1336,16 @@ class DecodeEngine:
         return ann(name) if ann is not None else contextlib.nullcontext()
 
     def _record_round(self, marks: List[float], wait_s: float, *,
-                      rows: int = 0, k: int = 0,
-                      greedy: bool = False) -> None:
+                      rows: int = 0, k: int = 0, greedy: bool = False,
+                      moe: Optional[dict] = None) -> None:
         """Close the round that ``marks`` opened: one ``engine.round``
         span whose five phase durations tile ``[start, end]`` (a phase
         the round never reached is 0; one that a recovery cut short runs
         to the end; ``wait_s`` is carved out of admission's stretch),
         and the same seconds into
-        ``kftpu_engine_round_seconds_total{phase}``."""
+        ``kftpu_engine_round_seconds_total{phase}``. ``moe`` is what a
+        model's routed layers counted over the round's steps
+        (``experts_hit``, ``routed_pairs``)."""
         bounds = marks + [self.clock()]
         secs = dict.fromkeys(_ROUND_PHASES, 0.0)
         for phase, t_a, t_b in zip(_ROUND_PHASES[1:], bounds, bounds[1:]):
@@ -1333,6 +1358,10 @@ class DecodeEngine:
         for phase, sec in secs.items():
             attrs[f"{phase}_s"] = sec
             _round_seconds.inc(sec, model=self.name, phase=phase)
+        if moe:
+            attrs.update(moe)
+            _moe_hit_c.inc(moe["experts_hit"], model=self.name)
+            _moe_pairs_c.inc(moe["routed_pairs"], model=self.name)
         self.tracer.record("engine.round", start=bounds[0],
                            end=bounds[-1], parent=self._run_ctx,
                            attrs=attrs)

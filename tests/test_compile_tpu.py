@@ -121,3 +121,75 @@ def test_k_step_program_copies_no_stack_inside_its_loops(
     forgets its result layout (jax 0.9.0), so the cure is a leaf whose
     last axis fills the lanes."""
     assert _stack_copies(one_chip, row_major) == (0, at_the_boundary)
+
+
+# The hybrid decoder (kubeflow_tpu/models/hybrid.py) at Ling-3.0-flash's
+# published widths, one layer of each kind: a dense-MLP KDA layer, a
+# routed MLA layer, a routed KDA layer; 128 of 512 experts held
+HYBRID = dict(vocab_size=39296, d_model=2560, n_heads=32, head_dim=128,
+              layer_types=("kda", "mla", "kda"), first_k_dense=1, d_ff=6144,
+              max_seq_len=4096, kv_lora_rank=512, qk_nope_dim=128,
+              qk_rope_dim=64, v_head_dim=128, rope_theta=6e6, n_experts=512,
+              experts_per_token=8, n_group=8, topk_group=4, d_expert=768,
+              d_shared=768, experts_held=(0, 128))
+HYBRID_SLOTS = 32
+
+
+def test_hybrid_k_step_program_rewrites_no_leaf_whole(one_chip, no_cache,
+                                                      monkeypatch):
+    """The K-step program of the hybrid decoder, on the TPU's own
+    compiler: no stacked cache leaf (``kda_state``, ``kda_conv``,
+    ``latent``) is copied, inside the loops or at the program's boundary,
+    and the float32 state is written by nothing but the ``kda.step``
+    kernel, in place (one call a KDA layer). The latent leaf's last axis
+    is padded to whole lanes for this: at 576 the chip's default layout
+    differs from the step's and the leaf was re-laid twice a round."""
+    import jax
+    import jax.numpy as jnp
+
+    from kubeflow_tpu.models.decode import decode_step_stats, prefill
+    from kubeflow_tpu.models.hybrid import HybridConfig, HybridDecoder
+    from kubeflow_tpu.ops import kda
+
+    # this process's backend is the CPU: compile the kernel itself, not
+    # its interpreter
+    monkeypatch.setattr(kda, "resolve_interpret", lambda interpret: False)
+    cfg = HybridConfig(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+                       **HYBRID)
+
+    def place(s):
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(place, jax.eval_shape(
+        lambda k: HybridDecoder(cfg).init(k, jnp.zeros((1, 8), jnp.int32)),
+        jax.random.key(0))["params"])
+    cache = jax.tree_util.tree_map(place, jax.eval_shape(
+        lambda p: prefill(cfg, p, jnp.zeros((HYBRID_SLOTS, 1), jnp.int32))[1],
+        params))
+    tokens = place(jax.ShapeDtypeStruct((HYBRID_SLOTS,), jnp.int32))
+
+    def step(params, cache, tokens):
+        def body(carry, _):
+            cache, tokens = carry
+            logits, cache, stats = decode_step_stats(cfg, params, cache,
+                                                     tokens)
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return (cache, nxt), (nxt, stats)
+        (cache, _), out = jax.lax.scan(body, (cache, tokens), None,
+                                       length=K)
+        return cache, out
+
+    text = jax.jit(step, donate_argnums=(1,)).lower(
+        params, cache, tokens).compile().as_text()
+    shapes = {name: "%s[%s]" % ({"float32": "f32", "bfloat16": "bf16"}[
+        str(leaf.dtype)], ",".join(map(str, leaf.shape)))
+        for name, leaf in cache.items() if name != "positions"}
+    assert shapes["latent"] == "bf16[1,32,4096,640]"
+    for name, shape in shapes.items():
+        assert not re.findall(rf"= {re.escape(shape)}\S* copy\(", text), name
+    state = re.escape(shapes["kda_state"])
+    writers = {re.search(r"\s([a-z][a-z0-9\-]*)\(", line).group(1)
+               for line in text.splitlines()
+               if re.search(rf" = \(?{state}", line)}
+    assert writers - {"parameter", "get-tuple-element"} == {"custom-call"}
+    assert len(re.findall(r"%kda\.step[\w.]* = ", text)) == cfg.n_kda

@@ -1142,8 +1142,9 @@ def test_step_returns_the_cache_layout_it_was_given(lm, program, paged):
     kw = dict(paged=True, kv_page_size=8) if paged else {}
     eng = DecodeEngine(config, params, slots=4, steps_per_sync=2,
                        autostart=False, **kw)
-    out, _ = jax.eval_shape(getattr(eng, program), eng._params, eng._cache,
-                            *_step_args(eng)[program])
+    out, _, stats = jax.eval_shape(getattr(eng, program), eng._params,
+                                   eng._cache, *_step_args(eng)[program])
+    assert stats == {}       # no routed layers: nothing beside the tokens
     assert _layout(out) == _layout(eng._cache)
     spec = _by_name(out)
     L, B = config.n_layers, eng.slots
