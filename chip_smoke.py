@@ -784,10 +784,15 @@ def compare_prefill_programs(cfg, params, prompts: List[List[int]],
             cache)
 
     def live_kv(cache, n: int):
-        """Row 0's written k/v at the prompt's real positions."""
+        """Row 0's written k/v at the prompt's real positions (the axis
+        after the rows, whatever lies behind it)."""
+        def live(x, rows):
+            return jax.lax.slice_in_dim(jnp.take(x, 0, axis=rows), 0, n,
+                                        axis=rows)
+
         return np.concatenate([
-            np.asarray(jnp.take(x, 0, axis=leaves[path[-1].key].batch_axis)
-                       [..., :n, :, :], np.float32).ravel()
+            np.asarray(live(x, leaves[path[-1].key].batch_axis),
+                       np.float32).ravel()
             for path, x in jax.tree_util.tree_leaves_with_path(cache)
             if leaves[path[-1].key].heads_axis is not None])
 

@@ -15,7 +15,9 @@ Shapes are the whole design:
   attention can see it (masking is by absolute position);
 - the per-step state is the flax ``cache`` collection the decode-mode
   :class:`~kubeflow_tpu.models.transformer.Transformer` declares (K/V
-  ``(L, B, max_seq_len, KH, Dh)`` + per-row write positions ``(L, B)``).
+  ``(L, B, max_seq_len, KH·Dh)``, a position's KV heads merged on the
+  last axis so that it fills the TPU's lanes at any head size, + per-row
+  write positions ``(L, B)``).
   It is a loop CARRY all the way down: of the K-step scan here, and of
   the layer scan inside the model, where each layer scatters its tokens
   into ``[layer, row, position]`` and reads its rows back out of the

@@ -53,6 +53,8 @@ class CacheLeaf:
     idle: Optional[int] = None     # what a row with no request holds
     #                                in the engine (None: ``fill``)
     heads_axis: Optional[int] = None   # sharded over heads on a mesh
+    head_width: int = 1            # elements of ``heads_axis`` that make
+    #                                one head (a mesh splits whole heads)
 
     @property
     def idle_value(self) -> int:
@@ -105,7 +107,7 @@ class TransformerConfig:
     # of the contiguous shared-start prefill fast path
     ragged_decode: bool = False
     # decode mode only: paged KV cache. 0 => dense per-row cache
-    # (B, max_seq_len, KH, Dh). >0 => the cache is a POOL of
+    # (B, max_seq_len, KH·Dh). >0 => the cache is a POOL of
     # ``kv_pages`` HBM blocks of ``kv_page_size`` tokens each, shared
     # by the batch through a per-row page table ("pages" cache var,
     # (B, max_seq_len/kv_page_size) int32 of physical page ids; the
@@ -145,13 +147,18 @@ class TransformerConfig:
     def cache_leaves(self, batch: int,
                      stack: Optional[Tuple[int, ...]] = None) -> dict:
         """The decode cache's leaves by name: per-row write
-        ``positions``, ``k`` / ``v`` as ``(B, max_seq_len, KH, Dh)`` rows
-        or, paged, a ``(kv_pages, kv_page_size, KH, Dh)`` pool beside the
-        per-row ``pages`` table (every entry the unmapped sentinel
-        ``kv_pages``; an idle row's position is ``max_seq_len``,
-        disarmed: its writes scatter-drop). ``stack`` is ``(n_layers,)``
-        where one owner holds every layer's leaves (the default under
-        ``scan_layers``)."""
+        ``positions``, ``k`` / ``v`` as ``(B, max_seq_len, KH·Dh)`` rows
+        (a position's KV heads side by side on ONE last axis, head
+        ``kvh`` in lanes ``[kvh·Dh, (kvh+1)·Dh)``: the same bytes in the
+        same order as ``(…, KH, Dh)``, declared so that the last axis
+        fills the TPU's 128 lanes at any head size; ``heads_axis`` is
+        that merged axis and ``head_width`` is ``Dh``, so a mesh still
+        splits whole KV heads of it) or, paged, a ``(kv_pages,
+        kv_page_size, KH, Dh)`` pool beside the per-row ``pages`` table
+        (every entry the unmapped sentinel ``kv_pages``; an idle row's
+        position is ``max_seq_len``, disarmed: its writes scatter-drop).
+        ``stack`` is ``(n_layers,)`` where one owner holds every layer's
+        leaves (the default under ``scan_layers``)."""
         if stack is None:
             stack = (self.n_layers,) if self.scan_layers else ()
         Smax, KH, Dh = self.max_seq_len, self.n_kv_heads, self.head_dim
@@ -165,8 +172,8 @@ class TransformerConfig:
                     "pages": CacheLeaf(stack + (batch, Smax // ps),
                                        jnp.int32, off, fill=P),
                     "k": kv, "v": kv}
-        kv = CacheLeaf(stack + (batch, Smax, KH, Dh), self.dtype, off,
-                       heads_axis=off + 2)
+        kv = CacheLeaf(stack + (batch, Smax, KH * Dh), self.dtype, off,
+                       heads_axis=off + 2, head_width=Dh)
         return {"positions": CacheLeaf(stack + (batch,), jnp.int32, off),
                 "k": kv, "v": kv}
 
@@ -263,6 +270,40 @@ def _layer_slice(stacked: jnp.ndarray, layer) -> jnp.ndarray:
     return jax.lax.dynamic_index_in_dim(stacked, layer, 0, keepdims=False)
 
 
+def _merged_step_attention(q, kp, vp, mask):
+    """One token's attention against K / V rows whose KV heads lie
+    merged on the last axis, without un-merging them.
+
+    ``q``: ``(B, KH, G, Dh)``, rotated, the ``G`` query heads of a KV
+    head side by side; ``kp`` / ``vp``: ``(B, T, KH·Dh)``; ``mask``:
+    ``(B, T)``, True where the row may look. Returns ``(B, KH, G, Dh)``.
+    Both products run over the whole merged axis: QKᵀ against a query
+    that is zero outside its own KV head's lanes (``qz[b, kvh·Dh + d, h]
+    = q[b, h, d]`` where ``kvh = h // G``), PV into all ``KH·Dh`` lanes,
+    of which a head keeps its KV head's ``Dh``. The terms added are exact
+    zeros, so the sums are those of the per-head products; grouped
+    queries live in ``qz`` and K and V are never repeated. It is ``KH``
+    times the minimum FLOPs, which a step of one token hides under the
+    bytes of K and V and a prefill would not. Whole KV heads are
+    independent of each other, so a mesh may split them
+    (``shard_kernel``): ``KH`` is read off the shapes.
+    """
+    from kubeflow_tpu.ops.attention import NEG_INF
+
+    B, KH, G, Dh = q.shape
+    own = jnp.eye(KH, dtype=bool)[None, :, None, :, None]
+    # (B, kvh, d, j, g): head (j, g)'s query in KV head kvh's lanes
+    qz = jnp.where(own, q.transpose(0, 1, 3, 2)[:, :, :, None, :],
+                   0).reshape(B, KH * Dh, KH * G)
+    logits = jnp.einsum("btl,blh->bht", kp, qz).astype(jnp.float32)
+    logits = logits * (Dh ** -0.5)
+    logits = jnp.where(mask[:, None, :], logits, NEG_INF)
+    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    full = jnp.einsum("bht,btl->bhl", probs, vp)      # (B, H, KH·Dh)
+    # (B, j, g, kvh, d): head (j, g) keeps the lanes of KV head j
+    return jnp.where(own, full.reshape(B, KH, G, KH, Dh), 0).sum(axis=3)
+
+
 class Attention(nn.Module):
     config: TransformerConfig
     decode: bool = False
@@ -348,6 +389,15 @@ class Attention(nn.Module):
         buffer per leaf: the new tokens are written into it at
         ``[layer, row, position]`` and this layer's rows are read back
         out of it, so a loop that carries it updates it in place.
+
+        ``k`` / ``v`` hold a position's KV heads MERGED on the last axis
+        (``(L, B, Smax, KH·Dh)``, ``TransformerConfig.cache_leaves``),
+        and every form writes its tokens as such rows. The one-token
+        step attends against the merged slice as it lies
+        (:func:`_merged_step_attention`): un-merging it would re-lay a
+        whole layer's K and V out on every step at a head size under 128
+        lanes. The multi-token forms, whose FLOPs are not hidden under
+        the cache's bytes, attend against the ``(B, Smax, KH, Dh)`` view.
         Returns ``(output, cache)``.
         """
         c = self.config
@@ -361,6 +411,9 @@ class Attention(nn.Module):
 
         from kubeflow_tpu.ops.attention import NEG_INF, gqa_repeat
 
+        def merged(x):  # (B, S, KH, Dh) -> the cache's (B, S, KH·Dh) rows
+            return x.reshape(B, S, KH * Dh)
+
         if S == 1:
             # one token per row at its own position
             sin = jnp.take(sin_full, pos, axis=0)[:, None, None, :].astype(
@@ -370,8 +423,8 @@ class Attention(nn.Module):
             q = _rotate(q, sin, cos)
             k = _rotate(k, sin, cos)
             rows = jnp.arange(B)
-            ck = ck.at[layer, rows, pos].set(k[:, 0])
-            cv = cv.at[layer, rows, pos].set(v[:, 0])
+            ck = ck.at[layer, rows, pos].set(merged(k)[:, 0])
+            cv = cv.at[layer, rows, pos].set(merged(v)[:, 0])
             q_pos = pos[:, None]  # (B, 1)
         elif c.ragged_decode:
             # multi-token with per-row starts (speculative verify,
@@ -386,8 +439,8 @@ class Attention(nn.Module):
             q = _rotate(q, sin, cos)
             k = _rotate(k, sin, cos)
             rows2d = jnp.broadcast_to(jnp.arange(B)[:, None], (B, S))
-            ck = ck.at[layer, rows2d, q_pos].set(k)
-            cv = cv.at[layer, rows2d, q_pos].set(v)
+            ck = ck.at[layer, rows2d, q_pos].set(merged(k))
+            cv = cv.at[layer, rows2d, q_pos].set(merged(v))
         else:
             # prefill: rows share a start (a fresh cache starts at 0;
             # the engine's 1-row prefix continuation shares trivially)
@@ -396,21 +449,32 @@ class Attention(nn.Module):
             cos = jax.lax.dynamic_slice_in_dim(cos_full, idx, S, 0)
             q = apply_rope(q, sin, cos)
             k = apply_rope(k, sin, cos)
-            at = (layer, 0, idx, 0, 0)
-            ck = jax.lax.dynamic_update_slice(ck, k[None], at)
-            cv = jax.lax.dynamic_update_slice(cv, v[None], at)
+            at = (layer, 0, idx, 0)
+            ck = jax.lax.dynamic_update_slice(ck, merged(k)[None], at)
+            cv = jax.lax.dynamic_update_slice(cv, merged(v)[None], at)
             q_pos = (idx + jnp.arange(S))[None, :]  # (1, S) → rows share
         cache = dict(cache, k=ck, v=cv,
                      positions=jax.lax.dynamic_update_index_in_dim(
                          cache["positions"], pos + S, layer, 0))
 
-        kc, vc = gqa_repeat(q, _layer_slice(ck, layer),
-                            _layer_slice(cv, layer))
+        kp, vp = _layer_slice(ck, layer), _layer_slice(cv, layer)
+        # (B or 1, S, Smax): per-row causal bound
+        mask = jnp.arange(Smax)[None, None, :] <= q_pos[:, :, None]
+        if S == 1:
+            # on a serving mesh each tp rank attends over its own KV
+            # heads' lanes and their query heads; nothing is exchanged
+            H = q.shape[2]
+            grouped, rows = (None, "heads", None, None), (None, None, "heads")
+            out = shard_kernel(
+                "merged_step_attention", _merged_step_attention,
+                (q.reshape(B, KH, H // KH, Dh), kp, vp, mask[:, 0]),
+                (grouped, rows, rows, (None, None)),
+                (B, KH, H // KH, Dh), grouped, c.rules)
+            return out.reshape(B, 1, H, Dh), cache
+        kc, vc = gqa_repeat(q, kp.reshape(B, Smax, KH, Dh),
+                            vp.reshape(B, Smax, KH, Dh))
         logits = jnp.einsum("bshd,bthd->bhst", q, kc).astype(jnp.float32)
         logits = logits * (Dh ** -0.5)
-        kv_pos = jnp.arange(Smax)
-        # (B or 1, S, Smax): per-row causal bound
-        mask = kv_pos[None, None, :] <= q_pos[:, :, None]
         logits = jnp.where(mask[:, None], logits, NEG_INF)
         probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
         return jnp.einsum("bhst,bthd->bshd", probs, vc), cache

@@ -306,7 +306,9 @@ def shard_kernel(name: str, fn, args, arg_axes, out_shape, out_axes,
     placement is logged once (a warning when an axis was dropped) and
     handed to :func:`record_kernel_placements`. The caller vouches that ``fn``
     is independent along every split dim; nothing is exchanged between
-    devices.
+    devices. (Plain XLA code whose split XLA cannot see goes the same
+    way: the dense decode step's attention over merged KV-head lanes,
+    ``models/transformer.py:_merged_step_attention``.)
     """
     mesh = compat.current_mesh()
     if mesh.size <= 1:  # no mesh (size 0) or a single device

@@ -755,7 +755,8 @@ class DecodeEngine:
             # leaves that declare a heads axis (k/v) shard it
             # per the model's logical rules, so the full-context cache
             # never materializes on one device; shape_aware_spec drops
-            # the axis when it doesn't divide (GQA kv heads < tp)
+            # the axis when it doesn't divide (GQA kv heads < tp),
+            # counted in whole heads of ``head_width`` elements
             from jax.sharding import NamedSharding
 
             from kubeflow_tpu.parallel.mesh import (
@@ -764,14 +765,15 @@ class DecodeEngine:
             )
 
             def _sharding(path, s):
-                shape = _engine_shape(path, s)
+                shape = list(_engine_shape(path, s))
                 names = [None] * len(shape)
-                heads = self._leaves[_leaf_name(path)].heads_axis
-                if heads is not None:
-                    names[heads] = "heads"
+                leaf = self._leaves[_leaf_name(path)]
+                if leaf.heads_axis is not None:
+                    names[leaf.heads_axis] = "heads"
+                    shape[leaf.heads_axis] //= leaf.head_width
                 spec = shape_aware_spec(
-                    logical_to_mesh_axes(names, config.rules), shape,
-                    mesh)
+                    logical_to_mesh_axes(names, config.rules),
+                    tuple(shape), mesh)
                 return NamedSharding(mesh, spec)
 
             sharded_zeros = jax.jit(

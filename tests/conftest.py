@@ -39,3 +39,25 @@ def shard_params(params, mesh):
         lambda x, s: jax.device_put(
             x, NamedSharding(mesh, shape_aware_spec(s, x.shape, mesh))),
         params, specs, is_leaf=lambda x: not isinstance(x, dict))
+
+
+# (n_heads, n_kv_heads, head size) of the dense decode cache's merged K/V
+# axis (KH·Dh lanes): one 128-lane tile's worth to 320 lanes, which is no
+# multiple of 128. Shared by the decode and engine geometry tests.
+KV_GEOMETRIES = {"h4kv4d64": (4, 4, 64), "h6kv3d64": (6, 3, 64),
+                 "h15kv5d64": (15, 5, 64), "h2kv2d128": (2, 2, 128)}
+
+
+def full_forward_greedy(model, params, prompt, n):
+    """Oracle with no cache at all: re-run the full forward per token.
+    ``prompt``: (B, S) tokens; returns the (B, n) greedy continuation."""
+    import jax.numpy as jnp
+
+    tokens = jnp.asarray(prompt, jnp.int32)
+    out = []
+    for _ in range(n):
+        logits = model.apply({"params": params}, tokens)
+        nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+        out.append(nxt)
+        tokens = jnp.concatenate([tokens, nxt[:, None]], axis=1)
+    return jnp.stack(out, axis=1)

@@ -12,13 +12,15 @@ benchmark's own, outside tier-1).
 import os
 import re
 
+import numpy as np
 import pytest
 
-# SmolLM2-1.7B's attention geometry (head size 64: under one 128-lane
-# tile, which is what makes the chip's default layout differ from the
-# one the step wants) at 4 layers
-GEOMETRY = dict(vocab_size=49152, d_model=2048, n_layers=4, n_heads=32,
-                n_kv_heads=32, d_ff=8192, max_seq_len=1024, remat=False)
+# SmolLM2-1.7B's attention width (32 heads of 64: a head is under one
+# 128-lane tile, which is what made the chip's default layout of a
+# ``(…, KH, Dh)`` leaf differ from the one the step wants) at 4 layers,
+# and the same width as 16 heads of 128
+GEOMETRY = dict(vocab_size=49152, d_model=2048, n_layers=4, d_ff=8192,
+                max_seq_len=1024, remat=False)
 SLOTS, K = 8, 8
 
 
@@ -51,36 +53,29 @@ def no_cache():
     compilation_cache.reset_cache()
 
 
-def _stack_copies(one_chip, row_major: bool):
-    """How often the engine's K-step greedy program, compiled for the
-    described chip, copies a buffer of the stacked cache's shape: (inside
-    the loops, in the entry computation). The cache arrives in the chip's
-    default layout or pinned row-major."""
+def _k_step_text(one_chip, n_heads: int):
+    """The engine's K-step greedy program compiled for the described
+    chip, as text, and the cache's ``k`` leaf. Every argument is a bare
+    shape: the cache arrives and leaves in the chip's default layout and
+    nothing is pinned."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.layout import Format, Layout
 
     from kubeflow_tpu.models import Transformer, TransformerConfig
     from kubeflow_tpu.models.decode import decode_step, prefill
 
     cfg = TransformerConfig(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
-                            **GEOMETRY)
+                            n_heads=n_heads, n_kv_heads=n_heads, **GEOMETRY)
 
-    def where(s, fmt):
-        return (Format(Layout(tuple(range(len(s.shape)))), one_chip)
-                if fmt else one_chip)
-
-    def place(s, fmt=False):
-        return jax.ShapeDtypeStruct(s.shape, s.dtype,
-                                    sharding=where(s, fmt))
+    def place(s):
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
 
     params = jax.tree_util.tree_map(place, jax.eval_shape(
         lambda k: Transformer(cfg).init(k, jnp.zeros((1, 8), jnp.int32)),
         jax.random.key(0))["params"])
-    cache = jax.tree_util.tree_map(
-        lambda s: place(s, row_major),
-        jax.eval_shape(lambda p: prefill(
-            cfg, p, jnp.zeros((SLOTS, 1), jnp.int32))[1], params))
+    cache = jax.tree_util.tree_map(place, jax.eval_shape(
+        lambda p: prefill(cfg, p, jnp.zeros((SLOTS, 1), jnp.int32))[1],
+        params))
     tokens = place(jax.ShapeDtypeStruct((SLOTS,), jnp.int32))
 
     def step(params, cache, tokens):
@@ -93,34 +88,72 @@ def _stack_copies(one_chip, row_major: bool):
                                        length=K)
         return cache, out
 
-    out = (jax.tree_util.tree_map(lambda s: where(s, row_major), cache),
-           None)
-    text = jax.jit(step, donate_argnums=(1,), out_shardings=out).lower(
+    text = jax.jit(step, donate_argnums=(1,)).lower(
         params, cache, tokens).compile().as_text()
-    stack = re.escape("bf16[%d,%d,%d,%d,%d]" % (
-        cfg.n_layers, SLOTS, cfg.max_seq_len, cfg.n_kv_heads, cfg.head_dim))
-    loops, _, entry = text.partition("\nENTRY ")
-    copy = rf"= {stack}\S* copy\("
-    return len(re.findall(copy, loops)), len(re.findall(copy, entry))
+    return text, cache["k"]
 
 
-@pytest.mark.parametrize("row_major, at_the_boundary",
-                         [(False, 4), (True, 0)],
-                         ids=["chip_default", "row_major"])
-def test_k_step_program_copies_no_stack_inside_its_loops(
-        one_chip, no_cache, row_major, at_the_boundary):
-    """On the TPU's own compiler: the K-step program copies no buffer of
-    the stacked cache's shape inside its loops (PR 28; before it, K and V
-    were copied on every step). What is left at head size 64 is outside
-    them: the chip's default layout of such a leaf puts the positions
-    minor-most, the loop wants the head minor-most, and the program
-    re-lays K and V out on entry and on exit, four whole-cache copies a
-    round (PERF.md section 5). The control pins the cache row-major, and
-    those four go too: a layout that ``jax.jit`` cannot be given here,
-    because an executable read back from the persistent compile cache
-    forgets its result layout (jax 0.9.0), so the cure is a leaf whose
-    last axis fills the lanes."""
-    assert _stack_copies(one_chip, row_major) == (0, at_the_boundary)
+_RESULT = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = bf16\[([\d,]*)\]\S* "
+                     r"([a-z][a-z\-]*)\(")
+
+
+def _cache_sized_results(text: str, leaf):
+    """What the program makes of the stacked K/V leaf's size or of one
+    layer's slice of it: ``(relayouts inside the loops, relayouts in the
+    entry computation, slices a loop body materializes)``. A relayout is
+    a ``copy`` or ``transpose`` with such a result, wherever it stands (a
+    fusion's body too); a materialized slice is any instruction of a
+    computation that is no fusion's body and not the entry, so a loop's
+    body or condition, whose bfloat16 result has one layer's elements.
+    Only results that keep the leaf's rows and positions as dimensions
+    count. The stack's in-place writers give results of the stack's size
+    and are no relayout."""
+    stack = int(np.prod(leaf.shape))
+    one_layer = stack // leaf.shape[0]
+    relayouts = {False: [], True: []}
+    slices, entry, fused = [], False, False
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            entry = line.startswith("ENTRY ")
+            fused = "fused_computation" in line.split(" ", 1)[0]
+            continue
+        m = _RESULT.match(line)
+        if not m:
+            continue
+        dims = [int(d) for d in m.group(1).split(",") if d]
+        size = int(np.prod(dims))
+        if not set(leaf.shape[1:3]) <= set(dims):
+            continue    # no rows x positions: a weight of the same size
+        if size in (stack, one_layer) and m.group(2) in ("copy",
+                                                          "transpose"):
+            relayouts[entry].append(line.strip())
+        elif (size == one_layer and not entry and not fused
+              and m.group(2) not in ("parameter", "get-tuple-element")):
+            slices.append(line.strip())
+    return relayouts[False], relayouts[True], slices
+
+
+@pytest.mark.parametrize("n_heads", [32, 16], ids=["head64", "head128"])
+def test_k_step_program_relays_no_stack_and_no_layer_slice(
+        one_chip, no_cache, n_heads):
+    """On the TPU's own compiler, the cache in the chip's default layout
+    and nothing pinned: the K-step program copies or transposes neither
+    the stacked K/V nor one layer's slice of it, inside its loops or at
+    its boundary, and no loop body materializes a slice (the attention's
+    two products read K and V where they lie). At head size 64 the
+    ``(…, KH, Dh)`` leaf failed this twice: its default layout put the
+    positions minor-most, so the program re-laid K and V out on entry and
+    on exit (four whole-stack copies a round, PR 28), and inside a
+    program a head of 64 is padded to the 128-lane tile. The leaf's
+    declared shape is the lever (``TransformerConfig.cache_leaves``: KV
+    heads merged on the last axis), since a layout pinned through
+    ``jax.jit`` does not survive the persistent compile cache (jax
+    0.9.0). Head size 128 at the same width is the same leaf."""
+    text, leaf = _k_step_text(one_chip, n_heads)
+    assert leaf.shape == (GEOMETRY["n_layers"], SLOTS,
+                          GEOMETRY["max_seq_len"], GEOMETRY["d_model"])
+    in_loops, at_the_boundary, slices = _cache_sized_results(text, leaf)
+    assert (in_loops, at_the_boundary, slices) == ([], [], [])
 
 
 # The hybrid decoder (kubeflow_tpu/models/hybrid.py) at Ling-3.0-flash's

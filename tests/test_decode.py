@@ -23,6 +23,8 @@ from kubeflow_tpu.models.decode import (
 )
 from kubeflow_tpu.serving import transformer_export_config
 
+from conftest import KV_GEOMETRIES, full_forward_greedy
+
 
 def small_config(**kw):
     base = dict(vocab_size=97, d_model=32, n_layers=2, n_heads=4,
@@ -40,18 +42,6 @@ def setup():
                                 config.vocab_size)
     params = model.init(jax.random.key(0), prompt)["params"]
     return config, model, params, prompt
-
-
-def full_forward_greedy(model, params, prompt, n):
-    """Oracle: re-run the full (non-cached) forward each step."""
-    tokens = prompt
-    out = []
-    for _ in range(n):
-        logits = model.apply({"params": params}, tokens)
-        nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-        out.append(nxt)
-        tokens = jnp.concatenate([tokens, nxt[:, None]], axis=1)
-    return jnp.stack(out, axis=1)
 
 
 @pytest.mark.slow  # multi-second XLA compiles; tier-1 runs the fast twin paths
@@ -156,16 +146,53 @@ def test_unscanned_layers_decode(setup):
     np.testing.assert_array_equal(got, want)
 
 
+# (n_heads, n_kv_heads, head size): the merged K/V axis is KH·Dh lanes
+GEOMETRIES = {"h4kv2d8": (4, 2, 8), **KV_GEOMETRIES}
+
+
+def geometry_config(name, **kw):
+    H, KH, Dh = GEOMETRIES[name]
+    return small_config(n_heads=H, n_kv_heads=KH, d_model=H * Dh, **kw)
+
+
+def layer0_kv(config, params, tokens):
+    """Layer 0's K (rotated) and V of ``tokens`` ``(B, T)`` as
+    ``(B, T, KH, Dh)``, computed from the parameters alone: what a cache
+    laid out ``(…, KH, Dh)`` held at those positions."""
+    from kubeflow_tpu.models.transformer import apply_rope, rope_tables
+
+    block = (jax.tree_util.tree_map(lambda a: a[0], params["blocks"])
+             if config.scan_layers else params["block_0"])
+    x = jnp.take(params["token_embed"], tokens, axis=0)
+    x = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + 1e-6)
+    x = x * block["attn_norm"]["scale"]
+    k = jnp.einsum("bsd,dhk->bshk", x, block["attn"]["k_proj"])
+    v = jnp.einsum("bsd,dhk->bshk", x, block["attn"]["v_proj"])
+    sin, cos = rope_tables(tokens.shape[1], config.head_dim,
+                           config.rope_theta)
+    return apply_rope(k, sin, cos), v
+
+
+@pytest.mark.parametrize("geometry", sorted(GEOMETRIES))
 @pytest.mark.parametrize("scan_layers", [True, False],
                          ids=["carried", "per_layer"])
-def test_prefix_then_suffix_then_steps_continue_one_cache(scan_layers):
+def test_prefix_then_suffix_then_steps_continue_one_cache(scan_layers,
+                                                          geometry):
     """prefill → prefill_continue → decode steps hand ONE cache along:
-    same layout at every stage, and the tokens and live K/V of a
-    prompt prefilled whole."""
-    config = small_config(scan_layers=scan_layers)
+    same layout at every stage, the tokens and live K/V of a prompt
+    prefilled whole, and the logits of the full forward; at every
+    geometry of the merged K/V axis (one head's lanes to 320, a width
+    that is no multiple of 128), whose ``(…, KH, Dh)`` view holds what
+    the parameters say."""
+    config = geometry_config(geometry, scan_layers=scan_layers)
+    model = Transformer(config)
     prompt = jax.random.randint(jax.random.key(1), (1, 9), 0,
                                 config.vocab_size)
-    params = Transformer(config).init(jax.random.key(0), prompt)["params"]
+    params = model.init(jax.random.key(0), prompt)["params"]
+    contract = config.cache_leaves(1)
+    KH, Dh = config.n_kv_heads, config.head_dim
+    assert contract["k"].shape[-1] == KH * Dh == contract["v"].shape[-1]
+    assert contract["k"].head_width == Dh
 
     def steps(logits, cache):
         toks = []
@@ -178,6 +205,11 @@ def test_prefix_then_suffix_then_steps_continue_one_cache(scan_layers):
 
     def layout(c):
         return jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), c)
+
+    def written(path, leaf, n):
+        """The leaf's first ``n`` positions: the axis after the rows."""
+        return jax.lax.slice_in_dim(
+            leaf, 0, n, axis=contract[path[-1].key].batch_axis + 1)
 
     whole = steps(*prefill(config, params, prompt))
     _, cache = prefill(config, params, prompt[:, :5])
@@ -194,10 +226,47 @@ def test_prefix_then_suffix_then_steps_continue_one_cache(scan_layers):
         if path[-1].key == "positions":
             np.testing.assert_array_equal(got, np.full(got.shape, 12))
             np.testing.assert_array_equal(got, want)
-        else:   # (..., B, Smax, KH, Dh): the 12 written positions
-            np.testing.assert_allclose(got[..., :12, :, :],
-                                       want[..., :12, :, :],
+        else:   # the 12 written positions, wherever the leaf keeps them
+            np.testing.assert_allclose(written(path, got, 12),
+                                       written(path, want, 12),
                                        rtol=1e-4, atol=1e-5)
+
+    seen = jnp.concatenate([prompt, jnp.asarray([split[0]], jnp.int32)],
+                           axis=1)                       # 12 tokens
+    full = model.apply({"params": params}, seen)
+    np.testing.assert_allclose(split[1], full[:, -1], rtol=1e-4, atol=1e-5)
+    first = jax.tree_util.tree_map(
+        lambda a: a[0], split[2]) if scan_layers else (
+            split[2]["block_0"]["attn"])
+    for name, want in zip("kv", layer0_kv(config, params, seen)):
+        got = first[name][:, :12].reshape(1, 12, KH, Dh)
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5,
+                                   err_msg=name)
+
+
+@pytest.mark.parametrize("geometry", sorted(KV_GEOMETRIES))
+def test_speculative_rounds_at_every_kv_geometry(geometry):
+    """The speculative verify writes several tokens a row from ragged
+    starts into the merged K/V rows and attends against their
+    ``(…, KH, Dh)`` view; a draft's steps take the one-token form. The
+    stream is the target's full-forward greedy stream at every width."""
+    from kubeflow_tpu.models.decode import speculative_generate
+
+    config = geometry_config(geometry, max_seq_len=48)
+    draft = geometry_config(geometry, max_seq_len=48, n_layers=1)
+    model = Transformer(config)
+    prompt = jnp.asarray([[5, 11, 17, 3], [9, 2, 0, 0]], jnp.int32)
+    lens = jnp.asarray([4, 2], jnp.int32)
+    params = model.init(jax.random.key(0), prompt)["params"]
+    draft_params = Transformer(draft).init(jax.random.key(1),
+                                           prompt)["params"]
+    got, stats = speculative_generate(
+        config, params, draft, draft_params, prompt, max_new_tokens=7,
+        draft_len=3, true_len=lens)
+    assert stats["rounds"] >= 2
+    for i, n in enumerate([4, 2]):
+        want = full_forward_greedy(model, params, prompt[i:i + 1, :n], 7)
+        np.testing.assert_array_equal(np.asarray(got)[i:i + 1], want)
 
 
 def test_moe_decode(setup):

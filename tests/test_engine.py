@@ -24,6 +24,8 @@ from kubeflow_tpu.models import Transformer, TransformerConfig
 from kubeflow_tpu.models.decode import generate
 from kubeflow_tpu.serving.engine import DecodeEngine
 
+from conftest import KV_GEOMETRIES, full_forward_greedy
+
 
 @pytest.fixture(scope="module")
 def lm():
@@ -354,6 +356,10 @@ def test_engine_on_sharded_mesh(lm):
     sharded = shard_params(params, mesh)
     eng = DecodeEngine(config, sharded, slots=2, mesh=mesh,
                        autostart=False)
+    # tp=4 divides the merged k/v axis (2 heads x 8) but no whole head:
+    # the leaves replicate, as the heads they hold cannot be split
+    assert all(leaf.sharding.is_fully_replicated
+               for leaf in jax.tree_util.tree_leaves(eng._cache))
     r1 = eng.submit([5, 11, 17], max_new=6)
     r2 = eng.submit([3, 2, 9, 23], max_new=4)
     for _ in range(10):
@@ -369,7 +375,7 @@ def test_engine_on_sharded_mesh(lm):
                         autostart=False)
     kv_specs = [leaf.sharding.spec
                 for leaf in jax.tree_util.tree_leaves(eng2._cache)
-                if leaf.ndim >= 4]
+                if leaf.ndim >= 3]
     assert kv_specs and all("tp" in str(s) for s in kv_specs), kv_specs
     r3 = eng2.submit([5, 11, 17], max_new=6)
     for _ in range(8):
@@ -1048,7 +1054,8 @@ def test_step_program_updates_the_cache_in_place(lm, program):
     eng = DecodeEngine(config, params, slots=4, steps_per_sync=4,
                        autostart=False)
     stack = _by_name(eng._cache)["k"]
-    assert stack.ndim == 5 and stack.dtype == jnp.float32
+    assert stack.shape == config.cache_leaves(eng.slots)["k"].shape
+    assert stack.dtype == jnp.float32
     shape = "f32[%s]" % ",".join(str(d) for d in stack.shape)
     text = getattr(eng, program).lower(
         eng._params, eng._cache, *_step_args(eng)[program]
@@ -1130,6 +1137,48 @@ def test_carried_cache_equals_per_layer_buffers(lm, paged, temperature):
             # rounding (seen: 14 of 3072 elements one ulp apart)
             np.testing.assert_allclose(np.asarray(got), np.asarray(leaf),
                                        rtol=1e-5, atol=1e-6, err_msg=name)
+
+
+@pytest.mark.parametrize("geometry", sorted(KV_GEOMETRIES))
+def test_greedy_tokens_at_every_kv_geometry(geometry):
+    """Batch admission, row admission into a reused slot and a prefix
+    miss and hit, on two slots: every request's greedy tokens are those
+    of the full forward with no cache, whatever the width of the merged
+    K/V axis, and the engine's leaves are the contract's."""
+    H, KH, Dh = KV_GEOMETRIES[geometry]
+    config = TransformerConfig(vocab_size=97, d_model=H * Dh, n_layers=2,
+                               n_heads=H, n_kv_heads=KH, d_ff=64,
+                               max_seq_len=48, dtype=jnp.float32,
+                               remat=False)
+    params = Transformer(config).init(
+        jax.random.key(0), np.zeros((1, 8), np.int32))["params"]
+    eng = DecodeEngine(config, params, slots=2, steps_per_sync=2,
+                       autostart=False)
+    for name, leaf in config.cache_leaves(eng.slots).items():
+        assert _by_name(eng._cache)[name].shape == leaf.shape, name
+    assert _by_name(eng._cache)["k"].shape == (2, 2, 48, KH * Dh)
+
+    sys_prompt = [7, 3, 19, 4]
+    burst = [[5, 11, 17], [3, 2, 9], [13, 1, 8]]    # one bucket: a batch
+    late = [9, 23, 41, 7, 2]                        # alone: the row path
+    reqs = [(p, eng.submit(p, max_new=5)) for p in burst]
+    for _ in range(6):
+        eng.run_once(timeout=0.01)
+    reqs.append((late, eng.submit(late, max_new=4)))
+    for tail in ([5, 11], [9, 23, 2]):              # a miss, then a hit
+        for _ in range(6):
+            eng.run_once(timeout=0.01)
+        reqs.append((sys_prompt + tail,
+                     eng.submit(sys_prompt + tail, max_new=4,
+                                prefix_len=4)))
+    for _ in range(8):
+        eng.run_once(timeout=0.01)
+    for prompt, req in reqs:
+        want = full_forward_greedy(Transformer(config), params, [prompt],
+                                   len(req.result()))
+        assert req.result() == np.asarray(want)[0].tolist(), prompt
+    assert eng.batch_prefills >= 1
+    assert (eng.prefix_misses, eng.prefix_hits) == (1, 1)
 
 
 @pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
