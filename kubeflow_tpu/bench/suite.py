@@ -796,7 +796,7 @@ def engine_throughput(config, params, prompts, *, slots: int,
         # fills every free slot, and each request's first token is
         # emitted during its prefill sample) — the number batched
         # admission improves
-        eng._admit(0.01)
+        eng._admit(None)
     engine_drain(eng)
     total = sum(len(r.result()) for r in reqs)
     dt = time.perf_counter() - t0
@@ -902,7 +902,7 @@ def bench_decode_engine(concurrency: int = 48, slots: int = 32,
     # first lever was bounded-vs-exact-sort (~2.4× tax for correct
     # sampling at slots=32); the fused Pallas kernel
     # (ops/sampling.py) is the exact path that must close that gap.
-    bound = int(os.environ.get("KFTPU_SAMPLER_BOUND", "64"))
+    bound = 64  # DecodeEngine's default sampler_bound
     # the headline greedy run keeps its request ledger: the artifact's
     # "requests" block is its per-phase breakdown (docs/OBSERVABILITY.md
     # "Request lifecycle")

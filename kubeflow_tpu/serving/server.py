@@ -516,7 +516,8 @@ class ModelRepository:
                  warmup_batches: Tuple[int, ...] = (),
                  decode_slots: int = 0,
                  decode_steps_per_sync: int = 1,
-                 decode_mesh=None) -> None:
+                 decode_mesh=None,
+                 engine_options: Optional[Dict[str, Any]] = None) -> None:
         self.base_path = base_path
         self.poll_interval_s = poll_interval_s
         # padded batch buckets to precompile at load time, before the new
@@ -537,6 +538,8 @@ class ModelRepository:
         # (KFTPU_SERVING_MESH, e.g. "tp=4"); params are sharded once at
         # engine creation via the models' logical partition specs
         self.decode_mesh = decode_mesh
+        # further DecodeEngine keywords (the deployment's cache sizing)
+        self.engine_options = dict(engine_options or {})
         self._models: Dict[str, LoadedModel] = {}
         self._pinned: Dict[Tuple[str, int], LoadedModel] = {}
         self._engines: Dict[Tuple[str, int], Any] = {}
@@ -594,7 +597,7 @@ class ModelRepository:
                                # same opt-in as predict bucket warmup:
                                # compile both step programs up front
                                precompile=bool(self.warmup_batches),
-                               name=name)
+                               name=name, **self.engine_options)
             with self._lock:
                 if not allowed_locked():
                     race = None  # retired while we were building
@@ -821,14 +824,16 @@ class ModelServer:
                  pin_version: Optional[int] = None,
                  warmup: bool = False, decode_slots: int = 0,
                  decode_steps_per_sync: int = 1,
-                 decode_mesh=None) -> None:
+                 decode_mesh=None,
+                 engine_options: Optional[Dict[str, Any]] = None) -> None:
         buckets = tuple(b for b in _PAD_BUCKETS if b <= max_batch_size)
         self.repo = ModelRepository(base_path, poll_interval_s=poll_interval_s,
                                     pin_version=pin_version,
                                     warmup_batches=buckets if warmup else (),
                                     decode_slots=decode_slots,
                                     decode_steps_per_sync=decode_steps_per_sync,
-                                    decode_mesh=decode_mesh)
+                                    decode_mesh=decode_mesh,
+                                    engine_options=engine_options)
         self.port = port
         self.max_batch_size = max_batch_size
         self._httpd: Optional[ThreadingHTTPServer] = None
@@ -1043,32 +1048,48 @@ def parse_pin_version(raw: Optional[str]) -> Optional[int]:
     return int(digits)
 
 
+def server_options(env) -> Dict[str, Any]:
+    """:class:`ModelServer`'s keywords from a pod's environment
+    (``os.environ``): every deployment setting is read HERE, the decode
+    engine reads none. A cache-sizing name (docs/SERVING.md "Knobs")
+    left unset or empty leaves its keyword out: the engine's default."""
+    engine_options: Dict[str, Any] = {}
+    if env.get("KFTPU_PAGED", "0") not in ("0", ""):
+        engine_options["paged"] = True  # fleet-wide, no code change
+    for key, name in (("kv_page_size", "KFTPU_KV_PAGE_SIZE"),
+                      ("kv_pages", "KFTPU_KV_PAGES"),
+                      ("prefill_chunk_tokens", "KFTPU_PREFILL_CHUNK"),
+                      ("prefix_cache_bytes", "KFTPU_PREFIX_CACHE_BYTES")):
+        if env.get(name):
+            engine_options[key] = int(env[name])
+    return dict(
+        port=int(env.get("KFTPU_REST_PORT", "8500")),
+        max_batch_size=int(env.get("KFTPU_MAX_BATCH_SIZE", "8")),
+        pin_version=parse_pin_version(env.get("KFTPU_MODEL_VERSION")),
+        warmup=env.get("KFTPU_WARMUP", "1") != "0",
+        # continuous batching is the production default; 0 falls back
+        # to whole-request bucketed batches
+        decode_slots=int(env.get("KFTPU_DECODE_SLOTS", "8")),
+        decode_steps_per_sync=int(
+            env.get("KFTPU_DECODE_STEPS_PER_SYNC", "4")),
+        # "tp=4": serve LMs tensor-parallel over the pod's chips
+        # (params + KV cache sharded)
+        decode_mesh=parse_serving_mesh(env.get("KFTPU_SERVING_MESH")),
+        engine_options=engine_options)
+
+
 def main() -> None:
     logging.basicConfig(level=logging.INFO)
     base = os.environ.get("KFTPU_MODEL_BASE_PATH", "/models")
-    port = int(os.environ.get("KFTPU_REST_PORT", "8500"))
     grpc_port = int(os.environ.get("KFTPU_GRPC_PORT", "9000"))
-    max_batch = int(os.environ.get("KFTPU_MAX_BATCH_SIZE", "8"))
     # version reloads and pod restarts reuse compiled executables; the
     # pod places the cache with JAX_COMPILATION_CACHE_DIR
     from kubeflow_tpu.utils.compile_cache import enable_compile_cache
 
     log.info("XLA compile cache at %s", enable_compile_cache())
-    server = ModelServer(base, port=port, max_batch_size=max_batch,
-                         pin_version=parse_pin_version(
-                             os.environ.get("KFTPU_MODEL_VERSION")),
-                         warmup=os.environ.get("KFTPU_WARMUP", "1") != "0",
-                         # continuous batching is the production default;
-                         # 0 falls back to whole-request bucketed batches
-                         decode_slots=int(
-                             os.environ.get("KFTPU_DECODE_SLOTS", "8")),
-                         decode_steps_per_sync=int(
-                             os.environ.get("KFTPU_DECODE_STEPS_PER_SYNC",
-                                            "4")),
-                         # "tp=4": serve LMs tensor-parallel over the
-                         # pod's chips (params + KV cache sharded)
-                         decode_mesh=parse_serving_mesh(
-                             os.environ.get("KFTPU_SERVING_MESH")))
+    opts = server_options(os.environ)
+    max_batch = opts["max_batch_size"]
+    server = ModelServer(base, **opts)
     server.start()
     grpc_server = None  # keep the reference: grpc.Server dies when GC'd
     if grpc_port:

@@ -207,9 +207,14 @@ def test_concurrent_clients_share_one_decode_step(lm):
         eng.close()
 
 
-def test_close_fails_inflight_requests(lm):
+_PAGED_KW = dict(paged=True, kv_page_size=8, prefill_chunk_tokens=8)
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+def test_close_fails_inflight_requests(lm, paged):
     config, params = lm
-    eng = DecodeEngine(config, params, slots=2, autostart=False)
+    eng = DecodeEngine(config, params, slots=2, autostart=False,
+                       **(_PAGED_KW if paged else {}))
     req = eng.submit([5, 11], max_new=8)
     eng.run_once(timeout=0.01)  # admitted, partially decoded
     eng.close()
@@ -457,7 +462,7 @@ def test_burst_admission_sampled_matches_row_path(lm):
     SAME first token as the row path (same fold_in(seed, 0), same
     bounded sampler) — the reproducibility contract survives batching."""
     config, params = lm
-    # row path: submit alone (singleton group -> _admit_one)
+    # row path: submit alone (singleton group -> RowCache._admit_row)
     eng1 = DecodeEngine(config, params, slots=4, autostart=False)
     solo = eng1.submit([5, 11, 17], max_new=6, temperature=0.8, seed=42)
     for _ in range(8):
@@ -955,6 +960,67 @@ def test_rounds_hang_off_one_engine_run_root(lm):
                           and r.parent_id == run.span_id for r in rounds)
     assert run.start <= rounds[0].start and rounds[-1].end <= run.end
     assert "engine.round" not in {s.name for s in collector.roots()}
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+def test_the_round_loop_keeps_its_contract_over_either_cache(lm, paged):
+    """The loop's contract, once, for both cache managers: a greedy
+    stream through ``run_once`` equals ``generate``; one injected step
+    failure is survived by replay, with the same tokens; the engine ends
+    with nothing active, mid-admission or pending, and one closed
+    ``engine.run`` root over its rounds."""
+    config, params = lm
+    eng, collector = _round_engine(
+        lm, f"loop-contract-{paged}", slots=2,
+        **(_PAGED_KW if paged else {}))
+    assert eng.paged is paged and eng.snapshot().get("paged", False) is paged
+    prompts = [[5, 11, 17], [3, 2, 9, 23, 7, 13, 19, 29, 31]]
+    reqs = [eng.submit(p, max_new=8) for p in prompts]
+    while not all(s is not None for s in eng._active):
+        assert eng.run_once(timeout=0.01)    # both streams are decoding
+    real = eng._step_greedy
+
+    def boom(*a, **kw):
+        raise RuntimeError("injected donating-call failure")
+
+    eng._step_greedy = boom
+    assert eng.run_once(timeout=0.01) is True    # fails, rebuilds, replays
+    eng._step_greedy = real
+    assert eng.recoveries == 1 and not eng.closed
+    while eng.run_once(timeout=0.01):
+        pass
+    for p, r in zip(prompts, reqs):
+        assert r.result() == _oracle(config, params, p, 8), p
+    assert eng.active_count == eng.pending_count == 0
+    snap = eng.snapshot()
+    assert (snap["active_slots"], snap["pending"], snap["recoveries"],
+            snap["closed"]) == (0, 0, 1, False)
+    if paged:
+        eng._pool.check_idle()
+        assert snap["pages_in_use"] == snap["prefill_slots"] == 0
+    # the recovered round is a round like any other, under the one root
+    assert not [s for s in collector.spans() if s.name == "engine.run"]
+    eng.close()
+    (run,) = [s for s in collector.spans() if s.name == "engine.run"]
+    rounds = _rounds(collector)
+    assert run.attrs["rounds"] == eng.rounds_total == len(rounds)
+    assert all(r.parent_id == run.span_id for r in rounds)
+    assert run.attrs["steps"] == eng.steps_total
+
+
+def test_engine_options_do_not_come_from_the_environment(lm, monkeypatch):
+    """A keyword left out takes the default the code has, whatever the
+    process environment says: deployment names are read by
+    ``serving/server.py:server_options`` and arrive as keywords."""
+    config, params = lm
+    monkeypatch.setenv("KFTPU_PAGED", "1")
+    monkeypatch.setenv("KFTPU_SAMPLER_IMPL", "exact_sort")
+    monkeypatch.setenv("KFTPU_ADMIT_BATCH", "1")
+    eng = DecodeEngine(config, params, slots=2, autostart=False)
+    assert eng.paged is False and eng.kv_page_size == 0
+    assert eng.sampler_impl == "bounded" and eng.sampler_bound == 64
+    assert eng.admit_batch_max == 8
+    eng.close()
 
 
 def test_admit_spans_name_their_round(lm):

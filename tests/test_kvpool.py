@@ -236,7 +236,7 @@ def test_trie_evicts_leaf_first_lru():
 
 def test_eviction_pressure_racing_cow_split():
     """Placement takes the COW ref BEFORE reservation-driven eviction
-    can run (engine `_place_paged` order). Even when the store entry is
+    can run (`PagedCache._place` order). Even when the store entry is
     evicted between the match and the split — the eviction-pressure
     race — the boundary page survives on the slot's ref and the split
     copies from live content; afterwards the pool reclaims fully."""

@@ -173,3 +173,49 @@ def test_pinned_version_served_and_cached(repo, mnist_params):
         assert ("mnist", 1) in server.repo._pinned  # cached for next time
     finally:
         server.stop()
+
+
+def test_server_options_hand_the_engine_its_cache_sizing(tmp_path,
+                                                         monkeypatch):
+    """The five deployment names a pod sets are read where every other
+    server setting is (``server_options``) and reach the engine the
+    repository builds as keywords; no socket is opened."""
+    import os
+
+    from kubeflow_tpu.models import Transformer, TransformerConfig
+    from kubeflow_tpu.serving import transformer_export_config
+    from kubeflow_tpu.serving.server import server_options
+
+    for name, value in (("KFTPU_PAGED", "1"), ("KFTPU_KV_PAGE_SIZE", "4"),
+                        ("KFTPU_KV_PAGES", "9"),
+                        ("KFTPU_PREFILL_CHUNK", "6"),
+                        ("KFTPU_PREFIX_CACHE_BYTES", "4096"),
+                        ("KFTPU_DECODE_SLOTS", "2"), ("KFTPU_WARMUP", "0")):
+        monkeypatch.setenv(name, value)
+    opts = server_options(os.environ)
+    assert opts["engine_options"] == {
+        "paged": True, "kv_page_size": 4, "kv_pages": 9,
+        "prefill_chunk_tokens": 6, "prefix_cache_bytes": 4096}
+    assert opts["decode_slots"] == 2 and opts["warmup"] is False
+    # unset or empty names leave the keyword out: the engine's default
+    assert server_options({"KFTPU_PAGED": "0", "KFTPU_KV_PAGES": ""})[
+        "engine_options"] == {}
+
+    config = TransformerConfig(vocab_size=97, d_model=32, n_layers=2,
+                               n_heads=4, n_kv_heads=2, d_ff=64,
+                               max_seq_len=48, dtype=jnp.float32,
+                               remat=False)
+    params = Transformer(config).init(
+        jax.random.key(0), np.zeros((1, 8), np.int32))["params"]
+    export_model(str(tmp_path / "lm"), "transformer", params, version=1,
+                 config=transformer_export_config(config))
+    srv = ModelServer(str(tmp_path), poll_interval_s=3600, **opts)
+    try:
+        eng = srv.repo.engine_for("lm", srv.repo.get("lm"))
+        assert eng.paged and eng.slots == 2
+        assert (eng.kv_page_size, eng.kv_pages) == (4, 9)
+        assert eng._kv.prefill_chunk_tokens == 6
+        assert eng._prefix_budget_bytes == 4096
+        assert eng._pool.pages_total == 9
+    finally:
+        srv.stop()
