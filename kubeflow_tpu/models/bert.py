@@ -24,6 +24,7 @@ from kubeflow_tpu.models.transformer import (
     RMSNorm,
     TransformerConfig,
     _constrain,
+    remat_block,
     rope_tables,
 )
 
@@ -132,7 +133,7 @@ class Bert(nn.Module):
         aux = (sin, cos, seq_lengths)
         block_cls = Block
         if c.remat:
-            block_cls = nn.remat(Block, prevent_cse=False)
+            block_cls = remat_block()
         if c.scan_layers:
             x, _ = nn.scan(
                 block_cls,

@@ -12,7 +12,9 @@ Design notes (TPU-first):
 - bf16 activations, fp32 params/optimizer; big fused einsums for the MXU.
 - ``nn.scan`` over blocks: one traced block, stacked params — fast compiles
   and a natural ``stage`` axis for pipeline parallelism.
-- ``nn.remat`` per block trades FLOPs for HBM.
+- ``nn.remat`` per block trades FLOPs for HBM; the flash forward's output
+  and log-sum-exp are kept, so the backward reruns no attention kernel
+  (``remat_block``).
 - MoE uses exact dense top-k dispatch (one-hot combine einsum): static
   shapes, XLA-friendly; experts shard over the ``expert`` logical axis. A
   capacity-based all_to_all dispatch is the planned fast path for large E.
@@ -794,6 +796,22 @@ class Block(nn.Module):
         return ((x, cache) if self.decode else x), None
 
 
+def remat_block():
+    """``Block`` recomputed in the backward (``remat=True``), except
+    what only the flash forward kernel can produce: its output and
+    log-sum-exp keep their values, so a layer's backward runs the dQ and
+    dK/dV kernels and not the forward kernel a second time. Kept per
+    layer: B·S·H·Dh activations in the compute dtype plus B·H·S float32.
+    The names exist only where the flash kernels ran
+    (``ops/attention.py:_flash_vjp_fwd``); under any other
+    ``attention_impl`` the policy keeps nothing."""
+    from kubeflow_tpu.ops import attention as att  # local: no cycle
+
+    return nn.remat(Block, prevent_cse=False,
+                    policy=jax.checkpoint_policies.save_only_these_names(
+                        att.FLASH_OUT, att.FLASH_LSE))
+
+
 class Transformer(nn.Module):
     config: TransformerConfig
     # autoregressive mode: a "cache" collection (K/V + per-row write
@@ -834,7 +852,7 @@ class Transformer(nn.Module):
 
         block_cls = Block
         if c.remat and not self.decode:
-            block_cls = nn.remat(Block, prevent_cse=False)
+            block_cls = remat_block()
         if c.scan_layers:
             scan = functools.partial(
                 nn.scan,

@@ -28,6 +28,7 @@ from kubeflow_tpu.models.transformer import (
     RMSNorm,
     TransformerConfig,
     _constrain,
+    remat_block,
     rope_tables,
 )
 
@@ -114,7 +115,7 @@ class ViT(nn.Module):
 
         block_cls = Block
         if c.remat:
-            block_cls = nn.remat(Block, prevent_cse=False)
+            block_cls = remat_block()
         if c.scan_layers:
             x, _ = nn.scan(
                 block_cls,

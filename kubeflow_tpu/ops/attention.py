@@ -32,6 +32,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from kubeflow_tpu import compat
 from kubeflow_tpu.ops.autotune import resolve_flash
@@ -620,6 +621,11 @@ def resolve_interpret(interpret: Optional[bool]) -> bool:
     return jax.default_backend() != "tpu"
 
 
+# checkpoint names of what the forward kernel leaves for the backward
+FLASH_OUT = "flash_out"
+FLASH_LSE = "flash_lse"
+
+
 @functools.partial(
     jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7)
 )
@@ -667,6 +673,14 @@ def _flash_vjp_fwd(q, k, v, causal, block_q, block_k, sm_scale, interpret,
                           block_k=block_k, sm_scale=sm_scale,
                           interpret=resolve_interpret(interpret),
                           kv_len=kv_len)
+    # the two residuals only the kernel can produce carry names, so a
+    # rematerialised caller can keep them (models/transformer.py:
+    # remat_block) and its backward does not run the forward kernel
+    # again; q, k and v stay unnamed, cheap to recompute. The names go
+    # on the residuals themselves: one on the caller's output alone
+    # leaves ``lse`` unsaved and the kernel still reruns.
+    out = checkpoint_name(out, FLASH_OUT)
+    lse = checkpoint_name(lse, FLASH_LSE)
     return out, (q, k, v, out, lse, kv_len)
 
 

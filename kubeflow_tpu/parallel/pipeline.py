@@ -146,11 +146,10 @@ def make_pipelined_lm_forward(
     over ``axis``. Requires ``scan_layers=True`` params (the stacked
     "blocks" subtree).
     """
-    import flax.linen as nn
-
     from kubeflow_tpu.models.transformer import (  # local import: no cycle
         Block,
         RMSNorm,
+        remat_block,
         rope_tables,
     )
 
@@ -158,7 +157,7 @@ def make_pipelined_lm_forward(
     c = model.config
     # honor config.remat here too — pipelining targets exactly the
     # large-model regime where un-rematted activations would blow HBM
-    block_cls = nn.remat(Block, prevent_cse=False) if c.remat else Block
+    block_cls = remat_block() if c.remat else Block
     block = block_cls(c)
     final_norm = RMSNorm(param_dtype=c.param_dtype)
 

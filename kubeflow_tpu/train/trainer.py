@@ -116,11 +116,18 @@ def create_sharded_state(
 
 
 def next_token_loss(logits: jnp.ndarray, tokens: jnp.ndarray) -> jnp.ndarray:
-    """Causal LM loss: predict tokens[:, 1:] from logits[:, :-1]."""
-    logp = jax.nn.log_softmax(logits[:, :-1].astype(jnp.float32), axis=-1)
+    """Causal LM loss: predict tokens[:, 1:] from logits[:, :-1].
+
+    Written as logsumexp minus the target's logit, read from the logits
+    themselves: a gather from ``log_softmax``'s result keeps those
+    B·(S-1)·V float32 (3.2 GB at 2 x 8192 tokens of a 49k vocabulary)
+    alive until the loss value is read, which the compiler schedules
+    after the whole backward."""
+    logits = logits[:, :-1]
     tgt = tokens[:, 1:]
-    ll = jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]
-    return -jnp.mean(ll)
+    picked = jnp.take_along_axis(logits, tgt[..., None], axis=-1)[..., 0]
+    lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
+    return jnp.mean(lse - picked.astype(jnp.float32))
 
 
 def softmax_cross_entropy(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:

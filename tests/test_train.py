@@ -108,6 +108,35 @@ def test_mnist_train_no_batchstats():
     assert losses[-1] < losses[0], losses
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_next_token_loss_is_log_softmax_at_the_targets(dtype):
+    """``next_token_loss`` reads the target's logit from the logits and
+    subtracts it from logsumexp, so that no float32 log-probability
+    outlives the head: the value and the gradient are those of the
+    gather from ``log_softmax``."""
+    from kubeflow_tpu.train import next_token_loss
+
+    logits = (4.0 * jax.random.normal(jax.random.key(0), (3, 17, 97))
+              ).astype(dtype)
+    tokens = jax.random.randint(jax.random.key(1), (3, 17), 0, 97)
+
+    def plain(x):
+        logp = jax.nn.log_softmax(x[:, :-1].astype(jnp.float32), axis=-1)
+        ll = jnp.take_along_axis(logp, tokens[:, 1:, None], axis=-1)
+        return -jnp.mean(ll)
+
+    want, want_g = jax.value_and_grad(plain)(logits)
+    got, got_g = jax.value_and_grad(
+        lambda x: next_token_loss(x, tokens))(logits)
+    assert got.dtype == jnp.float32 and got_g.dtype == dtype
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-6)
+    # one bfloat16 step at the largest gradient (1/48 a token)
+    atol = 1e-8 if dtype == jnp.float32 else 2.0 ** -13
+    np.testing.assert_allclose(np.asarray(got_g, np.float32),
+                               np.asarray(want_g, np.float32), atol=atol)
+    assert not np.any(np.asarray(got_g[:, -1], np.float32))
+
+
 @pytest.mark.slow  # multi-second XLA compiles; tier-1 runs the fast twin paths
 def test_chunked_loss_matches_full_logits_path():
     """chunked_next_token_loss from hidden states must equal
