@@ -124,6 +124,20 @@ def _row_lengths(config, lens) -> tuple:
     return (lens,) if config.has_recurrent_state else ()
 
 
+def refuse_unless_kv_cache(config, what: str) -> None:
+    """THE refusal of the paths that work on ``k`` / ``v`` leaves alone
+    (paging them, rolling a rejected draft back by resetting positions):
+    by what the model declares its cache leaves to be, whatever makes
+    them otherwise (a recurrent state, a latent row, an indexer's
+    keys)."""
+    other = sorted(set(config.cache_leaves(1))
+                   - {"positions", "pages", "k", "v"})
+    if other:
+        raise ValueError(
+            f"{what} `k`/`v` cache leaves; this model's cache also holds "
+            f"{', '.join(other)}")
+
+
 def _is_key(path, name: str) -> bool:
     return getattr(path[-1], "key", None) == name
 
@@ -555,11 +569,9 @@ def _spec_validate(config: TransformerConfig,
     if k < 1:
         raise ValueError("draft_len must be >= 1")
     for name, c in (("target", config), ("draft", draft_config)):
-        if c.has_recurrent_state:
-            raise ValueError(
-                f"speculative decoding rolls a rejected draft back by "
-                f"resetting positions; the {name} model keeps a recurrent "
-                "state that cannot be rolled back")
+        refuse_unless_kv_cache(
+            c, f"speculative decoding rolls the {name} model's rejected "
+               "draft back by resetting the positions of")
     if config.vocab_size != draft_config.vocab_size:
         raise ValueError("draft and target must share a vocabulary")
     if true_len is None:

@@ -1,7 +1,8 @@
-"""A decoder whose layers differ: linear-attention (KDA) and latent
-attention (MLA) mixers by a per-layer pattern, a dense SwiGLU MLP in the
-leading layers and a sigmoid-routed expert MLP with a shared expert after
-them, an untied output head. Served through the same
+"""A decoder whose layers differ: linear-attention (KDA), latent
+attention (MLA) and sparse latent attention (DSA: MLA over the positions
+a learned indexer keeps) mixers by a per-layer pattern, a dense SwiGLU
+MLP in the leading layers and a sigmoid-routed expert MLP with a shared
+expert after them, an untied output head. Served through the same
 ``models/decode.py`` / ``DecodeEngine`` path as :class:`Transformer`.
 
 It exists in decode mode only: every apply reads and writes the ``cache``
@@ -10,13 +11,19 @@ collection, whose leaves the model declares
 and idle value from there, never from its rank):
 
     positions     (B,)                       tokens each row holds
-    latent        (L_mla, B, S_max, W)       a token's normalised kv latent
-                                             (r) | rotated shared rope key
-                                             (p) | 1 / rms of each head's
-                                             key (H) | zeros up to W, the
-                                             next multiple of 128 lanes
+    latent        (L_mla + L_dsa, B, S_max, W)  a token's normalised kv
+                                             latent (r) | rotated shared
+                                             rope key (p) | with qk-norm,
+                                             1 / rms of each head's key
+                                             (H) | zeros up to W, the next
+                                             multiple of 128 lanes
+    index_k       (L_dsa, B, S_max, d_index) a token's index key
     kda_state     (L_kda, B, H, dk, dv) f32  the delta-rule state
     kda_conv      (L_kda, B, K - 1, 3 H dk)  last conv inputs of q | k | v
+
+(a leaf exists only where some layer needs it). A DSA layer reads
+``index_k`` as far as the row has grown and attends, of the ``latent``'s
+positions, to the ``index_topk`` it selects for each query.
 
 A recurrent state cannot be pulled back the way ``positions`` can, so
 ``true_len`` reaches the mixers: past a row's own length a multi-token
@@ -30,6 +37,7 @@ travel through the engine's K-step scan as its carry.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -43,6 +51,7 @@ from kubeflow_tpu.models.transformer import (
     rope_tables,
 )
 from kubeflow_tpu.ops.attention import NEG_INF
+from kubeflow_tpu.ops.dsa import index_scores, select_bias, sparse_attend
 from kubeflow_tpu.ops.kda import kda_step
 from kubeflow_tpu.parallel.mesh import DEFAULT_RULES, AxisRules
 
@@ -61,12 +70,30 @@ class HybridConfig:
     first_k_dense: int = 1            # leading layers with the dense MLP
     d_ff: int = 128                   # dense MLP width
     max_seq_len: int = 256
-    # MLA
+    # MLA ("mla" and "dsa" layers)
     kv_lora_rank: int = 32
     qk_nope_dim: int = 16
     qk_rope_dim: int = 8
     v_head_dim: int = 16
     rope_theta: float = 10000.0
+    q_lora_rank: int = 0              # 0: a full-rank query projection
+    use_qk_norm: bool = True          # RMSNorm over each head's q and k
+    head_gate: bool = True            # sigmoid gate a head on the output
+    # YaRN (rope_factor 1: plain rope): the long wavelengths stretched by
+    # the factor, and mscale ** 2 in the softmax scale
+    rope_factor: float = 1.0
+    rope_original_len: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    # the indexer of a "dsa" layer: a query attends to the index_topk
+    # cached positions that score highest
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    # a prompt longer than this is admitted by repeating ONE program of
+    # this many tokens against the row (0: one program a prompt bucket)
+    prefill_chunk: int = 0
     # KDA
     conv_kernel: int = 4
     kda_lower_bound: float = -5.0
@@ -94,10 +121,12 @@ class HybridConfig:
     @property
     def latent_width(self) -> int:
         """A cached token of an MLA layer: the latent, the shared rope
-        key and the per-head key scales, padded to whole 128-lane tiles
-        (the chip lays a leaf whose last axis does not fill the lanes
-        out otherwise than the step reads it, and re-lays it each round)."""
-        return -(-(self.kv_lora_rank + self.qk_rope_dim + self.n_heads)
+        key and, with qk-norm, the per-head key scales, padded to whole
+        128-lane tiles (the chip lays a leaf whose last axis does not
+        fill the lanes out otherwise than the step reads it, and re-lays
+        it each round)."""
+        scales = self.n_heads if self.use_qk_norm else 0
+        return -(-(self.kv_lora_rank + self.qk_rope_dim + scales)
                  // 128) * 128
 
     @property
@@ -107,6 +136,22 @@ class HybridConfig:
     @property
     def n_mla(self) -> int:
         return self.layer_types.count("mla")
+
+    @property
+    def n_dsa(self) -> int:
+        return self.layer_types.count("dsa")
+
+    @property
+    def rope_mscale(self) -> float:
+        if self.rope_factor <= 1.0:
+            return 1.0
+        return 0.1 * self.rope_mscale_all_dim * math.log(
+            self.rope_factor) + 1.0
+
+    @property
+    def softmax_scale(self) -> float:
+        return ((self.qk_nope_dim + self.qk_rope_dim) ** -0.5
+                * self.rope_mscale ** 2)
 
     @property
     def n_moe(self) -> int:
@@ -124,22 +169,37 @@ class HybridConfig:
         return HybridDecoder(self)
 
     def cache_leaves(self, batch: int) -> dict:
-        H, dk = self.n_heads, self.head_dim
-        return {
-            "positions": CacheLeaf((batch,), jnp.int32, 0),
-            "latent": CacheLeaf(
-                (self.n_mla, batch, self.max_seq_len, self.latent_width),
-                self.dtype, 1),
-            "kda_state": CacheLeaf((self.n_kda, batch, H, dk, dk),
-                                   jnp.float32, 1),
-            "kda_conv": CacheLeaf(
+        H, dk, S = self.n_heads, self.head_dim, self.max_seq_len
+        leaves = {"positions": CacheLeaf((batch,), jnp.int32, 0)}
+        if self.n_mla + self.n_dsa:
+            leaves["latent"] = CacheLeaf(
+                (self.n_mla + self.n_dsa, batch, S, self.latent_width),
+                self.dtype, 1)
+        if self.n_dsa:
+            leaves["index_k"] = CacheLeaf(
+                (self.n_dsa, batch, S, self.index_head_dim), self.dtype, 1)
+        if self.n_kda:
+            leaves["kda_state"] = CacheLeaf((self.n_kda, batch, H, dk, dk),
+                                            jnp.float32, 1)
+            leaves["kda_conv"] = CacheLeaf(
                 (self.n_kda, batch, self.conv_kernel - 1, 3 * H * dk),
-                self.dtype, 1),
-        }
+                self.dtype, 1)
+        return leaves
 
     def validate(self) -> None:
-        if set(self.layer_types) - {"kda", "mla"}:
+        if set(self.layer_types) - {"kda", "mla", "dsa"}:
             raise ValueError(f"unknown mixer in {self.layer_types!r}")
+        if self.n_dsa and not (
+                self.q_lora_rank and self.index_n_heads
+                and self.index_topk and self.index_head_dim
+                and self.index_head_dim % 2 == 0
+                and self.qk_rope_dim <= self.index_head_dim
+                and not self.use_qk_norm):
+            raise ValueError(
+                "a dsa layer needs q_lora_rank (the indexer reads the "
+                "query's latent), index_n_heads, index_topk, an "
+                "index_head_dim that holds the rope dims, and no qk-norm "
+                "(gathered rows carry no key scales)")
         lo, n = self.held
         if lo < 0 or n < 1 or lo + n > self.n_experts:
             raise ValueError(f"experts_held {self.held} outside the "
@@ -323,32 +383,67 @@ class KdaMixer(nn.Module):
         return out.astype(c.dtype), cache
 
 
-# -- MLA -------------------------------------------------------------------------
+# -- MLA ---------------------------------------------------------------------------
+
+def mla_rope_tables(c: HybridConfig, dim: int):
+    """(sin, cos) over ``max_seq_len`` positions for ``dim`` rotated
+    dims: plain rope, or YaRN's blend where ``rope_factor`` > 1 (a
+    frequency that turns more than ``beta_fast`` times over the original
+    context stays, one that turns fewer than ``beta_slow`` times is
+    divided by the factor, a linear ramp between)."""
+    if c.rope_factor <= 1.0:
+        return rope_tables(c.max_seq_len, dim, c.rope_theta)
+
+    def turns_at(n_turns):     # the dim whose wavelength fits n_turns times
+        return (dim * math.log(c.rope_original_len / (n_turns * 2 * math.pi))
+                / (2 * math.log(c.rope_theta)))
+
+    low = max(math.floor(turns_at(c.rope_beta_fast)), 0)
+    high = min(math.ceil(turns_at(c.rope_beta_slow)), dim - 1)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 0.001), 0.0, 1.0)
+    freqs = 1.0 / (c.rope_theta ** (
+        jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    freqs = freqs / c.rope_factor * ramp + freqs * (1.0 - ramp)
+    angles = jnp.outer(jnp.arange(c.max_seq_len, dtype=jnp.float32), freqs)
+    return jnp.sin(angles), jnp.cos(angles)
+
 
 class MlaAttention(nn.Module):
+    """Latent attention. What a model's config declares decides the
+    pieces: a full-rank or a low-rank (``q_lora_rank``) query, qk-norm
+    with its cached key scales or none, a head-wise output gate or none,
+    plain or YaRN rope. ``sparse`` (a "dsa" layer) adds the indexer: each
+    query attends to the ``index_topk`` cached positions its index scores
+    rank highest (``ops/dsa.py``: scores, selection and attend are three
+    kernels)."""
+
     config: HybridConfig
+    sparse: bool = False
 
     @nn.compact
-    def __call__(self, x, cache, index: int, fresh: bool):
+    def __call__(self, x, cache, index: int, fresh: bool, k_index: int = 0):
         """x (B, T, D). ``fresh`` (static): the rows' caches are empty,
         so the T tokens attend among themselves in the expanded form;
         otherwise each row's tokens are written at its own position and
-        attend to the whole cached row in the absorbed form (``kv_b``
-        folded into the query and the output), which reads nothing but
-        the latent. Returns (out, cache)."""
+        attend to the cached row in the absorbed form (``kv_b`` folded
+        into the query and the output), which reads nothing but the
+        latent. A sparse layer takes the second form always (an empty
+        row scores as one); ``k_index`` is its place among the rows of
+        ``index_k``. Returns (out, cache, (scored, selected) | None)."""
         c = self.config
         B, T, D = x.shape
         H, r = c.n_heads, c.kv_lora_rank
         n, p, vd = c.qk_nope_dim, c.qk_rope_dim, c.v_head_dim
         init = nn.initializers.normal(stddev=D ** -0.5)
-        w_q = self.param("q_proj", init, (D, H, n + p), c.param_dtype)
         w_kva = self.param("kv_a_proj", init, (D, r + p), c.param_dtype)
         w_kvb = self.param("kv_b_proj", nn.initializers.normal(r ** -0.5),
                            (r, H, n + vd), c.param_dtype)
-        w_gate = self.param("gate_proj", init, (D, H), c.param_dtype)
         w_o = self.param("o_proj", init, (H, vd, D), c.param_dtype)
-        w_kn = self.param("k_norm", nn.initializers.ones, (n + p,),
-                          c.param_dtype).astype(jnp.float32)
+        w_kn = None
+        if c.use_qk_norm:
+            w_kn = self.param("k_norm", nn.initializers.ones, (n + p,),
+                              c.param_dtype).astype(jnp.float32)
         eps = RMSNorm.eps
         ein = lambda spec, a, b: jnp.einsum(  # noqa: E731
             spec, a.astype(c.dtype), b.astype(c.dtype),
@@ -356,69 +451,170 @@ class MlaAttention(nn.Module):
 
         pos = cache["positions"]                                    # (B,)
         q_pos = pos[:, None] + jnp.arange(T)[None, :]               # (B, T)
-        sin_t, cos_t = rope_tables(c.max_seq_len, p, c.rope_theta)
+        sin_t, cos_t = mla_rope_tables(c, p)
         # an idle row's position runs past the table: clip, its output is
         # read by nobody
         sin = jnp.take(sin_t, q_pos, axis=0, mode="clip")[:, :, None, :]
         cos = jnp.take(cos_t, q_pos, axis=0, mode="clip")[:, :, None, :]
 
-        q = RMSNorm(param_dtype=c.param_dtype, name="q_norm")(
-            ein("btd,dhk->bthk", x, w_q))
+        # -- the query
+        c_q = None
+        if c.q_lora_rank:
+            w_qa = self.param("q_a_proj", init, (D, c.q_lora_rank),
+                              c.param_dtype)
+            w_qb = self.param(
+                "q_b_proj", nn.initializers.normal(c.q_lora_rank ** -0.5),
+                (c.q_lora_rank, H, n + p), c.param_dtype)
+            c_q = RMSNorm(param_dtype=c.param_dtype, name="q_a_norm")(
+                _dense(x, w_qa, c.dtype)).astype(c.dtype)
+            q = ein("btr,rhk->bthk", c_q, w_qb)
+        else:
+            w_q = self.param("q_proj", init, (D, H, n + p), c.param_dtype)
+            q = ein("btd,dhk->bthk", x, w_q)
+        if c.use_qk_norm:
+            q = RMSNorm(param_dtype=c.param_dtype, name="q_norm")(q)
         q_nope, q_rope = q[..., :n], _rotate(q[..., n:], sin, cos)
+
+        # -- the token's cached row
         kva = _dense(x, w_kva, c.dtype)
         lat = RMSNorm(param_dtype=c.param_dtype, name="kv_norm")(
             kva[..., :r]).astype(c.dtype)                        # (B, T, r)
         k_r = kva[..., r:]                                       # (B, T, p)
-        k_nope = ein("btr,rhk->bthk", lat, w_kvb[..., :n])       # new tokens
-        inv_rms = jax.lax.rsqrt(
-            (jnp.sum(jnp.square(k_nope), -1)
-             + jnp.sum(jnp.square(k_r), -1)[..., None]) / (n + p) + eps)
-        k_rot = _rotate((k_r * w_kn[n:])[:, :, None, :], sin, cos)[:, :, 0]
         W = c.latent_width
-        row = jnp.concatenate(
-            [lat, k_rot.astype(c.dtype), inv_rms.astype(c.dtype),
-             jnp.zeros((B, T, W - r - p - H), c.dtype)], -1)
-        scale = (n + p) ** -0.5
+        if c.use_qk_norm:
+            k_nope = ein("btr,rhk->bthk", lat, w_kvb[..., :n])   # new tokens
+            inv_rms = jax.lax.rsqrt(
+                (jnp.sum(jnp.square(k_nope), -1)
+                 + jnp.sum(jnp.square(k_r), -1)[..., None]) / (n + p) + eps)
+            k_rot = _rotate((k_r * w_kn[n:])[:, :, None, :], sin, cos)[:, :, 0]
+            row = jnp.concatenate(
+                [lat, k_rot.astype(c.dtype), inv_rms.astype(c.dtype),
+                 jnp.zeros((B, T, W - r - p - H), c.dtype)], -1)
+        else:
+            k_rot = _rotate(k_r[:, :, None, :], sin, cos)[:, :, 0]
+            row = jnp.concatenate(
+                [lat, k_rot.astype(c.dtype),
+                 jnp.zeros((B, T, W - r - p), c.dtype)], -1)
+        scale = c.softmax_scale
+        counts = None
 
-        with jax.named_scope("mla.attend"):
-            if fresh:
-                # rows start at 0 and share the slice
-                at = (index, 0, 0, 0)
-                latent = jax.lax.dynamic_update_slice(
-                    cache["latent"], row[None], at)
-                k = jnp.concatenate(
-                    [k_nope * w_kn[:n],
-                     jnp.broadcast_to(k_rot[:, :, None, :], (B, T, H, p))],
-                    -1) * inv_rms[..., None]
-                v = ein("btr,rhv->bthv", lat, w_kvb[..., n:])
-                qf = jnp.concatenate([q_nope, q_rope], -1)
-                s = ein("bqhd,bkhd->bhqk", qf, k) * scale
-                mask = jnp.arange(T)[None, :] <= jnp.arange(T)[:, None]
-                prob = jax.nn.softmax(jnp.where(mask, s, NEG_INF), axis=-1)
-                o = ein("bhqk,bkhv->bqhv", prob, v)
-            else:
-                rows = jnp.arange(B)[:, None]
-                latent = cache["latent"].at[index, rows, q_pos].set(row)
-                q_lat = ein("bthk,rhk->bthr", q_nope * w_kn[:n],
-                            w_kvb[..., :n])
-                # zeros against the scale and pad columns: the products
-                # run over whole cached rows, never over a slice of them
-                qf = jnp.concatenate(
-                    [q_lat, q_rope, jnp.zeros((B, T, H, W - r - p))], -1)
-                s = ein("bqhl,bkl->bhqk", qf, latent[index])
-                k_scale = latent[index][..., r + p:r + p + H]  # (B, S, H)
-                s = s * scale * jnp.swapaxes(
-                    k_scale, 1, 2)[:, :, None, :].astype(jnp.float32)
-                mask = (jnp.arange(c.max_seq_len)[None, None, :]
-                        <= q_pos[:, :, None])                   # (B, T, S)
-                prob = jax.nn.softmax(
-                    jnp.where(mask[:, None], s, NEG_INF), axis=-1)
-                o_lat = ein("bhqk,bkl->bqhl", prob, latent[index])[..., :r]
+        def absorbed_query():
+            q_lat = ein("bthk,rhk->bthr",
+                        q_nope * w_kn[:n] if c.use_qk_norm else q_nope,
+                        w_kvb[..., :n])
+            # zeros against the scale and pad columns: the products
+            # run over whole cached rows, never over a slice of them
+            return jnp.concatenate(
+                [q_lat, q_rope, jnp.zeros((B, T, H, W - r - p))], -1)
+
+        if self.sparse:
+            rows = jnp.arange(B)[:, None]
+            latent = cache["latent"].at[index, rows, q_pos].set(row)
+            index_k, kept, counts = self._select(
+                x, c_q, cache["index_k"], k_index, pos, q_pos, sin, cos)
+            cache = dict(cache, index_k=index_k)
+            with jax.named_scope("dsa.attend"):
+                o_lat = sparse_attend(absorbed_query(), kept, latent, index,
+                                      pos, scale=scale, values=r)
                 o = ein("bqhr,rhv->bqhv", o_lat, w_kvb[..., n:])
+        else:
+            with jax.named_scope("mla.attend"):
+                if fresh:
+                    # rows start at 0 and share the slice
+                    at = (index, 0, 0, 0)
+                    latent = jax.lax.dynamic_update_slice(
+                        cache["latent"], row[None], at)
+                    if c.use_qk_norm:
+                        k = jnp.concatenate(
+                            [k_nope * w_kn[:n],
+                             jnp.broadcast_to(k_rot[:, :, None, :],
+                                              (B, T, H, p))],
+                            -1) * inv_rms[..., None]
+                    else:
+                        k = jnp.concatenate(
+                            [ein("btr,rhk->bthk", lat, w_kvb[..., :n]),
+                             jnp.broadcast_to(k_rot[:, :, None, :],
+                                              (B, T, H, p))], -1)
+                    v = ein("btr,rhv->bthv", lat, w_kvb[..., n:])
+                    qf = jnp.concatenate([q_nope, q_rope], -1)
+                    s = ein("bqhd,bkhd->bhqk", qf, k) * scale
+                    mask = jnp.arange(T)[None, :] <= jnp.arange(T)[:, None]
+                    prob = jax.nn.softmax(jnp.where(mask, s, NEG_INF),
+                                          axis=-1)
+                    o = ein("bhqk,bkhv->bqhv", prob, v)
+                else:
+                    rows = jnp.arange(B)[:, None]
+                    latent = cache["latent"].at[index, rows, q_pos].set(row)
+                    s = ein("bqhl,bkl->bhqk", absorbed_query(), latent[index])
+                    if c.use_qk_norm:
+                        k_scale = latent[index][..., r + p:r + p + H]
+                        s = s * scale * jnp.swapaxes(
+                            k_scale, 1, 2)[:, :, None, :].astype(jnp.float32)
+                    else:
+                        s = s * scale
+                    mask = (jnp.arange(c.max_seq_len)[None, None, :]
+                            <= q_pos[:, :, None])                # (B, T, S)
+                    prob = jax.nn.softmax(
+                        jnp.where(mask[:, None], s, NEG_INF), axis=-1)
+                    o_lat = ein("bhqk,bkl->bqhl", prob, latent[index])[..., :r]
+                    o = ein("bqhr,rhv->bqhv", o_lat, w_kvb[..., n:])
         cache = dict(cache, latent=latent)
-        gate = jax.nn.sigmoid(_dense(x, w_gate, c.dtype))[..., None]
-        out = ein("bqhv,hvd->bqd", gate * o, w_o)
-        return out.astype(c.dtype), cache
+        if c.head_gate:
+            w_gate = self.param("gate_proj", init, (D, H), c.param_dtype)
+            o = jax.nn.sigmoid(_dense(x, w_gate, c.dtype))[..., None] * o
+        out = ein("bqhv,hvd->bqd", o, w_o)
+        return out.astype(c.dtype), cache, counts
+
+    def _select(self, x, c_q, index_k, k_index: int, pos, q_pos, sin, cos):
+        """The indexer: write the tokens' index keys at their positions,
+        score every cached position of the row for every query, keep the
+        ``index_topk`` highest (ties to the lower position). Returns
+        (index_k, kept (B, T, S) float32, 0 at a query's kept positions
+        and ``-inf`` elsewhere, (scored, selected)): the counts are
+        summed over the rows. ``sin`` / ``cos`` (B, T, 1, p / 2) are the
+        mixer's own angles: the indexer's rope turns its FIRST p dims."""
+        c = self.config
+        B, T, D = x.shape
+        J, d, p = c.index_n_heads, c.index_head_dim, c.qk_rope_dim
+        init = nn.initializers.normal(stddev=D ** -0.5)
+        w_q = self.param("index_q_proj",
+                         nn.initializers.normal(c.q_lora_rank ** -0.5),
+                         (c.q_lora_rank, J, d), c.param_dtype)
+        w_k = self.param("index_k_proj", init, (D, d), c.param_dtype)
+        w_w = self.param("index_w_proj", init, (D, J), c.param_dtype)
+        ln_scale = self.param("index_k_norm_scale", nn.initializers.ones,
+                              (d,), c.param_dtype)
+        ln_bias = self.param("index_k_norm_bias", nn.initializers.zeros,
+                             (d,), c.param_dtype)
+
+        def turned(y, sin, cos):
+            return jnp.concatenate(
+                [_rotate(y[..., :p], sin, cos), y[..., p:]], -1)
+
+        with jax.named_scope("dsa.index"):
+            k = _dense(x, w_k, c.dtype)                          # (B, T, d)
+            mean = jnp.mean(k, -1, keepdims=True)
+            k = (k - mean) * jax.lax.rsqrt(
+                jnp.mean(jnp.square(k - mean), -1, keepdims=True)
+                + RMSNorm.eps)
+            k = k * ln_scale.astype(jnp.float32) + ln_bias.astype(jnp.float32)
+            k = turned(k, sin[:, :, 0], cos[:, :, 0]).astype(c.dtype)
+            rows = jnp.arange(B)[:, None]
+            index_k = index_k.at[k_index, rows, q_pos].set(k)
+            q = jnp.einsum("btr,rjd->btjd", c_q, w_q.astype(c.dtype),
+                           preferred_element_type=jnp.float32)
+            q = turned(q, sin, cos)
+            w = _dense(x, w_w, c.dtype) * (J ** -0.5 * d ** -0.5)
+            scores = index_scores(q, w, index_k, k_index, pos)   # (B, T, S)
+        K = min(c.index_topk, c.max_seq_len)
+        with jax.named_scope("dsa.select"):
+            kept = select_bias(scores, K)
+        # for whoever asks (mutable "intermediates"): a test, a probe
+        self.sow("intermediates", "index_scores", scores)
+        self.sow("intermediates", "kept", kept)
+        held = jnp.minimum(q_pos + 1, c.max_seq_len)
+        counts = (jnp.sum(held), jnp.sum(jnp.minimum(held, K)))
+        return index_k, kept, counts
 
 
 # -- routed MLP ------------------------------------------------------------------
@@ -525,23 +721,30 @@ class HybridLayer(nn.Module):
     routed: bool
 
     @nn.compact
-    def __call__(self, x, cache, index: int, lens, fresh: bool):
+    def __call__(self, x, cache, index, lens, fresh: bool):
+        """``index`` is the layer's place in the leaves of its kind, for
+        a sparse layer (latent, index_k). Returns (x, cache, what the
+        routed MLP counted | None, what the indexer counted | None)."""
         c = self.config
         h = RMSNorm(param_dtype=c.param_dtype, name="attn_norm")(x)
+        picked = None
         if self.mixer == "kda":
             out, cache = KdaMixer(c, name="mixer")(h, cache, index, lens)
+        elif self.mixer == "dsa":
+            out, cache, picked = MlaAttention(c, sparse=True, name="mixer")(
+                h, cache, index[0], fresh, index[1])
         else:
-            out, cache = MlaAttention(c, name="mixer")(h, cache, index,
-                                                       fresh)
+            out, cache, _ = MlaAttention(c, name="mixer")(h, cache, index,
+                                                          fresh)
         x = x + out
         h = RMSNorm(param_dtype=c.param_dtype, name="mlp_norm")(x)
         if not self.routed:
-            return x + DenseMlp(c, name="mlp")(h), cache, None
+            return x + DenseMlp(c, name="mlp")(h), cache, None, picked
         live = None
         if lens is not None:
             live = jnp.arange(x.shape[1])[None, :] < lens[:, None]
         y, hit, pairs = RoutedMlp(c, name="mlp")(h, live)
-        return x + y, cache, (hit, pairs)
+        return x + y, cache, (hit, pairs), picked
 
 
 class HybridDecoder(nn.Module):
@@ -573,15 +776,21 @@ class HybridDecoder(nn.Module):
                           nn.initializers.normal(c.d_model ** -0.5),
                           (c.vocab_size, c.d_model), c.param_dtype)
         x = jnp.take(embed.astype(c.dtype), tokens, axis=0)
-        seen = {"kda": 0, "mla": 0}
-        stats = []
+        # "mla" and "dsa" layers share the latent leaf, in layer order
+        seen = {"kda": 0, "mla": 0, "dsa": 0}
+        stats, picked = [], []
         for i, mixer in enumerate(c.layer_types):
-            x, cache, stat = HybridLayer(
+            latent = seen["mla"] + seen["dsa"]
+            index = {"kda": seen["kda"], "mla": latent,
+                     "dsa": (latent, seen["dsa"])}[mixer]
+            x, cache, stat, kept = HybridLayer(
                 c, mixer, routed=i >= c.first_k_dense, name=f"layer_{i}")(
-                    x, cache, seen[mixer], lens, fresh)
+                    x, cache, index, lens, fresh)
             seen[mixer] += 1
             if stat is not None:
                 stats.append(stat)
+            if kept is not None:
+                picked.append(kept)
         cache["positions"] = cache["positions"] + (
             T if lens is None else lens)
         for name, var in leaves.items():
@@ -591,6 +800,11 @@ class HybridDecoder(nn.Module):
                      jnp.stack([s[0] for s in stats]))
             self.sow("moe_stats", "routed_pairs",
                      jnp.stack([s[1] for s in stats]))
+        if picked:
+            self.sow("moe_stats", "index_scored",
+                     jnp.stack([s[0] for s in picked]))
+            self.sow("moe_stats", "index_selected",
+                     jnp.stack([s[1] for s in picked]))
         x = RMSNorm(param_dtype=c.param_dtype, name="final_norm")(x)
         return jnp.einsum("btd,vd->btv", x, head.astype(c.dtype),
                           preferred_element_type=jnp.float32)

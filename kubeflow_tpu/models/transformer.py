@@ -141,6 +141,7 @@ class TransformerConfig:
         return self.d_model // self.n_heads
 
     has_recurrent_state = False   # every cache leaf is indexed by position
+    prefill_chunk = 0             # every prompt is admitted by one program
 
     def decoder(self) -> "Transformer":
         """The decode-mode module that ``models/decode.py`` applies."""

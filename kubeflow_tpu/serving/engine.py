@@ -116,6 +116,18 @@ _moe_hit_c = DEFAULT_REGISTRY.counter(
     "kftpu_moe_experts_hit_total",
     "distinct held experts hit, summed over the routed layers of every "
     "decode step: the expert weights a step had to read")
+_dsa_scored_c = DEFAULT_REGISTRY.counter(
+    "kftpu_dsa_scored_total",
+    "cached positions an indexer scored, summed over the rows, the sparse "
+    "attention layers and every decode step")
+_dsa_selected_c = DEFAULT_REGISTRY.counter(
+    "kftpu_dsa_selected_total",
+    "cached positions sparse attention kept and read, summed likewise: "
+    "over kftpu_dsa_scored_total, how sparse the traffic made the layer")
+# what a model's layers count in a decode step -> the counter it feeds
+_STEP_COUNTERS = {"experts_hit": _moe_hit_c, "routed_pairs": _moe_pairs_c,
+                  "index_scored": _dsa_scored_c,
+                  "index_selected": _dsa_selected_c}
 
 _END = object()  # per-request stream sentinel
 
@@ -798,8 +810,9 @@ class DecodeEngine:
         to the end; ``wait_s`` is carved out of admission's stretch),
         and the same seconds into
         ``kftpu_engine_round_seconds_total{phase}``. ``moe`` is what a
-        model's routed layers counted over the round's steps
-        (``experts_hit``, ``routed_pairs``)."""
+        model's layers counted over the round's steps (the routed ones
+        ``experts_hit`` and ``routed_pairs``, the sparse-attention ones
+        ``index_scored`` and ``index_selected``)."""
         bounds = marks + [self.clock()]
         secs = dict.fromkeys(_ROUND_PHASES, 0.0)
         for phase, t_a, t_b in zip(_ROUND_PHASES[1:], bounds, bounds[1:]):
@@ -812,10 +825,9 @@ class DecodeEngine:
         for phase, sec in secs.items():
             attrs[f"{phase}_s"] = sec
             _round_seconds.inc(sec, model=self.name, phase=phase)
-        if moe:
-            attrs.update(moe)
-            _moe_hit_c.inc(moe["experts_hit"], model=self.name)
-            _moe_pairs_c.inc(moe["routed_pairs"], model=self.name)
+        for counted, total in (moe or {}).items():
+            attrs[counted] = total
+            _STEP_COUNTERS[counted].inc(total, model=self.name)
         self.tracer.record("engine.round", start=bounds[0],
                            end=bounds[-1], parent=self._run_ctx,
                            attrs=attrs)

@@ -36,6 +36,7 @@ from kubeflow_tpu.models.decode import (
     prefill,
     prefill_chunk,
     prefill_continue,
+    refuse_unless_kv_cache,
 )
 from kubeflow_tpu.obs import requests as reqobs
 from kubeflow_tpu.serving.kvpool import (
@@ -128,6 +129,11 @@ def _sampling_args(req) -> tuple:
     """A request's (temperature, top_k, top_p, seed) as device scalars."""
     return (jnp.float32(req.temperature), jnp.int32(req.top_k),
             jnp.float32(req.top_p), jnp.int32(req.seed))
+
+
+def _no_sampling() -> tuple:
+    """:func:`_sampling_args` of a prefill whose token nobody reads."""
+    return (jnp.float32(0.0), jnp.int32(0), jnp.float32(1.0), jnp.int32(0))
 
 
 class _CacheManager:
@@ -344,6 +350,12 @@ class RowCache(_CacheManager):
         super().__init__(eng, eng.config)
         self._prefill = jax.jit(self._prefill_and_sample)
         self._continue = jax.jit(self._continue_and_sample)
+        # a prompt longer than the model's declared chunk is admitted by
+        # this ONE program, run chunk after chunk on the row's own 1-row
+        # cache (0: the model declares none, and no prompt is long)
+        self._chunk_width = int(self.cfg.prefill_chunk)
+        self._chunk = jax.jit(self._chunk_and_sample)
+        self._empty_row = jax.jit(self._empty_row_tree)
         self._prefill_batch = jax.jit(self._prefill_batch_and_sample)
         self._insert, self._insert_rows = _insert_programs(self._leaves)
         # LRU of prefilled prompt prefixes: (len, token bytes) → 1-row
@@ -370,6 +382,47 @@ class RowCache(_CacheManager):
         tok = self._sample1(logits, seed, jnp.int32(0), temperature,
                             top_k, top_p)
         return tok, cache
+
+    def _empty_row_tree(self):
+        """A 1-row cache as the model's first apply makes it."""
+        return jax.tree_util.tree_map_with_path(
+            lambda path, s: jnp.full(
+                s.shape, self._leaves[_leaf_name(path)].fill, s.dtype),
+            self._shapes)
+
+    def _chunk_and_sample(self, params, cache, chunk, n_real, total_len,
+                          temperature, top_k, top_p, seed, fold):
+        """One chunk of a long prompt against the row it has filled so
+        far; the sample is read after the last chunk only."""
+        logits, cache = prefill_continue(
+            self.cfg, params, cache, chunk, n_real, total_len)
+        tok = self._sample1(logits, seed, fold, temperature, top_k, top_p)
+        return tok, cache
+
+    def _is_long(self, n_tokens: int) -> bool:
+        return 0 < self._chunk_width < n_tokens
+
+    def _prefill_chunks(self, sampling: tuple, tokens: np.ndarray,
+                        fold: int, cache=None, start: int = 0):
+        """``tokens[start:]`` through the chunk program (tail padded,
+        ``true_len`` masking as in every prefill), continuing ``cache``,
+        a 1-row cache that holds the first ``start`` tokens (None: an
+        empty row); ``sampling`` is the request's :func:`_sampling_args`.
+        Returns (next token, the row's cache, chunks run)."""
+        C, L = self._chunk_width, int(tokens.size)
+        if cache is None:
+            cache = self._empty_row()
+        tok, chunks = None, 0
+        for at in range(start, L, C):
+            n = min(C, L - at)
+            tok, cache = self._chunk(
+                self.eng._params, cache, _padded(tokens[at:at + n], C),
+                jnp.asarray([n], jnp.int32), jnp.asarray([at + n], jnp.int32),
+                *sampling, jnp.int32(fold))
+            chunks += 1
+        self.prefill_chunks += chunks
+        _prefill_chunks_c.inc(chunks, model=self.eng.name)
+        return tok, cache, chunks
 
     def _prefill_batch_and_sample(self, params, prompts, true_lens, temps,
                                   top_ks, top_ps, seeds):
@@ -405,7 +458,7 @@ class RowCache(_CacheManager):
             if req is None:
                 break
             eng._admitted += 1
-            if req.prefix_len or cap <= 1:
+            if req.prefix_len or cap <= 1 or self._is_long(req.prompt.size):
                 self._admit_row(req, slot)
             else:
                 groups.setdefault(
@@ -450,6 +503,9 @@ class RowCache(_CacheManager):
     def _prefill_row(self, req, tokens: np.ndarray, fold: int,
                      bucket: Optional[int] = None):
         L = int(tokens.size)
+        if self._is_long(L):
+            return self._prefill_chunks(_sampling_args(req), tokens,
+                                        fold)[:2]
         bucket = bucket or pow2_bucket(L, self.cfg.max_seq_len)
         temperature, top_k, top_p, seed = _sampling_args(req)
         return self._prefill(
@@ -467,11 +523,13 @@ class RowCache(_CacheManager):
             return cached
         N = prefix.size
         # sampling args are dummies — only the cache is kept
-        _, pcache = self._prefill(
-            self.eng._params,
-            _padded(prefix, pow2_bucket(N, self.cfg.max_seq_len)),
-            jnp.asarray([N], jnp.int32), jnp.float32(0.0),
-            jnp.int32(0), jnp.float32(1.0), jnp.int32(0), jnp.int32(0))
+        if self._is_long(N):
+            _, pcache, _ = self._prefill_chunks(_no_sampling(), prefix, 0)
+        else:
+            _, pcache = self._prefill(
+                self.eng._params,
+                _padded(prefix, pow2_bucket(N, self.cfg.max_seq_len)),
+                jnp.asarray([N], jnp.int32), *_no_sampling(), jnp.int32(0))
         # byte-budget admission: evict LRU until the new row fits
         # (check_submit already routed away callers that can never fit)
         while (self._prefix_store and self.prefix_cache_bytes
@@ -496,7 +554,7 @@ class RowCache(_CacheManager):
             with eng.tracer.span("engine.admit", parent=req.ctx, attrs={
                     "model": eng.name, "slot": slot,
                     "prompt_tokens": int(S), "batched": False,
-                    "round": eng.rounds_total}), \
+                    "round": eng.rounds_total}) as adm, \
                     eng._mesh_ctx():
                 # prefill phase opens here (prefix-row prep IS prefill
                 # work); admission was the gap since _note_queue_wait
@@ -513,13 +571,26 @@ class RowCache(_CacheManager):
                         sbucket = suf
                     with eng.tracer.span("engine.prefill", attrs={
                             "prompt_tokens": int(S),
-                            "prefix_len": int(N)}):
-                        tok, row_cache = self._continue(
-                            eng._params, pcache,
-                            _padded(req.prompt[N:], sbucket),
-                            jnp.asarray([suf], jnp.int32),
-                            jnp.asarray([S], jnp.int32),
-                            *_sampling_args(req))
+                            "prefix_len": int(N)}) as span:
+                        if self._is_long(suf):
+                            tok, row_cache, chunks = self._prefill_chunks(
+                                _sampling_args(req), req.prompt, 0, pcache,
+                                N)
+                            span.attrs["chunks"] = adm.attrs["chunks"] = chunks
+                        else:
+                            tok, row_cache = self._continue(
+                                eng._params, pcache,
+                                _padded(req.prompt[N:], sbucket),
+                                jnp.asarray([suf], jnp.int32),
+                                jnp.asarray([S], jnp.int32),
+                                *_sampling_args(req))
+                elif self._is_long(S):
+                    with eng.tracer.span("engine.prefill", attrs={
+                            "prompt_tokens": int(S),
+                            "bucket": self._chunk_width}) as span:
+                        tok, row_cache, chunks = self._prefill_chunks(
+                            _sampling_args(req), req.prompt, 0)
+                        span.attrs["chunks"] = adm.attrs["chunks"] = chunks
                 else:
                     bucket = pow2_bucket(S, Smax)
                     with eng.tracer.span("engine.prefill", attrs={
@@ -650,10 +721,7 @@ class PagedCache(_CacheManager):
                  prefix_cache_entries: int,
                  prefix_cache_bytes: Optional[int]) -> None:
         config = eng.config
-        if config.has_recurrent_state:
-            raise ValueError(
-                "paged=True needs a cache that positions index; this "
-                "model keeps a recurrent state per slot")
+        refuse_unless_kv_cache(config, "paged=True maps pages of")
         # geometry: the largest power-of-two divisor of max_seq_len up
         # to 64; a full pool (slots × pages-per-row), where a smaller
         # kv_pages sizes HBM by LIVE tokens (admission gates on pages)

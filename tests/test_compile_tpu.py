@@ -226,3 +226,95 @@ def test_hybrid_k_step_program_rewrites_no_leaf_whole(one_chip, no_cache,
                if re.search(rf" = \(?{state}", line)}
     assert writers - {"parameter", "get-tuple-element"} == {"custom-call"}
     assert len(re.findall(r"%kda\.step[\w.]* = ", text)) == cfg.n_kda
+
+
+# DeepSeek-V3.2's sparse latent attention (kubeflow_tpu/models/hybrid.py
+# "dsa" layers, kubeflow_tpu/ops/dsa.py) at the published widths, one
+# dense-MLP layer and one routed: 16 of 256 experts held, 16 slots x 32768
+DSA = dict(vocab_size=16160, d_model=7168, n_heads=128,
+           layer_types=("dsa", "dsa"), first_k_dense=1, d_ff=18432,
+           max_seq_len=32768, q_lora_rank=1536, kv_lora_rank=512,
+           qk_nope_dim=128, qk_rope_dim=64, v_head_dim=128,
+           use_qk_norm=False, head_gate=False, rope_theta=10000.0,
+           rope_factor=40.0, rope_original_len=4096,
+           rope_mscale_all_dim=1.0, index_n_heads=64, index_head_dim=128,
+           index_topk=2048, prefill_chunk=1024, n_experts=256,
+           experts_per_token=8, n_group=8, topk_group=4, d_expert=2048,
+           d_shared=2048, experts_held=(0, 16))
+DSA_SLOTS = 16
+
+
+@pytest.mark.parametrize("program", ["step", "chunk"])
+def test_sparse_attention_programs_compile_with_three_kernels_a_layer(
+        one_chip, no_cache, monkeypatch, program):
+    """The K-step program and the admission chunk of 1024 tokens, on the
+    TPU's own compiler: Mosaic takes the index, select and attend kernels
+    at the published shapes (one call each a layer), there is no sort of
+    a row's 32768 scores (``lax.top_k`` lowered to one, 3 ms a layer), and
+    the step copies no stacked cache leaf whole (the chunk program, whose
+    row is not donated because a stored prefix row must outlive it, copies
+    each leaf once on the way in: 0.25 GB of a 1-row cache)."""
+    import jax
+    import jax.numpy as jnp
+
+    from kubeflow_tpu.models.decode import (
+        decode_step_stats,
+        prefill,
+        prefill_continue,
+    )
+    from kubeflow_tpu.models.hybrid import HybridConfig, HybridDecoder
+    from kubeflow_tpu.ops import dsa
+
+    monkeypatch.setattr(dsa, "resolve_interpret", lambda interpret: False)
+    cfg = HybridConfig(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, **DSA)
+
+    def place(s):
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(place, jax.eval_shape(
+        lambda k: HybridDecoder(cfg).init(k, jnp.zeros((1, 8), jnp.int32)),
+        jax.random.key(0))["params"])
+
+    def cache_of(rows):
+        return jax.tree_util.tree_map(place, jax.eval_shape(
+            lambda p: prefill(cfg, p, jnp.zeros((rows, 1), jnp.int32))[1],
+            params))
+
+    if program == "step":
+        cache = cache_of(DSA_SLOTS)
+
+        def step(params, cache, tokens):
+            def body(carry, _):
+                cache, tokens = carry
+                logits, cache, stats = decode_step_stats(cfg, params, cache,
+                                                         tokens)
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                return (cache, nxt), (nxt, stats)
+            (cache, _), out = jax.lax.scan(body, (cache, tokens), None,
+                                           length=K)
+            return cache, out
+
+        text = jax.jit(step, donate_argnums=(1,)).lower(
+            params, cache,
+            place(jax.ShapeDtypeStruct((DSA_SLOTS,), jnp.int32))
+        ).compile().as_text()
+        rows = DSA_SLOTS
+    else:
+        cache = cache_of(1)
+        one = place(jax.ShapeDtypeStruct((1,), jnp.int32))
+        text = jax.jit(
+            lambda p, c, t, n, total: prefill_continue(cfg, p, c, t, n, total)
+        ).lower(params, cache,
+                place(jax.ShapeDtypeStruct((1, cfg.prefill_chunk),
+                                           jnp.int32)), one, one
+                ).compile().as_text()
+        rows = 1
+    assert set(cache) == {"positions", "latent", "index_k"}
+    for kernel in ("index", "select", "attend"):
+        calls = re.findall(rf"%dsa\.{kernel}[\w.]* = \S+ custom-call\(", text)
+        assert len(calls) == cfg.n_dsa, (kernel, calls)
+    assert not re.findall(r"= \(f32\[\d+,\d+,32768\]\S*, s32\S*\) sort\(",
+                          text)
+    for leaf in (f"bf16[2,{rows},32768,640]", f"bf16[2,{rows},32768,128]"):
+        copies = re.findall(rf"= {re.escape(leaf)}\S* copy\(", text)
+        assert len(copies) <= (program == "chunk"), (leaf, copies)

@@ -559,7 +559,9 @@ def test_burst_insert_failure_closes_engine(lm):
     from kubeflow_tpu.serving.engine import EngineClosed
 
     config, params = lm
-    eng = DecodeEngine(config, params, slots=4)  # autostarted loop
+    # the loop starts once both are queued: started first, its thread
+    # may admit the first alone (the row path) before the second arrives
+    eng = DecodeEngine(config, params, slots=4, autostart=False)
     try:
         def boom(*a, **k):
             raise RuntimeError("injected insert failure")
@@ -567,6 +569,7 @@ def test_burst_insert_failure_closes_engine(lm):
         eng._insert_rows = boom
         reqs = [eng.submit([5, 11, 17], max_new=4),
                 eng.submit([3, 2, 9], max_new=4)]
+        eng.start()
         for r in reqs:
             with pytest.raises(EngineClosed):
                 r.result()

@@ -96,7 +96,7 @@ def test_mla_matches_reference_expanded_and_absorbed(fresh):
     cfg, w, pc, params = _setup()
     x = _x((2, 11, 64))
     want = ref.mla_mixer(x, ref.layer_weights(w, "mla", 0), cfg)
-    got, _ = hybrid.MlaAttention(pc).apply(
+    got, _cache, _counts = hybrid.MlaAttention(pc).apply(
         {"params": params["layer_4"]["mixer"]}, x, _empty_cache(pc, 2), 0,
         fresh)
     np.testing.assert_allclose(got, want, atol=2e-5)
@@ -303,36 +303,59 @@ def test_engine_round_spans_carry_the_routing_counts():
 
 # -- (e) the share test ----------------------------------------------------------
 
-def test_four_shares_and_one_shared_expert_make_the_uncut_layer():
+def _dsa_setup():
+    """DeepSeek-V3.2's routed layer at toy widths: the same ``RoutedMlp``
+    and ``route()`` under its own reference and weight layout."""
+    import test_dsa
+
+    cfg, w, pc, params = test_dsa._setup()
+    return test_dsa.ref, cfg, w, pc, params["layer_2"]["mlp"], 1
+
+
+def _ling_setup():
     cfg, w, pc, params = _setup()
+    return ref, cfg, w, pc, params["layer_4"]["mlp"], 3
+
+
+@pytest.mark.parametrize("family,shares", [(_ling_setup, 4),
+                                           (_dsa_setup, 16)],
+                         ids=["ling-4", "deepseek-v3.2-16"])
+def test_four_shares_and_one_shared_expert_make_the_uncut_layer(family,
+                                                                shares):
+    """The shares of an expert-parallel layer (4 chips of Ling's
+    deployment, 16 of DeepSeek-V3.2's), with the shared expert that every
+    chip computes counted once, add up to the uncut reference layer."""
+    rf, cfg, w, pc, mlp, layer = family()
     x = _x((2, 10, 64), seed=9)
-    lw = ref.layer_weights(w, "moe", 3)
-    whole, _ = ref.routed_mlp(x, lw, cfg)
-    mlp = params["layer_4"]["mlp"]
+    lw = rf.layer_weights(w, "moe", layer)
+    whole, _ = rf.routed_mlp(x, lw, cfg)
+    n = 16 // shares
     total = 0.0
-    for lo in (0, 4, 8, 12):
-        cut = {n: (v[lo:lo + 4] if n in ("gate_proj", "up_proj", "down_proj")
-                   else v) for n, v in mlp.items()}
+    for lo in range(0, 16, n):
+        cut = {k: (v[lo:lo + n] if k in ("gate_proj", "up_proj", "down_proj")
+                   else v) for k, v in mlp.items()}
         share = hybrid.RoutedMlp(
-            hybrid.dataclasses.replace(pc, experts_held=(lo, 4)))
+            hybrid.dataclasses.replace(pc, experts_held=(lo, n)))
         y, _hit, _pairs = share.apply({"params": cut}, x)
         total = total + y
         # the reference's share is the same part
-        lw_cut = dict(lw, **{n: lw[n][lo:lo + 4]
-                             for n in ("exp_gate", "exp_up", "exp_down")})
+        lw_cut = dict(lw, **{k: lw[k][lo:lo + n]
+                             for k in ("exp_gate", "exp_up", "exp_down")})
         np.testing.assert_allclose(
-            y, ref.routed_mlp(x, lw_cut, cfg, held=(lo, 4))[0], atol=2e-5)
+            y, rf.routed_mlp(x, lw_cut, cfg, held=(lo, n))[0], atol=2e-5)
     flat = x.reshape(20, 64)
-    shared = ref.swiglu(flat, lw["sh_gate"], lw["sh_up"], lw["sh_down"],
-                        None).reshape(x.shape)
-    np.testing.assert_allclose(total - 3 * shared, whole, atol=5e-5)
+    shared = rf.swiglu(flat, lw["sh_gate"], lw["sh_up"], lw["sh_down"],
+                       None).reshape(x.shape)
+    np.testing.assert_allclose(total - (shares - 1) * shared, whole,
+                               atol=1e-4)
 
 
 # -- (f) what the engine refuses for this model ----------------------------------
 
 def test_engine_refuses_paged_prefix_reuse_and_speculation():
     _cfg, _w, pc, params = _setup(**_SHORT)
-    with pytest.raises(ValueError, match="recurrent state"):
+    with pytest.raises(ValueError, match="also holds kda_conv, kda_state, "
+                                         "latent"):
         _engine(pc, params, paged=True)
     engine = _engine(pc, params)
     try:
@@ -340,7 +363,7 @@ def test_engine_refuses_paged_prefix_reuse_and_speculation():
             engine.submit(np.arange(1, 9), max_new=2, prefix_len=4)
     finally:
         engine.close()
-    with pytest.raises(ValueError, match="recurrent state"):
+    with pytest.raises(ValueError, match="also holds kda_conv"):
         decode.speculative_generate(
             pc, params, pc, params, jnp.ones((1, 4), jnp.int32),
             max_new_tokens=2)
