@@ -19,7 +19,7 @@ compares each with its in-tree reference:
            must show they went through the DecodeEngine; SIGTERM must
            end the server
   kernels  one child, in process, ``interpret=False`` asserted: flash
-           fwd + both bwd at the table tiles for seq 8192 (numerics vs
+           fwd + fused bwd at the table tiles for seq 8192 (numerics vs
            ``reference_attention``, then ``bench_longcontext`` steps),
            the kv_len-masked bidirectional flash path at seq 512, the
            paged decode kernel vs the gather path at engine shapes,
@@ -541,7 +541,7 @@ def _rel_err(got, want) -> float:
 
 def check_flash(sizes: Sizes, *, seq: int, batch: int, heads: int,
                 causal: bool, masked: bool) -> Dict[str, Any]:
-    """Flash fwd + dQ + dK/dV at the table tiles vs the O(S^2) oracle."""
+    """Flash fwd + fused dQ/dK/dV at the table tiles vs the O(S^2) oracle."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -611,7 +611,7 @@ def check_longcontext(sizes: Sizes) -> Dict[str, Any]:
                  and math.prod(p["split"].values()) == p["devices"]
                  for p in placed)
     return {"ok": (sources == ["table"] and kernels == [
-                       "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+                       "flash_bwd_fused", "flash_fwd"]
                    and row["tokens_per_sec_per_chip"] > 0 and spread
                    and (row["n_chips"] == 1) == (not placed)),
             "tile_sources": sources, "kernels": kernels,

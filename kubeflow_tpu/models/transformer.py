@@ -800,8 +800,8 @@ class Block(nn.Module):
 def remat_block():
     """``Block`` recomputed in the backward (``remat=True``), except
     what only the flash forward kernel can produce: its output and
-    log-sum-exp keep their values, so a layer's backward runs the dQ and
-    dK/dV kernels and not the forward kernel a second time. Kept per
+    log-sum-exp keep their values, so a layer's backward runs the
+    backward kernel and not the forward kernel a second time. Kept per
     layer: B·S·H·Dh activations in the compute dtype plus B·H·S float32.
     The names exist only where the flash kernels ran
     (``ops/attention.py:_flash_vjp_fwd``); under any other

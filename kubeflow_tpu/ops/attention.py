@@ -10,9 +10,10 @@ they are framework ops:
   ring attention and the portable fallback everywhere.
 - :func:`flash_attention` — Pallas TPU kernels for the forward AND backward
   pass (VMEM block tiles, MXU matmuls, f32 accumulators): the forward saves
-  the per-row logsumexp, and dedicated dQ and dK/dV kernels replay blocks
-  against it instead of recomputing the softmax; ``interpret=True`` runs the
-  same kernels on CPU in tests.
+  the per-row logsumexp, and one fused dQ/dK/dV kernel replays blocks
+  against it instead of recomputing the softmax (a dQ and a dK/dV kernel
+  where a row's dQ does not fit VMEM); ``interpret=True`` runs the same
+  kernels on CPU in tests.
 - :func:`ring_attention` — sequence-parallel attention over a mesh axis:
   each device holds a sequence shard of Q/K/V and KV shards rotate around
   the ring via ``ppermute`` (one ICI hop per step when the axis is laid out
@@ -35,7 +36,11 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from kubeflow_tpu import compat
-from kubeflow_tpu.ops.autotune import resolve_flash
+from kubeflow_tpu.ops.autotune import (
+    flash_bwd_fuses,
+    fused_vmem_limit_bytes,
+    resolve_flash,
+)
 
 NEG_INF = -1e30
 
@@ -179,7 +184,7 @@ def _pad_mask(s, limit, j, block_k: int):
     """Mask KV positions at/past the row's valid length ``limit`` in
     one (block_q, block_k) score tile at kv block ``j`` — the padding
     mask of the bidirectional/BERT flash path. The SAME expression in
-    the forward and both backward kernels, or the backward's
+    the forward and every backward kernel, or the backward's
     recomputed P diverges from the forward's."""
     kv_pos = j * block_k + jax.lax.broadcasted_iota(
         jnp.int32, (1, block_k), 1)
@@ -394,22 +399,44 @@ def _flash_fwd(q, k, v, *, causal: bool, block_q: Optional[int],
     return out.reshape(B, H, S, D).transpose(0, 2, 1, 3), lse
 
 
+def _bwd_tile(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, limit,
+              i, j, *, block_q: int, block_k: int, scale: float,
+              causal: bool):
+    """What every backward kernel rebuilds of one live (q block ``i``,
+    kv block ``j``) tile from the forward's saved logsumexp: the
+    pre-scaled q, k and dO in float32, P and dS. One function, so the
+    masks and the operand types are the same wherever a tile is
+    replayed; ``limit`` is the row's valid length, or None."""
+    qs = q_ref[0].astype(jnp.float32) * scale  # pre-scaled, as in fwd
+    g = g_ref[0].astype(jnp.float32)
+    kb = k_ref[0].astype(jnp.float32)
+    vb = v_ref[0].astype(jnp.float32)
+    s = jax.lax.dot_general(qs, kb, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    if causal:
+        s = _causal_block_mask(s, i, j, block_q, block_k)
+    if limit is not None:
+        s = _pad_mask(s, limit, j, block_k)
+    p = jnp.exp(s - lse_ref[0])  # (block_q, block_k); lse (block_q, 1)
+    dp = jax.lax.dot_general(g, vb, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    ds = p * (dp - delta_ref[0])
+    return qs, kb, g, p, ds
+
+
 def _flash_bwd_dq_kernel(*refs, block_q: int, block_k: int,
                          scale: float, causal: bool, n_kv: int,
                          masked: bool = False):
     """dQ for one (batch·head, q-block, kv-block) grid step: the KV
     stream rides the innermost grid dimension (seq-independent VMEM,
     like the forward), recompute P from the saved logsumexp,
-    accumulate dS·K in f32 scratch, emit at the last kv step."""
+    accumulate dS·K in f32 scratch, emit at the last kv step. Runs,
+    with :func:`_flash_bwd_dkv_kernel`, only where a dQ row does not
+    fit VMEM (:func:`_flash_bwd`)."""
     import jax.experimental.pallas as pl
 
-    if masked:
-        q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, len_ref, \
-            dq_ref, acc_ref = refs
-    else:
-        q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dq_ref, \
-            acc_ref = refs
-        len_ref = None
+    *tile_refs, dq_ref, acc_ref = refs
+    len_ref = tile_refs.pop() if masked else None
 
     i = pl.program_id(1)
     j = pl.program_id(2)
@@ -423,22 +450,9 @@ def _flash_bwd_dq_kernel(*refs, block_q: int, block_k: int,
 
     @pl.when(live)
     def _update():
-        qs = q_ref[0].astype(jnp.float32) * scale  # pre-scaled, as in fwd
-        g = g_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]    # (block_q, 1)
-        delta = delta_ref[0]
-        kb = k_ref[0].astype(jnp.float32)
-        vb = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(qs, kb, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if causal:
-            s = _causal_block_mask(s, i, j, block_q, block_k)
-        if masked:
-            s = _pad_mask(s, limit, j, block_k)
-        p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(g, vb, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
+        _, kb, _, _, ds = _bwd_tile(
+            *tile_refs, limit, i, j, block_q=block_q, block_k=block_k,
+            scale=scale, causal=causal)
         acc_ref[...] = acc_ref[...] + jax.lax.dot_general(
             ds, kb, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -448,23 +462,28 @@ def _flash_bwd_dq_kernel(*refs, block_q: int, block_k: int,
         dq_ref[0] = (acc_ref[...] * scale).astype(dq_ref.dtype)
 
 
-def _flash_bwd_dkv_kernel(*refs, block_q: int,
-                          block_k: int, scale: float, causal: bool,
-                          n_q: int, masked: bool = False):
-    """dK/dV for one (batch·head, kv-block, q-block) grid step: the Q
-    stream rides the innermost grid dimension; causal steps before this
-    kv block's first contributing q block move and compute nothing.
-    Recompute P, accumulate Pᵀ·dO and dSᵀ·Q in f32 scratch, emit at
-    the last q step (which causality never skips)."""
+def _bwd_q_stream(refs, *, fused: bool, block_q: int, block_k: int,
+                  scale: float, causal: bool, n_q: int, n_kv: int,
+                  masked: bool):
+    """One (batch·head, kv-block, q-block) grid step of the backward
+    kernels that stream Q innermost; causal steps before this kv
+    block's first contributing q block move and compute nothing.
+    Recompute P and dS once, accumulate Pᵀ·dO and dSᵀ·Q in f32 scratch
+    and emit them at the last q step (which causality never skips).
+
+    ``fused`` adds dQ to the same pass: dS·K goes into the q block's
+    rows of an f32 scratch that holds the WHOLE row's dQ, zeroed at the
+    row's first grid step and written out at its last. The dQ output's
+    block is that whole row with an index map constant in ``j`` and
+    ``i``, so it goes back to HBM once a row. Each dQ block sums over
+    ``j`` ascending, as :func:`_flash_bwd_dq_kernel` sums it."""
     import jax.experimental.pallas as pl
 
-    if masked:
-        q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, len_ref, \
-            dk_ref, dv_ref, dk_acc, dv_acc = refs
+    if fused:
+        *tile_refs, dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = refs
     else:
-        q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dk_ref, dv_ref, \
-            dk_acc, dv_acc = refs
-        len_ref = None
+        *tile_refs, dk_ref, dv_ref, dk_acc, dv_acc = refs
+    len_ref = tile_refs.pop() if masked else None
 
     j = pl.program_id(1)  # kv-block index
     i = pl.program_id(2)  # q-block index
@@ -475,38 +494,51 @@ def _flash_bwd_dkv_kernel(*refs, block_q: int,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
+    if fused:
+        @pl.when((j == 0) & (i == 0))
+        def _init_row():
+            dq_acc[...] = jnp.zeros_like(dq_acc)
+
     live = (i >= _first_live_q(j, block_q, block_k)) if causal else True
 
     @pl.when(live)
     def _update():
-        kb = k_ref[0].astype(jnp.float32)
-        vb = v_ref[0].astype(jnp.float32)
-        qs = q_ref[0].astype(jnp.float32) * scale
-        g = g_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]    # (block_q, 1)
-        delta = delta_ref[0]
-        s = jax.lax.dot_general(qs, kb, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if causal:
-            s = _causal_block_mask(s, i, j, block_q, block_k)
-        if masked:
-            s = _pad_mask(s, limit, j, block_k)
-        p = jnp.exp(s - lse)  # (block_q, block_k)
+        qs, kb, g, p, ds = _bwd_tile(
+            *tile_refs, limit, i, j, block_q=block_q, block_k=block_k,
+            scale=scale, causal=causal)
         dv_acc[...] = dv_acc[...] + jax.lax.dot_general(
             p, g, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(g, vb, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
         # dK = dSᵀ·(q·scale) — the scale chains through the pre-scaled q
         dk_acc[...] = dk_acc[...] + jax.lax.dot_general(
             ds, qs, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        if fused:
+            rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+            dq_acc[rows, :] = dq_acc[rows, :] + jax.lax.dot_general(
+                ds, kb, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
     @pl.when(i == n_q - 1)
     def _emit():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+    if fused:
+        @pl.when((j == n_kv - 1) & (i == n_q - 1))
+        def _emit_row():
+            dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
+
+
+def _flash_bwd_dkv_kernel(*refs, **static):
+    """dK/dV alone: :func:`_bwd_q_stream` without the dQ row."""
+    _bwd_q_stream(refs, fused=False, **static)
+
+
+def _flash_bwd_fused_kernel(*refs, **static):
+    """dQ, dK and dV in one pass over the score tiles: five products
+    and one softmax recompute a tile (:func:`_bwd_q_stream`)."""
+    _bwd_q_stream(refs, fused=True, **static)
 
 
 def _flash_bwd(q, k, v, o, lse, g, *, causal: bool,
@@ -516,20 +548,6 @@ def _flash_bwd(q, k, v, o, lse, g, *, causal: bool,
     from jax.experimental.pallas import tpu as pltpu
 
     B, S, H, D = q.shape
-    # the dQ and dK/dV kernels stream opposite axes, so their optima
-    # are INDEPENDENT shape classes — each resolves its own tile pair
-    # (an explicit override pins both, the pre-PR behavior)
-    shape_kw = dict(seq=S, head_dim=D, n_heads=H, n_kv_heads=k.shape[2],
-                    dtype=q.dtype, causal=causal, block_q=block_q,
-                    block_k=block_k)
-    cfg_dq = resolve_flash("flash_bwd_dq", **shape_kw)
-    cfg_kv = resolve_flash("flash_bwd_dkv", **shape_kw)
-    bq_dq, bk_dq = min(cfg_dq.block_q, S), min(cfg_dq.block_k, S)
-    bq_kv, bk_kv = min(cfg_kv.block_q, S), min(cfg_kv.block_k, S)
-    for bq, bk in ((bq_dq, bk_dq), (bq_kv, bk_kv)):
-        if S % bq or S % bk:
-            raise ValueError(
-                f"seq_len {S} must divide by blocks {bq}/{bk}")
     scale = _scale(q, sm_scale)
     masked = kv_len is not None
 
@@ -539,71 +557,78 @@ def _flash_bwd(q, k, v, o, lse, g, *, causal: bool,
     # trailing singleton for a legal TPU block layout (see lse)
     delta = jnp.sum(gf.astype(jnp.float32) * of.astype(jnp.float32),
                     axis=-1, keepdims=True)
-    lens = _fused_lens(kv_len, H) if masked else None
-
-    n_q, n_kv = S // bq_dq, S // bk_dq
-    blk_q = lambda b, i, j: (b, i, 0)  # noqa: E731
-    kv_map = _causal_clamp_kv(bq_dq, bk_dq, causal)
-
     inputs = [qf, kf, vf, gf, lse, delta]
-    in_specs = [
-        pl.BlockSpec((1, bq_dq, D), blk_q, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, bk_dq, D), kv_map, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, bk_dq, D), kv_map, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, bq_dq, D), blk_q, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, bq_dq, 1), blk_q, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, bq_dq, 1), blk_q, memory_space=pltpu.VMEM),
-    ]
     if masked:
-        inputs.append(lens)
-        in_specs.append(_len_spec(pl, pltpu))
-    dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, block_q=bq_dq,
-                          block_k=bk_dq, scale=scale, causal=causal,
-                          n_kv=n_kv, masked=masked),
-        grid=(B * H, n_q, n_kv),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, bq_dq, D), blk_q,
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq_dq, D), jnp.float32)],
-        interpret=interpret,
-    )(*inputs)
+        inputs.append(_fused_lens(kv_len, H))
 
-    n_q, n_kv = S // bq_kv, S // bk_kv
-    q_map = _causal_clamp_q(bq_kv, bk_kv, causal)
+    def tiles(kernel: str):
+        """Each kernel is its own shape class and resolves its own tile
+        pair (an explicit override pins them all)."""
+        cfg = resolve_flash(
+            kernel, seq=S, head_dim=D, n_heads=H, n_kv_heads=k.shape[2],
+            dtype=q.dtype, causal=causal, block_q=block_q, block_k=block_k)
+        bq, bk = min(cfg.block_q, S), min(cfg.block_k, S)
+        if S % bq or S % bk:
+            raise ValueError(f"seq_len {S} must divide by blocks {bq}/{bk}")
+        return bq, bk
+
+    def call(kernel, grid, bq, bk, q_map, kv_map, outs, scratch,
+             vmem_limit=None, **static):
+        """One backward kernel over ``inputs``: q-sized blocks (q, dO,
+        lse, delta) by ``q_map``, k and v by ``kv_map``; ``outs`` as
+        (block rows, index map, dtype), ``scratch`` as rows of f32."""
+        in_specs = [
+            pl.BlockSpec((1, bq, D), q_map, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, bk, D), kv_map, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, bk, D), kv_map, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, bq, D), q_map, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, bq, 1), q_map, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, bq, 1), q_map, memory_space=pltpu.VMEM),
+        ]
+        if masked:
+            in_specs.append(_len_spec(pl, pltpu))
+        return pl.pallas_call(
+            functools.partial(kernel, block_q=bq, block_k=bk, scale=scale,
+                              causal=causal, masked=masked, **static),
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=[pl.BlockSpec((1, rows, D), index_map,
+                                    memory_space=pltpu.VMEM)
+                       for rows, index_map, _ in outs],
+            out_shape=[jax.ShapeDtypeStruct((B * H, S, D), dtype)
+                       for _, _, dtype in outs],
+            scratch_shapes=[pltpu.VMEM((rows, D), jnp.float32)
+                            for rows in scratch],
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=vmem_limit),
+            interpret=interpret,
+        )(*inputs)
+
     blk_kv = lambda b, j, i: (b, j, 0)  # noqa: E731
-
-    inputs = [qf, kf, vf, gf, lse, delta]
-    in_specs = [
-        pl.BlockSpec((1, bq_kv, D), q_map, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, bk_kv, D), blk_kv, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, bk_kv, D), blk_kv, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, bq_kv, D), q_map, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, bq_kv, 1), q_map, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, bq_kv, 1), q_map, memory_space=pltpu.VMEM),
-    ]
-    if masked:
-        inputs.append(lens)
-        in_specs.append(_len_spec(pl, pltpu))
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, block_q=bq_kv,
-                          block_k=bk_kv, scale=scale, causal=causal,
-                          n_q=n_q, masked=masked),
-        grid=(B * H, n_kv, n_q),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, bk_kv, D), blk_kv, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk_kv, D), blk_kv, memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B * H, S, D), k.dtype),
-            jax.ShapeDtypeStruct((B * H, S, D), v.dtype),
-        ],
-        scratch_shapes=[pltpu.VMEM((bk_kv, D), jnp.float32),
-                        pltpu.VMEM((bk_kv, D), jnp.float32)],
-        interpret=interpret,
-    )(*inputs)
+    if flash_bwd_fuses(S, D, q.dtype):
+        bq, bk = tiles("flash_bwd_fused")
+        dq, dk, dv = call(
+            _flash_bwd_fused_kernel, (B * H, S // bk, S // bq), bq, bk,
+            _causal_clamp_q(bq, bk, causal), blk_kv,
+            outs=[(S, lambda b, j, i: (b, 0, 0), q.dtype),
+                  (bk, blk_kv, k.dtype), (bk, blk_kv, v.dtype)],
+            scratch=[S, bk, bk], n_q=S // bq, n_kv=S // bk,
+            vmem_limit=fused_vmem_limit_bytes(S, D, q.dtype.itemsize))
+    else:
+        # the row does not fit: dQ streams KV, dK/dV stream Q, and the
+        # score tiles are rebuilt in both
+        bq, bk = tiles("flash_bwd_dq")
+        blk_q = lambda b, i, j: (b, i, 0)  # noqa: E731
+        dq, = call(
+            _flash_bwd_dq_kernel, (B * H, S // bq, S // bk), bq, bk,
+            blk_q, _causal_clamp_kv(bq, bk, causal),
+            outs=[(bq, blk_q, q.dtype)], scratch=[bq], n_kv=S // bk)
+        bq, bk = tiles("flash_bwd_dkv")
+        dk, dv = call(
+            _flash_bwd_dkv_kernel, (B * H, S // bk, S // bq), bq, bk,
+            _causal_clamp_q(bq, bk, causal), blk_kv,
+            outs=[(bk, blk_kv, k.dtype), (bk, blk_kv, v.dtype)],
+            scratch=[bk, bk], n_q=S // bq, n_kv=S // bk)
 
     unfuse = lambda x: x.reshape(B, H, S, D).transpose(0, 2, 1, 3)  # noqa: E731
     return unfuse(dq), unfuse(dk), unfuse(dv)
@@ -636,22 +661,27 @@ def flash_attention(q, k, v, causal: bool = True,
                     interpret: Optional[bool] = None, kv_len=None):
     """Pallas flash attention: fwd AND bwd kernels (saved-LSE backward).
 
-    The backward is the standard flash split — a dQ kernel streaming KV
-    blocks and a dK/dV kernel streaming Q blocks — recomputing P from the
-    forward's saved logsumexp, so training never materializes (S, S) and
-    both passes run on the MXU from VMEM tiles.
+    The backward recomputes P from the forward's saved logsumexp, so
+    training never materializes (S, S) and both passes run on the MXU
+    from VMEM tiles. It is ONE kernel streaming Q blocks per KV block,
+    with the row's dQ resident in VMEM (five products and one softmax
+    recompute a tile), wherever that row fits; past that (the shape
+    decides: ``autotune.flash_bwd_fuses``) it is the standard flash
+    split, a dQ kernel streaming KV blocks and a dK/dV kernel streaming
+    Q blocks (seven products, two recomputes).
 
     ``block_q``/``block_k`` are INDEPENDENT tile knobs. ``None`` (the
     default) resolves each kernel's tiles from the committed shape-keyed
-    tile table — ``flash_fwd``, ``flash_bwd_dq`` and ``flash_bwd_dkv``
-    are separate kernel keys, so the chip sweep can tune each pass —
-    with an analytic VMEM-budget fallback when the shape class has no
-    entry (``kubeflow_tpu/ops/autotune.py``). Explicit values override
-    the table for every kernel (the pre-PR behavior).
+    tile table — ``flash_fwd``, ``flash_bwd_fused``, ``flash_bwd_dq``
+    and ``flash_bwd_dkv`` are separate kernel keys, so the chip sweep
+    can tune each pass and a recorded resolution says which backward
+    ran — with an analytic VMEM-budget fallback when the shape class
+    has no entry (``kubeflow_tpu/ops/autotune.py``). Explicit values
+    override the table for every kernel (the pre-PR behavior).
 
     ``kv_len`` is an optional per-row valid-length ``(B,)`` int32: KV
     positions at/past a row's length are masked out in the forward AND
-    both backward kernels — the padding mask of the bidirectional/BERT
+    every backward kernel — the padding mask of the bidirectional/BERT
     path (``reference_attention(kv_len=...)`` is the parity oracle).
     Rows whose cotangent is zero at padded positions get exact
     gradients; outputs AT padded q positions are unspecified (mask them

@@ -7,10 +7,10 @@ MXU) — yet every kernel shipped ONE hardcoded default. This module is
 the selection plane every tuned kernel consults instead of growing
 another constant:
 
-- a **kernel key** (``flash_fwd`` / ``flash_bwd_dq`` / ``flash_bwd_dkv``
-  / ``paged_attn``) plus a **shape class** (seq bucket, head_dim,
-  n_heads / n_kv_heads, dtype, causal, backend generation) maps to a
-  measured tile config — ``(block_q, block_k)`` as independent knobs
+- a **kernel key** (``flash_fwd`` / ``flash_bwd_fused`` /
+  ``flash_bwd_dq`` / ``flash_bwd_dkv`` / ``paged_attn``) plus a
+  **shape class** (seq bucket, head_dim, n_heads / n_kv_heads, dtype,
+  causal, backend generation) maps to a measured tile config — ``(block_q, block_k)`` as independent knobs
   for the flash kernels, the KV ``head_block`` group for the paged
   kernel;
 - the table is a versioned, committed JSON file
@@ -40,7 +40,8 @@ import os
 import warnings
 from typing import Any, Dict, Iterator, List, Optional, Tuple
 
-KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "paged_attn")
+KERNELS = ("flash_fwd", "flash_bwd_fused", "flash_bwd_dq", "flash_bwd_dkv",
+           "paged_attn")
 
 # the scoped-VMEM limit the r05 round hit at 16.75 MB of residency —
 # the budget every analytic estimate is checked against
@@ -124,17 +125,24 @@ def backend_generation() -> str:
 
 
 def flash_vmem_bytes(kernel: str, block_q: int, block_k: int,
-                     head_dim: int, dtype_bytes: int) -> int:
+                     head_dim: int, dtype_bytes: int, seq: int = 0) -> int:
     """Per-grid-step VMEM residency estimate for one flash kernel.
 
     I/O blocks are doubled for the grid pipeline's double buffering;
     the f32 score/probability tile (``block_q × block_k``) is the term
     that reproduces the r05 wall — it is exactly what pushes 2048-edge
-    tiles past the 16 MB scoped budget while 1024 fits.
+    tiles past the 16 MB scoped budget while 1024 fits. ``seq`` counts
+    only for ``flash_bwd_fused``, the one kernel that keeps a whole row.
     """
     f32 = 4
     d = head_dim
     score = block_q * block_k * f32
+    if kernel == "flash_bwd_fused":
+        # the dkv kernel's blocks, and for the life of a batch·head row
+        # its dQ: the f32 accumulator and the output block it leaves in
+        row = seq * d * (f32 + dtype_bytes)
+        return row + flash_vmem_bytes("flash_bwd_dkv", block_q, block_k,
+                                      head_dim, dtype_bytes)
     if kernel == "flash_fwd":
         # in: q, k, v; out: o, lse — scratch: f32 acc + m + l
         io = (2 * block_q * d + 2 * block_k * d) * dtype_bytes + block_q * f32
@@ -152,6 +160,20 @@ def flash_vmem_bytes(kernel: str, block_q: int, block_k: int,
     else:
         raise ValueError(f"unknown flash kernel {kernel!r}")
     return 2 * io + scratch + score
+
+
+def fused_vmem_limit_bytes(seq: int, head_dim: int, dtype_bytes: int) -> int:
+    """The scope the fused backward asks Mosaic for
+    (``vmem_limit_bytes``), where :func:`flash_vmem_bytes` is the
+    estimate its tile is chosen by: the default scope, which the tile
+    fits as the dK/dV kernel's does, and the dQ row as VMEM lays it out,
+    a head under 128 lanes padded to them, the f32 accumulator once and
+    the output block in its two pipeline buffers. Compiled for a v5e at
+    (8192, 64) bf16 and 1024-edge tiles the kernel takes 24.1 MiB of
+    the 25 this gives; the chip has 128 MiB."""
+    lanes = -(-head_dim // LANE_MULTIPLE) * LANE_MULTIPLE
+    return (VMEM_BUDGET_BYTES + seq * lanes * (4 + 2 * dtype_bytes)
+            + 2 ** 20)
 
 
 def paged_vmem_bytes(page_size: int, n_heads: int, n_kv_heads: int,
@@ -288,7 +310,8 @@ def validate_entry(entry: Dict[str, Any],
     if bk % LANE_MULTIPLE:
         errs.append(f"block_k {bk} is not a multiple of the 128 lane "
                     "tile (the score tile's lane axis)")
-    vm = flash_vmem_bytes(kernel, bq, bk, head_dim, nbytes)
+    # the longest sequence of the bucket is the bucket itself
+    vm = flash_vmem_bytes(kernel, bq, bk, head_dim, nbytes, seq=sb or 0)
     if vm > budget:
         errs.append(f"VMEM estimate {vm} bytes exceeds the "
                     f"{budget}-byte scoped budget (the r05 wall that "
@@ -519,11 +542,22 @@ def _fallback_flash(kernel: str, seq: int, head_dim: int,
     nbytes = DTYPE_BYTES.get(dtype_name(dtype), 4)
     edge = min(MAX_TILE_EDGE, seq_bucket(seq))
     while edge > 1:
-        if flash_vmem_bytes(kernel, edge, edge, head_dim,
-                            nbytes) <= VMEM_BUDGET_BYTES:
+        if flash_vmem_bytes(kernel, edge, edge, head_dim, nbytes,
+                            seq=seq) <= VMEM_BUDGET_BYTES:
             return edge, edge
         edge //= 2
     return 1, 1
+
+
+def flash_bwd_fuses(seq: int, head_dim: int, dtype: Any) -> bool:
+    """Which backward a shape takes (``ops/attention.py:_flash_bwd``):
+    the fused kernel wherever a dQ row of ``seq × head_dim`` fits the
+    budget beside the smallest legal tile, the dQ and dK/dV pair past
+    that. The shape decides alone, so a recorded resolution
+    (:func:`record_resolutions`) says which path ran."""
+    nbytes = DTYPE_BYTES.get(dtype_name(dtype), 4)
+    return flash_vmem_bytes("flash_bwd_fused", LANE_MULTIPLE, LANE_MULTIPLE,
+                            head_dim, nbytes, seq=seq) <= VMEM_BUDGET_BYTES
 
 
 def resolve_flash(kernel: str, *, seq: int, head_dim: int, n_heads: int,

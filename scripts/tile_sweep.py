@@ -61,6 +61,17 @@ def _time_best(fn, warmup: int = 1, iters: int = 3) -> float:
     return best * 1e3
 
 
+def _bwd_keys(shape) -> tuple:
+    """The backward kernels a shape's fwd+bwd timing ran: the fused
+    one where its dQ row fits, else the dQ and dK/dV pair."""
+    from kubeflow_tpu.ops import autotune
+
+    if autotune.flash_bwd_fuses(shape["seq"], shape["head_dim"],
+                                "bfloat16"):
+        return ("flash_bwd_fused",)
+    return ("flash_bwd_dq", "flash_bwd_dkv")
+
+
 def sweep(args) -> dict:
     import jax
     import jax.numpy as jnp
@@ -88,9 +99,9 @@ def sweep(args) -> dict:
                 point["skip"] = "blocks do not divide seq"
                 print(json.dumps(point), flush=True)
                 continue
-            vm = max(autotune.flash_vmem_bytes(kname, bq, bk, D, nbytes)
-                     for kname in ("flash_fwd", "flash_bwd_dq",
-                                   "flash_bwd_dkv"))
+            vm = max(autotune.flash_vmem_bytes(kname, bq, bk, D, nbytes,
+                                               seq=S)
+                     for kname in ("flash_fwd", *_bwd_keys(shape)))
             if vm > autotune.VMEM_BUDGET_BYTES:
                 point["skip"] = (f"VMEM estimate {vm} over budget "
                                  f"{autotune.VMEM_BUDGET_BYTES}")
@@ -198,7 +209,7 @@ def update_table(result: dict, paged_result: dict, path: str) -> None:
         put(dict(kernel="flash_fwd", block_q=bq, block_k=bk,
                  provenance=f"tile_sweep {gen}: fwd {ms} ms", **base))
         (bq, bk), ms = w["fwdbwd"]
-        for kname in ("flash_bwd_dq", "flash_bwd_dkv"):
+        for kname in _bwd_keys(shape):
             put(dict(kernel=kname, block_q=bq, block_k=bk,
                      provenance=f"tile_sweep {gen}: fwd+bwd {ms} ms",
                      **base))

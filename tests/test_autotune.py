@@ -37,6 +37,37 @@ class TestResolution:
         assert cfg.source == "table"
         assert (cfg.block_q, cfg.block_k) == (1024, 1024)
 
+    @pytest.mark.parametrize("seq, causal, tile", [
+        (512, False, 512), (8192, True, 1024), (16384, True, 1024),
+        (32768, True, 512)])
+    def test_fused_backward_rows_resolve_from_table(self, seq, causal,
+                                                    tile):
+        """The fused backward is its own key, so a recorded resolution
+        says which backward a shape took; its rows were seeded from the
+        dK/dV ones, less the tile a 32768-long dQ row leaves no room
+        for."""
+        assert autotune.flash_bwd_fuses(seq, 64, jnp.bfloat16)
+        cfg = autotune.resolve_flash(
+            "flash_bwd_fused", seq=seq, **dict(R05_SHAPE, causal=causal))
+        assert (cfg.source, cfg.block_q, cfg.block_k) == (
+            "table", tile, tile)
+
+    def test_fused_backward_falls_back_to_a_tile_beside_its_row(self):
+        """No row for head size 128: the analytic choice is the largest
+        edge that fits WITH the dQ row, smaller than the dK/dV kernel's
+        at the same shape."""
+        shape = dict(seq=16384, head_dim=128, n_heads=8, n_kv_heads=8,
+                     dtype=jnp.bfloat16, causal=True)
+        assert autotune.flash_bwd_fuses(16384, 128, jnp.bfloat16)
+        fused = autotune.resolve_flash("flash_bwd_fused", **shape)
+        pair = autotune.resolve_flash("flash_bwd_dkv", **shape)
+        assert (fused.source, pair.source) == ("fallback", "fallback")
+        assert fused.block_q < pair.block_q == 1024
+        assert autotune.flash_vmem_bytes(
+            "flash_bwd_fused", fused.block_q, fused.block_k, 128, 2,
+            seq=16384) <= autotune.VMEM_BUDGET_BYTES
+        assert not autotune.flash_bwd_fuses(32768, 128, jnp.bfloat16)
+
     def test_bert_bidirectional_shape_resolves_from_table(self):
         cfg = autotune.resolve_flash(
             "flash_fwd", seq=512, head_dim=64, n_heads=12, n_kv_heads=12,
@@ -157,6 +188,33 @@ class TestTableIO:
         assert any("VMEM" in e for e in errs)
         entry.update(block_q=1024, block_k=1024)
         assert autotune.validate_entry(entry) == []
+
+    @pytest.mark.parametrize("nbytes", [2, 4], ids=["bf16", "f32"])
+    def test_fused_backward_estimate_grows_by_the_row(self, nbytes):
+        """The dK/dV kernel's blocks plus, for the life of a row, the
+        f32 dQ accumulator and the output block it leaves in."""
+        for seq in (512, 8192, 32768):
+            assert autotune.flash_vmem_bytes(
+                "flash_bwd_fused", 512, 256, 64, nbytes, seq=seq
+            ) - autotune.flash_vmem_bytes(
+                "flash_bwd_dkv", 512, 256, 64, nbytes
+            ) == seq * 64 * (4 + nbytes)
+
+    def test_fused_backward_entry_validated_with_its_row(self):
+        """A bucket's longest sequence is the bucket: 1024-edge tiles
+        fit beside an 8192 or 16384 row and not beside a 32768 one."""
+        entry = {"kernel": "flash_bwd_fused", "seq_bucket": 8192,
+                 "head_dim": 64, "dtype": "bfloat16", "causal": True,
+                 "generation": "*", "block_q": 1024, "block_k": 1024}
+        assert autotune.validate_entry(entry) == []
+        assert autotune.validate_entry(dict(entry, seq_bucket=16384)) == []
+        errs = autotune.validate_entry(dict(entry, seq_bucket=32768))
+        assert any("VMEM" in e for e in errs)
+        assert autotune.validate_entry(dict(
+            entry, seq_bucket=32768, block_q=512, block_k=512)) == []
+        # the same tile is legal for the kernel that keeps no row
+        assert autotune.validate_entry(dict(
+            entry, kernel="flash_bwd_dkv", seq_bucket=32768)) == []
 
     def test_strict_load_raises_on_illegal(self, tmp_path):
         path = tmp_path / "bad.json"
@@ -320,13 +378,17 @@ class TestTableLint:
         findings = self._run(monkeypatch, autotune.DEFAULT_TABLE_PATH)
         assert findings == []
 
+    @pytest.mark.parametrize("kernel, seq_bucket, block", [
+        ("flash_fwd", 8192, 2048), ("flash_bwd_fused", 32768, 1024)],
+        ids=["tile-over-budget", "fused-row-over-budget"])
     def test_illegal_entry_flagged_against_json(self, monkeypatch,
-                                                tmp_path):
+                                                tmp_path, kernel,
+                                                seq_bucket, block):
         path = tmp_path / "t.json"
         path.write_text(json.dumps({"entries": [{
-            "kernel": "flash_fwd", "seq_bucket": 8192, "head_dim": 64,
-            "dtype": "bfloat16", "causal": True, "block_q": 2048,
-            "block_k": 2048}]}))
+            "kernel": kernel, "seq_bucket": seq_bucket, "head_dim": 64,
+            "dtype": "bfloat16", "causal": True, "block_q": block,
+            "block_k": block}]}))
         findings = self._run(monkeypatch, path)
         assert findings
         assert all(f.path == "kubeflow_tpu/ops/tile_table.json"

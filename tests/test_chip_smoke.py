@@ -82,8 +82,7 @@ def test_kernel_checks_against_their_references_at_toy_width():
                                     heads=TOY.flash_heads, causal=True,
                                     masked=False)
     assert causal["ok"], causal
-    assert set(causal["tiles"]) == {"flash_fwd", "flash_bwd_dq",
-                                    "flash_bwd_dkv"}
+    assert set(causal["tiles"]) == {"flash_fwd", "flash_bwd_fused"}
     masked = chip_smoke.check_flash(TOY, seq=TOY.masked_seq,
                                     batch=TOY.masked_batch,
                                     heads=TOY.masked_heads, causal=False,

@@ -318,3 +318,40 @@ def test_sparse_attention_programs_compile_with_three_kernels_a_layer(
     for leaf in (f"bf16[2,{rows},32768,640]", f"bf16[2,{rows},32768,128]"):
         copies = re.findall(rf"= {re.escape(leaf)}\S* copy\(", text)
         assert len(copies) <= (program == "chunk"), (leaf, copies)
+
+
+@pytest.mark.parametrize("seq, heads", [(8192, 30), (32768, 2)],
+                         ids=["pretrain-8k", "longest-fused-row"])
+def test_fused_flash_backward_compiles_at_its_table_tile(one_chip, no_cache,
+                                                         seq, heads):
+    """The gradient of ``flash_attention`` at head size 64, on the TPU's
+    own compiler: wherever the dQ row fits (``autotune.flash_bwd_fuses``)
+    Mosaic takes the one kernel that returns dQ, dK and dV at the tile
+    the table gives it, under the scope the kernel asks for (the row of
+    8192 takes 24.1 MiB with 1024-edge tiles, past the default 16), and
+    neither the dQ nor the dK/dV kernel is in the program."""
+    import jax
+    import jax.numpy as jnp
+
+    from kubeflow_tpu.ops import autotune
+    from kubeflow_tpu.ops.attention import flash_attention
+
+    x = jax.ShapeDtypeStruct((1, seq, heads, 64), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def grads(q, k, v):
+        # interpret=False: this process's backend is the CPU
+        return jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, True, None, None, None, False).astype(jnp.float32)),
+            argnums=(0, 1, 2))(q, k, v)
+
+    with autotune.record_resolutions() as rec:
+        text = jax.jit(grads).lower(x, x, x).compile().as_text()
+    assert {d["kernel"]: d["source"] for d in rec} == {
+        "flash_fwd": "table", "flash_bwd_fused": "table"}
+    t = rf"bf16\[{heads},{seq},64\]\S*"
+    returns = [len(re.findall(t, outs)) for outs in re.findall(
+        r"^\s*%[\w.\-]+ = (.*?) custom-call\(.*tpu_custom_call", text,
+        flags=re.M)]
+    # the forward (a tensor and its row statistics) and the fused call
+    assert sorted(returns) == [1, 3]

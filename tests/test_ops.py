@@ -149,6 +149,127 @@ class TestFlash:
                                    atol=0.15, rtol=0.1)
 
 
+# name → what differs from a causal float32 (2, 64, 4, 16) problem on
+# 16 × 16 tiles
+FUSED_BACKWARD_CASES = {
+    "causal": {},
+    "bidirectional": dict(causal=False),
+    "kv_len": dict(causal=False, kv_len=(40, 64)),
+    "kv_len_causal": dict(kv_len=(23, 57)),
+    "gqa_15_5_heads": dict(H=15, KH=5),
+    "block_q_wider": dict(bq=32, bk=16),
+    "block_k_wider": dict(bq=16, bk=32),
+    "block_q_4x": dict(bq=64, bk=16),
+    "one_block": dict(bq=64, bk=64),
+    "bfloat16": dict(dtype=jnp.bfloat16, atol=0.15, rtol=0.1),
+    "bfloat16_bidirectional_kv_len": dict(
+        dtype=jnp.bfloat16, causal=False, kv_len=(40, 64), atol=0.15,
+        rtol=0.1),
+}
+
+
+def _flash_problem(causal=True, kv_len=None, H=4, KH=None, bq=16, bk=16,
+                   dtype=jnp.float32, atol=1e-4, rtol=1e-7):
+    """(gradient of the flash kernels, gradient of the reference,
+    inputs, tolerances) of one loss; the cotangent is zero at padded q
+    rows, as the MLM loss weights guarantee."""
+    from kubeflow_tpu.ops.attention import gqa_repeat
+
+    q = jax.random.normal(jax.random.key(0), (2, 64, H, 16), dtype)
+    k, v = (jax.random.normal(jax.random.key(i), (2, 64, KH or H, 16),
+                              dtype) for i in (1, 2))
+    lens = None if kv_len is None else jnp.array(kv_len, jnp.int32)
+    w = 1.0 if lens is None else (
+        jnp.arange(64)[None, :] < lens[:, None]).astype(
+            jnp.float32)[..., None, None]
+
+    def grad_of(attend):
+        def loss(q, k, v):
+            out = attend(q, *gqa_repeat(q, k, v))
+            return jnp.sum((out.astype(jnp.float32) * w) ** 2)
+        return jax.grad(loss, argnums=(0, 1, 2))
+
+    flash = grad_of(lambda q, k, v: flash_attention(
+        q, k, v, causal, bq, bk, None, None, lens))
+    ref = grad_of(lambda q, k, v: reference_attention(
+        q, k, v, causal=causal, kv_len=lens))
+    return flash, ref, (q, k, v), dict(atol=atol, rtol=rtol)
+
+
+def _resolved(rec):
+    from kubeflow_tpu.ops import autotune
+
+    return {d["kernel"] for d in autotune.summarize_resolutions(rec)}
+
+
+class TestFlashFusedBackward:
+    """One kernel for dQ, dK and dV wherever a dQ row fits VMEM, the
+    dQ and dK/dV pair past that: the shape decides
+    (``autotune.flash_bwd_fuses``), and the recorded resolutions say
+    which ran."""
+
+    @pytest.mark.parametrize("case", list(FUSED_BACKWARD_CASES))
+    def test_fused_matches_reference_and_the_kernel_pair(self, case,
+                                                         monkeypatch):
+        from kubeflow_tpu.ops import autotune
+
+        flash, ref, args, tol = _flash_problem(**FUSED_BACKWARD_CASES[case])
+        with autotune.record_resolutions() as rec:
+            fused = flash(*args)
+        assert _resolved(rec) == {"flash_fwd", "flash_bwd_fused"}
+        for got, want, name in zip(fused, ref(*args), "qkv"):
+            np.testing.assert_allclose(
+                np.asarray(got, np.float32), np.asarray(want, np.float32),
+                err_msg=f"d{name} against the reference", **tol)
+        # the same sums in the same order as the two kernels it replaces
+        monkeypatch.setattr(autotune, "VMEM_BUDGET_BYTES", 1024)
+        with autotune.record_resolutions() as rec:
+            pair = flash(*args)
+        assert _resolved(rec) == {"flash_fwd", "flash_bwd_dq",
+                                  "flash_bwd_dkv"}
+        for got, want, name in zip(fused, pair, "qkv"):
+            np.testing.assert_array_equal(
+                np.asarray(got), np.asarray(want),
+                err_msg=f"d{name} against the dQ + dK/dV kernels")
+
+    def test_row_that_does_not_fit_takes_the_kernel_pair(self,
+                                                         monkeypatch):
+        """Past the budget the fallback runs, and only there: one row
+        of (64, 16) float32 and its output block is 8 KB."""
+        from kubeflow_tpu.ops import autotune
+
+        assert autotune.flash_bwd_fuses(64, 16, jnp.float32)
+        row = 64 * 16 * (4 + 4)
+        tile = autotune.flash_vmem_bytes("flash_bwd_dkv", 128, 128, 16, 4)
+        monkeypatch.setattr(autotune, "VMEM_BUDGET_BYTES", tile + row - 1)
+        assert not autotune.flash_bwd_fuses(64, 16, jnp.float32)
+        flash, ref, args, tol = _flash_problem()
+        with autotune.record_resolutions() as rec:
+            got = flash(*args)
+        assert _resolved(rec) == {"flash_fwd", "flash_bwd_dq",
+                                  "flash_bwd_dkv"}
+        for g, want in zip(got, ref(*args)):
+            np.testing.assert_allclose(g, want, **tol)
+
+    def test_pretrain_shape_takes_the_fused_kernel(self):
+        """SmolLM2-360M at 8192 tokens (15 heads of 64, bfloat16): the
+        row is 2 MB + 1 MB; traced, not run."""
+        from kubeflow_tpu.ops import autotune
+
+        x = jax.ShapeDtypeStruct((1, 8192, 15, 64), jnp.bfloat16)
+        with autotune.record_resolutions() as rec:
+            jax.eval_shape(jax.grad(lambda q, k, v: jnp.sum(
+                flash_attention(q, k, v).astype(jnp.float32)),
+                argnums=(0, 1, 2)), x, x, x)
+        fused = [d for d in rec if d["kernel"] == "flash_bwd_fused"]
+        assert _resolved(rec) == {"flash_fwd", "flash_bwd_fused"}
+        assert (fused[0]["source"], fused[0]["block_q"],
+                fused[0]["block_k"]) == ("table", 1024, 1024)
+        # head size 64 fuses through the 32768 bucket and not past it
+        assert autotune.flash_bwd_fuses(32768, 64, jnp.bfloat16)
+        assert not autotune.flash_bwd_fuses(65536, 64, jnp.bfloat16)
+
+
 class TestRing:
     def test_matches_reference(self, mesh_dp_tp):
         q, k, v = qkv()

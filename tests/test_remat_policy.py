@@ -1,7 +1,8 @@
 """What a rematerialised block keeps for its backward
 (``models/transformer.py:remat_block``): the flash forward's output and
 log-sum-exp, named in ``ops/attention.py:_flash_vjp_fwd``, so that a
-layer's backward runs dQ and dK/dV and not the forward kernel again; and
+layer's backward runs the one fused dQ/dK/dV kernel and not the forward
+kernel again; and
 nothing where no flash kernel ran, nor in a served program."""
 
 import collections
@@ -103,8 +104,7 @@ def test_remat_backward_runs_the_forward_kernel_once_a_layer(case,
     with record_kernel_placements() as placed:
         count, (got_l, got_g) = run(True)
     assert count == {"_flash_fwd_kernel": a_layer,
-                     "_flash_bwd_dq_kernel": a_layer,
-                     "_flash_bwd_dkv_kernel": a_layer}
+                     "_flash_bwd_fused_kernel": a_layer}
     if mesh_config is not None:
         # the kernels sat inside shard_kernel's shard_map, heads split
         assert placed == [{"kernel": "flash_attention", "devices": 2,
@@ -119,7 +119,7 @@ def test_remat_backward_runs_the_forward_kernel_once_a_layer(case,
         monkeypatch.setattr(module, "remat_block", plain_remat)
     count, (want_l, want_g) = run(True)
     assert count["_flash_fwd_kernel"] == 2 * a_layer
-    assert count["_flash_bwd_dq_kernel"] == a_layer
+    assert count["_flash_bwd_fused_kernel"] == a_layer
     same(got_l, want_l)
     jax.tree_util.tree_map(same, got_g, want_g)
 

@@ -181,7 +181,7 @@ def test_default_none_blocks_resolve_per_kernel_key():
         jax.grad(lambda p: jnp.sum(
             model.apply({"params": p}, tokens)))(params)
     kernels = {d["kernel"] for d in autotune.summarize_resolutions(rec)}
-    assert {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"} <= kernels
+    assert {"flash_fwd", "flash_bwd_fused"} <= kernels
 
 
 def test_old_square_config_matches_new_default_numerically():
