@@ -5,6 +5,12 @@ MLP in the leading layers and a sigmoid-routed expert MLP with a shared
 expert after them, an untied output head. Served through the same
 ``models/decode.py`` / ``DecodeEngine`` path as :class:`Transformer`.
 
+``layer_types`` says, entry by entry, what a layer is. ``"kda"``,
+``"mla"`` and ``"dsa"`` are a mixer and then an MLP, each under its own
+norm and residual. ``"ssm"`` (a Mamba-2 state-space mixer), ``"gqa"``
+(grouped-query attention with no rotary embedding) and ``"moe"`` (the
+routed MLP) are blocks of ONE sublayer under one norm and one residual.
+
 It exists in decode mode only: every apply reads and writes the ``cache``
 collection, whose leaves the model declares
 (``HybridConfig.cache_leaves``; the engine takes each leaf's batch axis
@@ -20,6 +26,14 @@ and idle value from there, never from its rank):
     index_k       (L_dsa, B, S_max, d_index) a token's index key
     kda_state     (L_kda, B, H, dk, dv) f32  the delta-rule state
     kda_conv      (L_kda, B, K - 1, 3 H dk)  last conv inputs of q | k | v
+    ssm_state     (L_ssm, B, Hs / k, N, k P) f32  the state-space state,
+                                             transposed, k heads a lane
+                                             tile (``ops/ssm.pack_state``)
+    ssm_conv      (L_ssm, B, K - 1, Hs P + 2 G N)  last conv inputs of
+                                             x | B | C
+    k, v          (L_gqa, B, S_max, KH Dh)   a token's keys / values, the
+                                             KV heads merged on the last
+                                             axis (the dense decoder's leaf)
 
 (a leaf exists only where some layer needs it). A DSA layer reads
 ``index_k`` as far as the row has grown and attends, of the ``latent``'s
@@ -27,7 +41,8 @@ positions, to the ``index_topk`` it selects for each query.
 
 A recurrent state cannot be pulled back the way ``positions`` can, so
 ``true_len`` reaches the mixers: past a row's own length a multi-token
-apply leaves ``kda_state`` and ``kda_conv`` as they were.
+apply leaves ``kda_state`` / ``ssm_state`` and the conv tails as they
+were.
 
 The layers are few and unlike, so they are unrolled, not scanned; each
 reads and writes its own ``[index]`` slice of the stacked leaves, which
@@ -47,16 +62,27 @@ import jax.numpy as jnp
 from kubeflow_tpu.models.transformer import (
     CacheLeaf,
     RMSNorm,
+    _merged_step_attention,
     _rotate,
     rope_tables,
 )
 from kubeflow_tpu.ops.attention import NEG_INF
 from kubeflow_tpu.ops.dsa import index_scores, select_bias, sparse_attend
 from kubeflow_tpu.ops.kda import kda_step
+from kubeflow_tpu.ops.ssm import (
+    pack_state,
+    ssm_chunked,
+    ssm_step,
+    unpack_state,
+)
 from kubeflow_tpu.parallel.mesh import DEFAULT_RULES, AxisRules
 
 HIGHEST = jax.lax.Precision.HIGHEST
 L2_EPS = 1e-6
+MIXERS = ("kda", "mla", "dsa")       # a layer: this mixer, then an MLP
+SUBLAYERS = ("ssm", "gqa", "moe")    # a block: this one sublayer
+GQA_Q_BLOCK = 512    # query rows a fresh prefill attends at once
+EXPERT_TILE = 256    # a routed expert is stored in whole tiles of columns
 
 
 @dataclasses.dataclass(frozen=True)
@@ -67,7 +93,8 @@ class HybridConfig:
     head_dim: int = 16                # KDA's dk = dv; a field, not d / H
     layer_types: Tuple[str, ...] = ("kda", "kda", "kda", "kda", "mla",
                                     "kda", "kda")
-    first_k_dense: int = 1            # leading layers with the dense MLP
+    first_k_dense: int = 1            # leading mixer layers whose MLP is dense
+    norm_eps: float = 1e-6            # of the layer / block and final norms
     d_ff: int = 128                   # dense MLP width
     max_seq_len: int = 256
     # MLA ("mla" and "dsa" layers)
@@ -94,7 +121,15 @@ class HybridConfig:
     # a prompt longer than this is admitted by repeating ONE program of
     # this many tokens against the row (0: one program a prompt bucket)
     prefill_chunk: int = 0
-    # KDA
+    # "gqa" blocks: n_heads query heads of head_dim over this many KV heads
+    n_kv_heads: int = 0
+    # "ssm" blocks: H heads of P, a state of N a head, B / C a group
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_chunk: int = 128
+    # KDA (the conv's taps are the "ssm" blocks' too)
     conv_kernel: int = 4
     kda_lower_bound: float = -5.0
     kda_chunk: int = 64
@@ -107,8 +142,11 @@ class HybridConfig:
     topk_group: int = 2
     routed_scaling: float = 2.5
     norm_topk_prob: bool = True
-    d_expert: int = 32
+    d_expert: int = 32                # published; stored at expert_width
     d_shared: int = 32
+    # "swiglu": down(silu(gate x) * up x), three matrices an expert;
+    # "relu2": down(relu(up x) ** 2), two
+    expert_act: str = "swiglu"
     experts_held: Optional[Tuple[int, int]] = None
     dtype: Any = jnp.bfloat16         # activations
     param_dtype: Any = jnp.float32
@@ -130,6 +168,23 @@ class HybridConfig:
                  // 128) * 128
 
     @property
+    def expert_width(self) -> int:
+        """The columns a routed expert is STORED at: ``d_expert`` rounded
+        up to whole tiles of 256 once it is wider than one, the added
+        columns of ``up_proj`` / ``gate_proj`` and rows of ``down_proj``
+        zero (``stored_expert``), which adds exactly 0 to the result.
+        The TPU compiler tiles ``ragged_dot`` along this axis by the
+        largest of 512, 256 and 128 that divides it, and lays a
+        parameter whose last axis is no whole number of 128 lanes out
+        column-major, which the grouped product then re-lays at every
+        call: at 1856 columns that was 319 MB a block a round, and at
+        1920 the products ran in 128-wide tiles at 17 % of the hit
+        experts' bytes over the HBM peak (PERF.md, PR 35). 768 and 2048
+        are stored as they are; 1856 is stored at 2048."""
+        f = self.d_expert
+        return f if f <= EXPERT_TILE else -(-f // EXPERT_TILE) * EXPERT_TILE
+
+    @property
     def n_kda(self) -> int:
         return self.layer_types.count("kda")
 
@@ -140,6 +195,33 @@ class HybridConfig:
     @property
     def n_dsa(self) -> int:
         return self.layer_types.count("dsa")
+
+    @property
+    def n_ssm(self) -> int:
+        return self.layer_types.count("ssm")
+
+    @property
+    def n_gqa(self) -> int:
+        return self.layer_types.count("gqa")
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_pack(self) -> int:
+        """Heads of one group that lie side by side on the lanes of the
+        ``ssm_state`` leaf (``ops/ssm.py:pack_state``): as many as fill
+        128 lanes, and a whole number of packs a group."""
+        per_group = self.ssm_heads // self.ssm_groups
+        return max(k for k in range(1, per_group + 1)
+                   if per_group % k == 0
+                   and (k == 1 or k * self.ssm_head_dim <= 128))
+
+    @property
+    def ssm_conv_width(self) -> int:
+        """The channels the short conv runs over: x | B | C."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
 
     @property
     def rope_mscale(self) -> float:
@@ -155,14 +237,19 @@ class HybridConfig:
 
     @property
     def n_moe(self) -> int:
-        return len(self.layer_types) - self.first_k_dense
+        """Routed MLPs: the "moe" blocks and the mixer layers past the
+        leading dense ones."""
+        return sum(kind == "moe" or (kind in MIXERS
+                                     and i >= self.first_k_dense)
+                   for i, kind in enumerate(self.layer_types))
 
     @property
     def has_recurrent_state(self) -> bool:
-        """A per-row state that no position indexes (KDA's): such a
-        cache cannot be paged, sliced at a prefix or rolled back, so the
-        paths that do those refuse the model."""
-        return self.n_kda > 0
+        """A per-row state that no position indexes (KDA's, a
+        state-space block's): such a cache cannot be paged, sliced at a
+        prefix or rolled back, so the paths that do those refuse the
+        model, and a multi-token apply is given each row's length."""
+        return any(name.endswith("_state") for name in self.cache_leaves(1))
 
     def decoder(self) -> "HybridDecoder":
         """The decode-mode module that ``models/decode.py`` applies."""
@@ -184,11 +271,37 @@ class HybridConfig:
             leaves["kda_conv"] = CacheLeaf(
                 (self.n_kda, batch, self.conv_kernel - 1, 3 * H * dk),
                 self.dtype, 1)
+        if self.n_ssm:
+            k = self.ssm_pack
+            leaves["ssm_state"] = CacheLeaf(
+                (self.n_ssm, batch, self.ssm_heads // k, self.ssm_state,
+                 k * self.ssm_head_dim), jnp.float32, 1)
+            leaves["ssm_conv"] = CacheLeaf(
+                (self.n_ssm, batch, self.conv_kernel - 1,
+                 self.ssm_conv_width), self.dtype, 1)
+        if self.n_gqa:
+            # the dense decoder's leaf: a position's KV heads merged on
+            # the last axis (``TransformerConfig.cache_leaves`` has why)
+            kv = CacheLeaf((self.n_gqa, batch, S, self.n_kv_heads * dk),
+                           self.dtype, 1, heads_axis=3, head_width=dk)
+            leaves["k"] = leaves["v"] = kv
         return leaves
 
     def validate(self) -> None:
-        if set(self.layer_types) - {"kda", "mla", "dsa"}:
-            raise ValueError(f"unknown mixer in {self.layer_types!r}")
+        if set(self.layer_types) - set(MIXERS + SUBLAYERS):
+            raise ValueError(f"unknown layer kind in {self.layer_types!r}")
+        if self.n_gqa and (self.n_kv_heads < 1
+                           or self.n_heads % self.n_kv_heads):
+            raise ValueError("a gqa block needs n_kv_heads that divide "
+                             "n_heads")
+        if self.n_ssm and (min(self.ssm_heads, self.ssm_head_dim,
+                               self.ssm_state, self.ssm_groups) < 1
+                           or self.ssm_heads % self.ssm_groups):
+            raise ValueError("an ssm block needs ssm_heads, ssm_head_dim, "
+                             "ssm_state and ssm_groups that divide the "
+                             "heads")
+        if self.expert_act not in ("swiglu", "relu2"):
+            raise ValueError(f"unknown expert_act {self.expert_act!r}")
         if self.n_dsa and not (
                 self.q_lora_rank and self.index_n_heads
                 and self.index_topk and self.index_head_dim
@@ -212,6 +325,19 @@ class HybridConfig:
                              "dims be even")
 
 
+def stored_expert(w, c: HybridConfig, axis: int):
+    """A routed expert tensor at its published width ``d_expert`` along
+    ``axis`` -> as ``RoutedMlp`` stores it: zero-filled to
+    ``expert_width``. What a loader of published weights calls; the
+    module's own initialiser goes through it too."""
+    if w.shape[axis] != c.d_expert:
+        raise ValueError(f"axis {axis} of {w.shape} is not d_expert "
+                         f"{c.d_expert}")
+    pad = [(0, 0)] * w.ndim
+    pad[axis] = (0, c.expert_width - c.d_expert)
+    return jnp.pad(w, pad)
+
+
 def _dense(x, w, dtype):
     return jnp.dot(x.astype(dtype), w.astype(dtype),
                    preferred_element_type=jnp.float32)
@@ -225,6 +351,21 @@ def _l2_norm(x):
 def _swiglu(x, w_gate, w_up, w_down, dtype):
     h = jax.nn.silu(_dense(x, w_gate, dtype)) * _dense(x, w_up, dtype)
     return _dense(h, w_down, dtype).astype(dtype)
+
+
+def _relu2(x, w_up, w_down, dtype):
+    h = jnp.square(jax.nn.relu(_dense(x, w_up, dtype)))
+    return _dense(h, w_down, dtype).astype(dtype)
+
+
+def _conv_tail(seq, taps: int, T: int, lens):
+    """The last ``taps - 1`` real inputs of each row of ``seq`` = [the
+    cached tail | this call's T inputs]: inputs len-3 .. len-1 of a row
+    sit at len .. len+2."""
+    if lens is None:
+        return seq[:, T:]
+    return jax.vmap(lambda row, n: jax.lax.dynamic_slice_in_dim(
+        row, n, taps - 1, 0))(seq, lens)
 
 
 # -- KDA -------------------------------------------------------------------------
@@ -345,11 +486,7 @@ class KdaMixer(nn.Module):
         seq = jnp.concatenate([cache["kda_conv"][index], pre], axis=1)
         conv = sum(w_conv[j].astype(jnp.float32) * seq[:, j:j + T]
                    for j in range(taps))
-        if lens is None:
-            tail = seq[:, T:]
-        else:   # inputs len-3 .. len-1 of the row sit at len .. len+2
-            tail = jax.vmap(lambda row, n: jax.lax.dynamic_slice_in_dim(
-                row, n, taps - 1, 0))(seq, lens)
+        tail = _conv_tail(seq, taps, T, lens)
         q, k, v = (y.reshape(B, T, H, dk) for y in jnp.split(
             jax.nn.silu(conv.astype(jnp.float32)), 3, axis=-1))
         q = _l2_norm(q) * dk ** -0.5
@@ -617,22 +754,181 @@ class MlaAttention(nn.Module):
         return index_k, kept, counts
 
 
+# -- state-space (Mamba-2) ---------------------------------------------------------
+
+class SsmMixer(nn.Module):
+    """[z | xBC | dt] = W_in h; a short causal conv and SiLU over xBC = [x
+    | B | C]; the selective state update (``ops/ssm.py``) on H heads of
+    P with B and C shared by a group's heads; the output gated by
+    silu(z), RMS-normalised within each group's channels, and projected
+    out."""
+
+    config: HybridConfig
+
+    @nn.compact
+    def __call__(self, x, cache, index: int, lens=None):
+        """x (B, T, D); ``index`` this block's place among the "ssm"
+        blocks; ``lens`` (B,) the real tokens of each row (None: all T).
+        Returns (out, cache)."""
+        c = self.config
+        B, T, D = x.shape
+        H, P, N, G = c.ssm_heads, c.ssm_head_dim, c.ssm_state, c.ssm_groups
+        I, W, taps = c.ssm_inner, c.ssm_conv_width, c.conv_kernel
+        init = nn.initializers.normal(stddev=D ** -0.5)
+        w_in = self.param("in_proj", init, (D, I + W + H), c.param_dtype)
+        w_out = self.param("out_proj", nn.initializers.normal(I ** -0.5),
+                           (I, D), c.param_dtype)
+        w_conv = self.param("conv", nn.initializers.normal(0.4), (taps, W),
+                            c.param_dtype)
+        b_conv = self.param("conv_bias", nn.initializers.zeros, (W,),
+                            c.param_dtype)
+        a_log = self.param("a_log", nn.initializers.zeros, (H,), jnp.float32)
+        dt_bias = self.param("dt_bias", nn.initializers.zeros, (H,),
+                             jnp.float32)
+        d_skip = self.param("d", nn.initializers.ones, (H,), jnp.float32)
+        w_norm = self.param("norm", nn.initializers.ones, (I,),
+                            c.param_dtype)
+
+        zxd = _dense(x, w_in, c.dtype)                  # (B, T, I + W + H)
+        z, dt = zxd[..., :I], zxd[..., I + W:]
+        with jax.named_scope("ssm.conv"):
+            # over [the cached tail | this call's inputs]
+            seq = jnp.concatenate(
+                [cache["ssm_conv"][index],
+                 zxd[..., I:I + W].astype(c.dtype)], axis=1)
+            conv = sum(w_conv[j].astype(jnp.float32) * seq[:, j:j + T]
+                       for j in range(taps)) + b_conv.astype(jnp.float32)
+            tail = _conv_tail(seq, taps, T, lens)
+            xbc = jax.nn.silu(conv)
+        xs = xbc[..., :I].reshape(B, T, H, P)
+        Bm, Cm = (y.reshape(B, T, G, N)
+                  for y in jnp.split(xbc[..., I:], 2, axis=-1))
+        dt = jax.nn.softplus(dt + dt_bias)                      # (B, T, H)
+        A = -jnp.exp(a_log)
+
+        if T == 1:
+            # the kernel updates block ``index`` of the stacked leaf in place
+            with jax.named_scope("ssm.step"):
+                states, y = ssm_step(cache["ssm_state"], index, xs[:, 0],
+                                     dt[:, 0], A, Bm[:, 0], Cm[:, 0], d_skip)
+                y = y[:, None]
+        else:
+            with jax.named_scope("ssm.chunk"):
+                state, y = ssm_chunked(
+                    unpack_state(cache["ssm_state"][index], c.ssm_pack), xs,
+                    dt, A, Bm, Cm, d_skip, c.ssm_chunk, lens)
+            states = cache["ssm_state"].at[index].set(
+                pack_state(state, c.ssm_pack))
+        cache = dict(cache, ssm_state=states,
+                     ssm_conv=cache["ssm_conv"].at[index].set(tail))
+
+        y = (y.reshape(B, T, I) * jax.nn.silu(z)).reshape(B, T, G, I // G)
+        y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), -1, keepdims=True)
+                              + c.norm_eps)
+        y = y.reshape(B, T, I) * w_norm.astype(jnp.float32)
+        return _dense(y, w_out, c.dtype).astype(c.dtype), cache
+
+
+# -- grouped-query attention, no rotary embedding ----------------------------------
+
+class GqaAttention(nn.Module):
+    """Causal softmax attention of ``n_heads`` query heads over
+    ``n_kv_heads`` KV heads, positions entering by the mask alone. It
+    keeps the dense decoder's ``k`` / ``v`` leaves, KV heads merged on
+    the last axis, and one token attends against them as they lie
+    (``transformer._merged_step_attention``)."""
+
+    config: HybridConfig
+
+    @nn.compact
+    def __call__(self, x, cache, index: int, fresh: bool):
+        """x (B, T, D). ``fresh`` (static): the rows' caches are empty,
+        so the T tokens attend among themselves; otherwise each row's
+        tokens are written at its own position and attend to the cached
+        row. Returns (out, cache)."""
+        c = self.config
+        B, T, D = x.shape
+        H, KH, Dh, S = c.n_heads, c.n_kv_heads, c.head_dim, c.max_seq_len
+        R = H // KH
+        init = nn.initializers.normal(stddev=D ** -0.5)
+        w_q = self.param("q_proj", init, (D, H * Dh), c.param_dtype)
+        w_k = self.param("k_proj", init, (D, KH * Dh), c.param_dtype)
+        w_v = self.param("v_proj", init, (D, KH * Dh), c.param_dtype)
+        w_o = self.param("o_proj", nn.initializers.normal((H * Dh) ** -0.5),
+                         (H * Dh, D), c.param_dtype)
+        # query head h reads KV head h // R: (KH, R) side by side
+        q = _dense(x, w_q, c.dtype).astype(c.dtype).reshape(B, T, KH, R, Dh)
+        k = _dense(x, w_k, c.dtype).astype(c.dtype)        # (B, T, KH Dh)
+        v = _dense(x, w_v, c.dtype).astype(c.dtype)
+        pos = cache["positions"]
+        q_pos = pos[:, None] + jnp.arange(T)[None, :]               # (B, T)
+        ein = lambda spec, a, b: jnp.einsum(  # noqa: E731
+            spec, a, b, preferred_element_type=jnp.float32)
+
+        def attend(q, k, v, mask):
+            """q (B, Q, KH, R, Dh) against k, v (B, K, KH, Dh) under
+            mask (B or 1, Q, K)."""
+            s = ein("bqhrd,bkhd->bhrqk", q, k) * Dh ** -0.5
+            p = jax.nn.softmax(jnp.where(mask[:, None, None], s, NEG_INF),
+                               axis=-1).astype(c.dtype)
+            return ein("bhrqk,bkhd->bqhrd", p, v).astype(c.dtype)
+
+        with jax.named_scope("gqa.attend"):
+            if fresh:
+                # rows start at 0 and share the slice
+                at = (index, 0, 0, 0)
+                ck = jax.lax.dynamic_update_slice(cache["k"], k[None], at)
+                cv = jax.lax.dynamic_update_slice(cache["v"], v[None], at)
+                kh, vh = (y.reshape(B, T, KH, Dh) for y in (k, v))
+                qb = min(T, GQA_Q_BLOCK)
+
+                def block(i):
+                    rows = i * qb + jnp.arange(qb)
+                    mask = jnp.arange(T)[None, :] <= rows[:, None]
+                    return attend(jax.lax.dynamic_slice_in_dim(
+                        q, i * qb, qb, 1), kh, vh, mask[None])
+
+                if T % qb:
+                    o = attend(q, kh, vh, jnp.tril(
+                        jnp.ones((T, T), bool))[None])
+                else:
+                    o = jnp.moveaxis(
+                        jax.lax.map(block, jnp.arange(T // qb)), 0, 1)
+                o = o.reshape(B, T, H * Dh)
+            else:
+                rows = jnp.arange(B)[:, None]
+                ck = cache["k"].at[index, rows, q_pos].set(k)
+                cv = cache["v"].at[index, rows, q_pos].set(v)
+                mask = jnp.arange(S)[None, None, :] <= q_pos[:, :, None]
+                if T == 1:
+                    o = _merged_step_attention(q[:, 0], ck[index], cv[index],
+                                               mask[:, 0])
+                else:
+                    o = attend(q, ck[index].reshape(B, S, KH, Dh),
+                               cv[index].reshape(B, S, KH, Dh), mask)
+                o = o.reshape(B, T, H * Dh)
+        out = _dense(o, w_o, c.dtype)
+        return out.astype(c.dtype), dict(cache, k=ck, v=cv)
+
+
 # -- routed MLP ------------------------------------------------------------------
 
 def route(scores_logits, bias, c: HybridConfig):
-    """Sigmoid scores, group-limited selection on score + bias, weights
-    from the scores. (N, E) f32 -> (ids (N, K), weights (N, K))."""
+    """Sigmoid scores, group-limited selection on score + bias (with
+    more than one group), weights from the scores. (N, E) f32 -> (ids
+    (N, K), weights (N, K))."""
     s = jax.nn.sigmoid(scores_logits)
     sel = s + bias
     E = sel.shape[-1]
-    grouped = sel.reshape(-1, c.n_group, E // c.n_group)
-    g_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
-    _, g_idx = jax.lax.top_k(g_score, c.topk_group)
-    g_keep = jnp.sum(jax.nn.one_hot(g_idx, c.n_group, dtype=jnp.int32),
-                     axis=1) > 0
-    keep = jnp.repeat(g_keep, E // c.n_group, axis=-1)
-    _, idx = jax.lax.top_k(jnp.where(keep, sel, -jnp.inf),
-                           c.experts_per_token)
+    if c.n_group > 1:     # one group: every expert stands
+        grouped = sel.reshape(-1, c.n_group, E // c.n_group)
+        g_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+        _, g_idx = jax.lax.top_k(g_score, c.topk_group)
+        g_keep = jnp.sum(jax.nn.one_hot(g_idx, c.n_group, dtype=jnp.int32),
+                         axis=1) > 0
+        sel = jnp.where(jnp.repeat(g_keep, E // c.n_group, axis=-1), sel,
+                        -jnp.inf)
+    _, idx = jax.lax.top_k(sel, c.experts_per_token)
     w = jnp.take_along_axis(s, idx, axis=-1)
     if c.norm_topk_prob:
         w = w / jnp.sum(w, axis=-1, keepdims=True)
@@ -644,7 +940,8 @@ class RoutedMlp(nn.Module):
     computes what those add for the tokens routed to them, plus the
     shared expert. Tokens are grouped by expert (one sort, then
     ``ragged_dot`` over the groups): no token is dropped, and the work
-    follows the routed (token, expert) pairs held here."""
+    follows the routed (token, expert) pairs held here. An expert's
+    ``d_expert`` columns are stored at ``expert_width``, the fill zero."""
 
     config: HybridConfig
 
@@ -657,16 +954,24 @@ class RoutedMlp(nn.Module):
         K, F = c.experts_per_token, c.d_expert
         lo, n = c.held
         init = nn.initializers.normal(stddev=D ** -0.5)
+
+        def stored(axis):     # drawn at d_expert, zero-filled to the store
+            return lambda key, shape, dtype: stored_expert(
+                init(key, shape, dtype), c, axis)
         w_router = self.param("router", init, (D, c.n_experts), jnp.float32)
         bias = self.param("router_bias", nn.initializers.zeros,
                           (c.n_experts,), jnp.float32)
-        w_gate = self.param("gate_proj", init, (n, D, F), c.param_dtype)
-        w_up = self.param("up_proj", init, (n, D, F), c.param_dtype)
-        w_down = self.param("down_proj", init, (n, F, D), c.param_dtype)
+        gated = c.expert_act == "swiglu"     # relu2 experts have no gate
+        if gated:
+            w_gate = self.param("gate_proj", stored(2), (n, D, F),
+                                c.param_dtype)
+        w_up = self.param("up_proj", stored(2), (n, D, F), c.param_dtype)
+        w_down = self.param("down_proj", stored(1), (n, F, D), c.param_dtype)
         sh = [self.param(f"shared_{name}", init, shape, c.param_dtype)
               for name, shape in (("gate", (D, c.d_shared)),
                                   ("up", (D, c.d_shared)),
-                                  ("down", (c.d_shared, D)))]
+                                  ("down", (c.d_shared, D)))
+              if gated or name != "gate"]
         flat = x.reshape(B * T, D)
         N = B * T
 
@@ -688,14 +993,18 @@ class RoutedMlp(nn.Module):
             rd = lambda a, b: jax.lax.ragged_dot(  # noqa: E731
                 a, b.astype(c.dtype), sizes,
                 preferred_element_type=jnp.float32)
-            h = (jax.nn.silu(rd(xs, w_gate)) * rd(xs, w_up)).astype(c.dtype)
-            ys = rd(h, w_down)                                   # (N K, D)
+            if gated:
+                h = jax.nn.silu(rd(xs, w_gate)) * rd(xs, w_up)
+            else:
+                h = jnp.square(jax.nn.relu(rd(xs, w_up)))
+            ys = rd(h.astype(c.dtype), w_down)                   # (N K, D)
             back = jnp.take(ys, jnp.argsort(order), axis=0).reshape(N, K, D)
             y = jnp.sum(jnp.where(held[..., None],
                                   back * w[..., None], 0.0), axis=1)
 
         with jax.named_scope("moe.shared"):
-            y = y.astype(c.dtype) + _swiglu(flat, *sh, c.dtype)
+            y = y.astype(c.dtype) + (_swiglu if gated else _relu2)(
+                flat, *sh, c.dtype)
         return y.reshape(B, T, D), hit, pairs
 
 
@@ -726,7 +1035,7 @@ class HybridLayer(nn.Module):
         a sparse layer (latent, index_k). Returns (x, cache, what the
         routed MLP counted | None, what the indexer counted | None)."""
         c = self.config
-        h = RMSNorm(param_dtype=c.param_dtype, name="attn_norm")(x)
+        h = RMSNorm(c.norm_eps, c.param_dtype, name="attn_norm")(x)
         picked = None
         if self.mixer == "kda":
             out, cache = KdaMixer(c, name="mixer")(h, cache, index, lens)
@@ -737,14 +1046,41 @@ class HybridLayer(nn.Module):
             out, cache, _ = MlaAttention(c, name="mixer")(h, cache, index,
                                                           fresh)
         x = x + out
-        h = RMSNorm(param_dtype=c.param_dtype, name="mlp_norm")(x)
+        h = RMSNorm(c.norm_eps, c.param_dtype, name="mlp_norm")(x)
         if not self.routed:
             return x + DenseMlp(c, name="mlp")(h), cache, None, picked
-        live = None
-        if lens is not None:
-            live = jnp.arange(x.shape[1])[None, :] < lens[:, None]
-        y, hit, pairs = RoutedMlp(c, name="mlp")(h, live)
+        y, hit, pairs = RoutedMlp(c, name="mlp")(h, _live(x, lens))
         return x + y, cache, (hit, pairs), picked
+
+
+def _live(x, lens):
+    """(B, T) bool: a row's real tokens (None: all of them)."""
+    if lens is None:
+        return None
+    return jnp.arange(x.shape[1])[None, :] < lens[:, None]
+
+
+class HybridBlock(nn.Module):
+    """One sublayer under one norm and one residual."""
+
+    config: HybridConfig
+    kind: str
+
+    @nn.compact
+    def __call__(self, x, cache, index, lens, fresh: bool):
+        """``index`` is the block's place in the leaves of its kind.
+        Returns (x, cache, what a routed MLP counted | None)."""
+        c = self.config
+        h = RMSNorm(c.norm_eps, c.param_dtype, name="norm")(x)
+        stat = None
+        if self.kind == "ssm":
+            out, cache = SsmMixer(c, name="mixer")(h, cache, index, lens)
+        elif self.kind == "gqa":
+            out, cache = GqaAttention(c, name="mixer")(h, cache, index,
+                                                       fresh)
+        else:
+            out, *stat = RoutedMlp(c, name="mlp")(h, _live(x, lens))
+        return x + out, cache, stat
 
 
 class HybridDecoder(nn.Module):
@@ -777,15 +1113,20 @@ class HybridDecoder(nn.Module):
                           (c.vocab_size, c.d_model), c.param_dtype)
         x = jnp.take(embed.astype(c.dtype), tokens, axis=0)
         # "mla" and "dsa" layers share the latent leaf, in layer order
-        seen = {"kda": 0, "mla": 0, "dsa": 0}
+        seen = dict.fromkeys(MIXERS + SUBLAYERS, 0)
         stats, picked = [], []
         for i, mixer in enumerate(c.layer_types):
             latent = seen["mla"] + seen["dsa"]
-            index = {"kda": seen["kda"], "mla": latent,
-                     "dsa": (latent, seen["dsa"])}[mixer]
-            x, cache, stat, kept = HybridLayer(
-                c, mixer, routed=i >= c.first_k_dense, name=f"layer_{i}")(
+            index = {"mla": latent, "dsa": (latent, seen["dsa"])}.get(
+                mixer, seen[mixer])
+            if mixer in SUBLAYERS:
+                kept = None
+                x, cache, stat = HybridBlock(c, mixer, name=f"layer_{i}")(
                     x, cache, index, lens, fresh)
+            else:
+                x, cache, stat, kept = HybridLayer(
+                    c, mixer, routed=i >= c.first_k_dense,
+                    name=f"layer_{i}")(x, cache, index, lens, fresh)
             seen[mixer] += 1
             if stat is not None:
                 stats.append(stat)
@@ -805,6 +1146,6 @@ class HybridDecoder(nn.Module):
                      jnp.stack([s[0] for s in picked]))
             self.sow("moe_stats", "index_selected",
                      jnp.stack([s[1] for s in picked]))
-        x = RMSNorm(param_dtype=c.param_dtype, name="final_norm")(x)
+        x = RMSNorm(c.norm_eps, c.param_dtype, name="final_norm")(x)
         return jnp.einsum("btd,vd->btv", x, head.astype(c.dtype),
                           preferred_element_type=jnp.float32)

@@ -320,6 +320,99 @@ def test_sparse_attention_programs_compile_with_three_kernels_a_layer(
         assert len(copies) <= (program == "chunk"), (leaf, copies)
 
 
+# Nemotron-3-Nano's one-sublayer blocks (kubeflow_tpu/models/hybrid.py
+# "ssm", "gqa" and "moe" blocks, kubeflow_tpu/ops/ssm.py) at the published
+# widths, one block of each kind: 32 of 128 experts held, 128 slots x 8192
+SSM = dict(vocab_size=32768, d_model=2688, n_heads=32, n_kv_heads=2,
+           head_dim=128, layer_types=("ssm", "moe", "gqa"), first_k_dense=0,
+           norm_eps=1e-5, max_seq_len=8192, ssm_heads=64, ssm_head_dim=64,
+           ssm_state=128, ssm_groups=8, ssm_chunk=128, n_experts=128,
+           experts_per_token=6, n_group=1, topk_group=1, d_expert=1856,
+           d_shared=3712, expert_act="relu2", experts_held=(0, 32))
+SSM_SLOTS = 128
+
+
+@pytest.mark.parametrize("program", ["step", "prefill"])
+def test_state_space_programs_compile_with_the_step_kernel_in_place(
+        one_chip, no_cache, monkeypatch, program):
+    """The K-step program at 128 slots and a batch prefill of 4 rows x
+    2048 tokens, on the TPU's own compiler: Mosaic takes the ``ssm.step``
+    kernel at the published shapes (a row's 2 MB of state in, 2 MB out),
+    the float32 state is written by nothing else, the step copies no
+    stacked cache leaf whole (state, conv tail, K, V), and the prefill's
+    temporaries fit beside the resident weights and cache."""
+    import jax
+    import jax.numpy as jnp
+
+    from kubeflow_tpu.models.decode import decode_step_stats, prefill
+    from kubeflow_tpu.models.hybrid import HybridConfig, HybridDecoder
+    from kubeflow_tpu.ops import ssm
+
+    monkeypatch.setattr(ssm, "resolve_interpret", lambda interpret: False)
+    cfg = HybridConfig(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, **SSM)
+
+    def place(s):
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(place, jax.eval_shape(
+        lambda k: HybridDecoder(cfg).init(k, jnp.zeros((1, 8), jnp.int32),
+                                          jnp.asarray([8])),
+        jax.random.key(0))["params"])
+    if program == "prefill":
+        compiled = jax.jit(lambda p, t, n: prefill(cfg, p, t, n)).lower(
+            params, place(jax.ShapeDtypeStruct((4, 2048), jnp.int32)),
+            place(jax.ShapeDtypeStruct((4,), jnp.int32))).compile()
+        assert "ssm.step" not in compiled.as_text()
+        # 16 of the full model's 18 blocks' weights and the 128-slot cache
+        # leave 5 GB of the chip
+        assert compiled.memory_analysis().temp_size_in_bytes < 4e9
+        return
+    cache = jax.tree_util.tree_map(place, jax.eval_shape(
+        lambda p: prefill(cfg, p, jnp.zeros((SSM_SLOTS, 1), jnp.int32),
+                          jnp.ones((SSM_SLOTS,), jnp.int32))[1], params))
+
+    def step(params, cache, tokens):
+        def body(carry, _):
+            cache, tokens = carry
+            logits, cache, stats = decode_step_stats(cfg, params, cache,
+                                                     tokens)
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return (cache, nxt), (nxt, stats)
+        (cache, _), out = jax.lax.scan(body, (cache, tokens), None,
+                                       length=K)
+        return cache, out
+
+    text = jax.jit(step, donate_argnums=(1,)).lower(
+        params, cache, place(jax.ShapeDtypeStruct((SSM_SLOTS,), jnp.int32))
+    ).compile().as_text()
+    shapes = {name: "%s[%s]" % ({"float32": "f32", "bfloat16": "bf16"}[
+        str(leaf.dtype)], ",".join(map(str, leaf.shape)))
+        for name, leaf in cache.items() if name != "positions"}
+    assert shapes == {"ssm_state": "f32[1,128,32,128,128]",
+                      "ssm_conv": "bf16[1,128,3,6144]",
+                      "k": "bf16[1,128,8192,256]",
+                      "v": "bf16[1,128,8192,256]"}
+    # the conv tail of ONE block (4.7 MB) the compiler keeps in fast
+    # memory through the K steps: a copy in and one out, at the boundary
+    for name in ("ssm_state", "k", "v"):
+        assert not re.findall(rf"= {re.escape(shapes[name])}\S* copy\(",
+                              text), name
+    # the published 1856 columns are stored at ``expert_width`` 2048: no
+    # expert tensor is re-laid (stored at 1856, ``up_proj`` was, 319 MB a
+    # round), and the grouped products' expert axis is tiled by 512
+    assert cfg.expert_width == 2048
+    assert params["layer_1"]["mlp"]["up_proj"].shape == (32, 2688, 2048)
+    assert not re.findall(r"= bf16\[32,\d+,\d+\]\S* copy\(", text)
+    tiles = re.findall(r'ragged_dot_tiling="([\d,]+)"', text)
+    assert len(tiles) == 2 and all("512" in t.split(",") for t in tiles)
+    state = re.escape(shapes["ssm_state"])
+    writers = {re.search(r"\s([a-z][a-z0-9\-]*)\(", line).group(1)
+               for line in text.splitlines()
+               if re.search(rf" = \(?{state}", line)}
+    assert writers - {"parameter", "get-tuple-element"} == {"custom-call"}
+    assert len(re.findall(r"%ssm\.step[\w.]* = ", text)) == cfg.n_ssm
+
+
 @pytest.mark.parametrize("seq, heads", [(8192, 30), (32768, 2)],
                          ids=["pretrain-8k", "longest-fused-row"])
 def test_fused_flash_backward_compiles_at_its_table_tile(one_chip, no_cache,

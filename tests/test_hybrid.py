@@ -317,18 +317,31 @@ def _ling_setup():
     return ref, cfg, w, pc, params["layer_4"]["mlp"], 3
 
 
+def _nemotron_setup():
+    """Nemotron-3-Nano's routed block at toy widths: relu² experts of two
+    matrices, one group, under its own reference and weight layout."""
+    import test_ssm
+
+    cfg, w, pc, params = test_ssm._setup()
+    return test_ssm.ref, cfg, w, pc, params["layer_3"]["mlp"], 1
+
+
 @pytest.mark.parametrize("family,shares", [(_ling_setup, 4),
-                                           (_dsa_setup, 16)],
-                         ids=["ling-4", "deepseek-v3.2-16"])
+                                           (_dsa_setup, 16),
+                                           (_nemotron_setup, 4)],
+                         ids=["ling-4", "deepseek-v3.2-16", "nemotron-4"])
 def test_four_shares_and_one_shared_expert_make_the_uncut_layer(family,
                                                                 shares):
     """The shares of an expert-parallel layer (4 chips of Ling's
-    deployment, 16 of DeepSeek-V3.2's), with the shared expert that every
-    chip computes counted once, add up to the uncut reference layer."""
+    deployment, 16 of DeepSeek-V3.2's, 4 of Nemotron-3-Nano's), with the
+    shared expert that every chip computes counted once, add up to the
+    uncut reference layer."""
     rf, cfg, w, pc, mlp, layer = family()
     x = _x((2, 10, 64), seed=9)
     lw = rf.layer_weights(w, "moe", layer)
-    whole, _ = rf.routed_mlp(x, lw, cfg)
+    gated = "exp_gate" in lw
+    layer_of = rf.routed_mlp if gated else rf.moe_block
+    whole, _ = layer_of(x, lw, cfg)
     n = 16 // shares
     total = 0.0
     for lo in range(0, 16, n):
@@ -340,14 +353,18 @@ def test_four_shares_and_one_shared_expert_make_the_uncut_layer(family,
         total = total + y
         # the reference's share is the same part
         lw_cut = dict(lw, **{k: lw[k][lo:lo + n]
-                             for k in ("exp_gate", "exp_up", "exp_down")})
+                             for k in ("exp_gate", "exp_up", "exp_down")
+                             if k in lw})
         np.testing.assert_allclose(
-            y, rf.routed_mlp(x, lw_cut, cfg, held=(lo, n))[0], atol=2e-5)
+            y, layer_of(x, lw_cut, cfg, held=(lo, n))[0], atol=2e-5)
     flat = x.reshape(20, 64)
-    shared = rf.swiglu(flat, lw["sh_gate"], lw["sh_up"], lw["sh_down"],
-                       None).reshape(x.shape)
-    np.testing.assert_allclose(total - (shares - 1) * shared, whole,
-                               atol=1e-4)
+    if gated:
+        shared = rf.swiglu(flat, lw["sh_gate"], lw["sh_up"], lw["sh_down"],
+                           None)
+    else:
+        shared = rf.relu2_mlp(flat, lw["sh_up"], lw["sh_down"], None)
+    np.testing.assert_allclose(
+        total - (shares - 1) * shared.reshape(x.shape), whole, atol=1e-4)
 
 
 # -- (f) what the engine refuses for this model ----------------------------------
