@@ -68,6 +68,7 @@ from kubeflow_tpu.models.transformer import (
 )
 from kubeflow_tpu.ops.attention import NEG_INF
 from kubeflow_tpu.ops.dsa import index_scores, select_bias, sparse_attend
+from kubeflow_tpu.ops.gmm import grouped_matmul
 from kubeflow_tpu.ops.kda import kda_step
 from kubeflow_tpu.ops.ssm import (
     pack_state,
@@ -82,7 +83,6 @@ L2_EPS = 1e-6
 MIXERS = ("kda", "mla", "dsa")       # a layer: this mixer, then an MLP
 SUBLAYERS = ("ssm", "gqa", "moe")    # a block: this one sublayer
 GQA_Q_BLOCK = 512    # query rows a fresh prefill attends at once
-EXPERT_TILE = 256    # a routed expert is stored in whole tiles of columns
 
 
 @dataclasses.dataclass(frozen=True)
@@ -142,7 +142,7 @@ class HybridConfig:
     topk_group: int = 2
     routed_scaling: float = 2.5
     norm_topk_prob: bool = True
-    d_expert: int = 32                # published; stored at expert_width
+    d_expert: int = 32                # published, and stored at that
     d_shared: int = 32
     # "swiglu": down(silu(gate x) * up x), three matrices an expert;
     # "relu2": down(relu(up x) ** 2), two
@@ -169,20 +169,13 @@ class HybridConfig:
 
     @property
     def expert_width(self) -> int:
-        """The columns a routed expert is STORED at: ``d_expert`` rounded
-        up to whole tiles of 256 once it is wider than one, the added
-        columns of ``up_proj`` / ``gate_proj`` and rows of ``down_proj``
-        zero (``stored_expert``), which adds exactly 0 to the result.
-        The TPU compiler tiles ``ragged_dot`` along this axis by the
-        largest of 512, 256 and 128 that divides it, and lays a
-        parameter whose last axis is no whole number of 128 lanes out
-        column-major, which the grouped product then re-lays at every
-        call: at 1856 columns that was 319 MB a block a round, and at
-        1920 the products ran in 128-wide tiles at 17 % of the hit
-        experts' bytes over the HBM peak (PERF.md, PR 35). 768 and 2048
-        are stored as they are; 1856 is stored at 2048."""
-        f = self.d_expert
-        return f if f <= EXPERT_TILE else -(-f // EXPERT_TILE) * EXPERT_TILE
+        """The columns a routed expert is STORED at: ``d_expert``, the
+        published width. (Under ``jax.lax.ragged_dot`` 1856 columns were
+        stored at 2048, the fill zero, for the TPU compiler's tiles;
+        ``ops/gmm.py`` takes a block that spans a whole axis, whatever
+        its length, and reads the tensor as the chip lays it out:
+        PERF.md, PRs 35 and 36.)"""
+        return self.d_expert
 
     @property
     def n_kda(self) -> int:
@@ -327,15 +320,14 @@ class HybridConfig:
 
 def stored_expert(w, c: HybridConfig, axis: int):
     """A routed expert tensor at its published width ``d_expert`` along
-    ``axis`` -> as ``RoutedMlp`` stores it: zero-filled to
-    ``expert_width``. What a loader of published weights calls; the
-    module's own initialiser goes through it too."""
+    ``axis`` -> as ``RoutedMlp`` stores it, which is as it comes. What a
+    loader of published weights hands its tensors through: the width is
+    checked here, and a store that differs from the published width
+    would be made here."""
     if w.shape[axis] != c.d_expert:
         raise ValueError(f"axis {axis} of {w.shape} is not d_expert "
                          f"{c.d_expert}")
-    pad = [(0, 0)] * w.ndim
-    pad[axis] = (0, c.expert_width - c.d_expert)
-    return jnp.pad(w, pad)
+    return w
 
 
 def _dense(x, w, dtype):
@@ -939,9 +931,10 @@ class RoutedMlp(nn.Module):
     """Routes over all ``n_experts``, holds ``experts_held`` of them and
     computes what those add for the tokens routed to them, plus the
     shared expert. Tokens are grouped by expert (one sort, then
-    ``ragged_dot`` over the groups): no token is dropped, and the work
-    follows the routed (token, expert) pairs held here. An expert's
-    ``d_expert`` columns are stored at ``expert_width``, the fill zero."""
+    ``ops/gmm.py``'s grouped matmul over the groups, one call a
+    product): no token is dropped, the work follows the routed (token,
+    expert) pairs held here, and an expert no pair reached is not read.
+    An expert is stored at its published width ``d_expert``."""
 
     config: HybridConfig
 
@@ -955,18 +948,14 @@ class RoutedMlp(nn.Module):
         lo, n = c.held
         init = nn.initializers.normal(stddev=D ** -0.5)
 
-        def stored(axis):     # drawn at d_expert, zero-filled to the store
-            return lambda key, shape, dtype: stored_expert(
-                init(key, shape, dtype), c, axis)
         w_router = self.param("router", init, (D, c.n_experts), jnp.float32)
         bias = self.param("router_bias", nn.initializers.zeros,
                           (c.n_experts,), jnp.float32)
         gated = c.expert_act == "swiglu"     # relu2 experts have no gate
         if gated:
-            w_gate = self.param("gate_proj", stored(2), (n, D, F),
-                                c.param_dtype)
-        w_up = self.param("up_proj", stored(2), (n, D, F), c.param_dtype)
-        w_down = self.param("down_proj", stored(1), (n, F, D), c.param_dtype)
+            w_gate = self.param("gate_proj", init, (n, D, F), c.param_dtype)
+        w_up = self.param("up_proj", init, (n, D, F), c.param_dtype)
+        w_down = self.param("down_proj", init, (n, F, D), c.param_dtype)
         sh = [self.param(f"shared_{name}", init, shape, c.param_dtype)
               for name, shape in (("gate", (D, c.d_shared)),
                                   ("up", (D, c.d_shared)),
@@ -990,9 +979,7 @@ class RoutedMlp(nn.Module):
 
         with jax.named_scope("moe.experts"):
             xs = jnp.take(flat, order // K, axis=0).astype(c.dtype)
-            rd = lambda a, b: jax.lax.ragged_dot(  # noqa: E731
-                a, b.astype(c.dtype), sizes,
-                preferred_element_type=jnp.float32)
+            rd = lambda a, b: grouped_matmul(a, b, sizes)  # noqa: E731
             if gated:
                 h = jax.nn.silu(rd(xs, w_gate)) * rd(xs, w_up)
             else:

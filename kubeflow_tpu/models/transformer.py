@@ -755,7 +755,7 @@ class MoeMlp(nn.Module):
         # keep experts resident exist: capacity dispatch above (drops
         # tokens past capacity), and models/hybrid.py:RoutedMlp, which
         # holds a share of the experts and groups routed tokens by expert
-        # (ragged_dot) without dropping any.
+        # (ops/gmm.py:grouped_matmul) without dropping any.
         h = _constrain(h, c.rules, "batch", None, None, "expert_mlp")
         y = jnp.einsum("bsef,efd->bsed", h, w_down.astype(c.dtype))
         y = jnp.einsum("bsed,bse->bsd", y, combine)

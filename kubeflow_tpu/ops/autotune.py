@@ -189,6 +189,38 @@ def paged_vmem_bytes(page_size: int, n_heads: int, n_kv_heads: int,
     return 2 * io + scratch
 
 
+# The grouped matmul (``ops/gmm.py``) has no table rows: the rule below
+# is within 3 % of the best tiles the chip sweep found at every served
+# shape but two prefill ``up`` products (PERF.md, PR 36, sections 6 and
+# 7), and rows that repeat a rule choose nothing. Its expert tile: the
+# largest ``tk x tn`` piece of an expert under this many bytes (two are
+# in flight); pieces of half the size read alike, 128-wide ones 20-25 %
+# slower
+GMM_TILE_BYTES = 4 * 1024 * 1024
+# the row tile: in a decode step 64 reads like 128 and 256 reads 4-10 %
+# slower; in a prefill the best of 64 / 128 / 256 differs by shape, and
+# 512 lost a tenth (a group's last, part-filled tile)
+GMM_ROW_TILE = 128
+
+
+def gmm_vmem_bytes(tm: int, tk: int, tn: int, dtype_bytes: int) -> int:
+    """VMEM residency of one grouped-matmul grid step: the row and
+    expert blocks in their two pipeline buffers, the f32 result block
+    in its two, the f32 accumulator and the product it adds, every last
+    axis padded to whole 128-lane tiles as VMEM lays it out."""
+    f32 = 4
+    lanes = lambda x: -(-x // LANE_MULTIPLE) * LANE_MULTIPLE  # noqa: E731
+    blocks = (tm * lanes(tk) + tk * lanes(tn)) * dtype_bytes
+    return 2 * blocks + 4 * tm * lanes(tn) * f32
+
+
+def _gmm_axis_tiles(size: int) -> List[int]:
+    """The legal tiles of an expert axis: the whole of it, or a whole
+    number of 128 lanes that divides it."""
+    return [size] + [t for t in range(LANE_MULTIPLE, size, LANE_MULTIPLE)
+                     if size % t == 0]
+
+
 # ---------------------------------------------------------------------------
 # Table entries: schema, validation, matching
 # ---------------------------------------------------------------------------
@@ -485,11 +517,14 @@ class TileConfig:
     block_k: int = 0
     head_block: int = 0
     source: str = "fallback"
+    tiling: Tuple[int, ...] = ()      # gmm: (tm, tk, tn)
 
     def as_dict(self) -> Dict[str, Any]:
         d: Dict[str, Any] = {"kernel": self.kernel, "source": self.source}
         if self.kernel == "paged_attn":
             d["head_block"] = self.head_block
+        elif self.kernel == "gmm":
+            d["tiling"] = list(self.tiling)
         else:
             d["block_q"] = self.block_q
             d["block_k"] = self.block_k
@@ -527,7 +562,7 @@ def summarize_resolutions(buf: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
     seen, out = set(), []
     for d in buf:
         key = (d["kernel"], d.get("block_q"), d.get("block_k"),
-               d.get("head_block"), d["source"])
+               d.get("head_block"), tuple(d.get("tiling", ())), d["source"])
         if key in seen:
             continue
         seen.add(key)
@@ -628,3 +663,31 @@ def resolve_paged(*, max_seq_len: int, page_size: int, n_heads: int,
             hb, source = 1, "fallback"
     return _record(TileConfig("paged_attn", head_block=hb, source=source),
                    shape)
+
+
+def _fallback_gmm(k: int, n: int, nbytes: int) -> Tuple[int, int, int]:
+    """The rule: an expert arrives in the largest legal ``tk x tn``
+    pieces under :data:`GMM_TILE_BYTES` (of two equal ones the wider,
+    whose rows lie whole in HBM)."""
+    fits = [(tk * tn, tn, tk) for tk in _gmm_axis_tiles(k)
+            for tn in _gmm_axis_tiles(n) if tk * tn * nbytes <= GMM_TILE_BYTES]
+    _, tn, tk = max(fits) if fits else (0, min(_gmm_axis_tiles(n)),
+                                        min(_gmm_axis_tiles(k)))
+    return GMM_ROW_TILE, tk, tn
+
+
+def resolve_gmm(*, m: int, k: int, n: int, dtype: Any,
+                tiling: Optional[Tuple[int, int, int]] = None) -> TileConfig:
+    """Resolve the grouped matmul's ``(tm, tk, tn)`` for ``(m, k) x
+    (groups, k, n)``: an explicit ``tiling`` untouched, else the
+    analytic rule (:func:`_fallback_gmm`). The row tile never exceeds
+    the rows there are (rounded up to the dtype's sublane tile)."""
+    name = dtype_name(dtype)
+    shape = {"m": m, "k": k, "n": n, "dtype": name}
+    if tiling is not None:
+        return _record(TileConfig("gmm", tiling=tuple(map(int, tiling)),
+                                  source="override"), shape)
+    tm, tk, tn = _fallback_gmm(k, n, DTYPE_BYTES.get(name, 4))
+    floor = SUBLANE_FLOOR.get(name, SUBLANE_FLOOR_STRICTEST)
+    return _record(TileConfig("gmm", tiling=(min(tm, -(-m // floor) * floor),
+                                             tk, tn)), shape)

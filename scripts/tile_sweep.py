@@ -12,6 +12,7 @@ One JSON line per point for PERF.md.
     python scripts/tile_sweep.py --out sweep.json      # + artifact
     python scripts/tile_sweep.py --update-table        # merge winners
     python scripts/tile_sweep.py --paged               # head-group sweep
+    python scripts/tile_sweep.py --gmm                 # grouped matmul only
     python scripts/tile_sweep.py --validate            # no chip needed
 
 ``--validate`` is the preflight stage: strict table legality
@@ -174,6 +175,102 @@ def sweep_paged(args) -> dict:
     return {"generation": gen, "points": points,
             "winner": {"head_block": best[0], "step_ms": best[1],
                        "n_kv_heads": KH, "page_size": ps}}
+
+
+# the routed experts of the three hybrid-decoder cells: (k, n, held
+# experts, [(rows, held pairs)] of a decode step and a prefill)
+GMM_SHAPES = [
+    (2688, 1856, 32, [(768, 192), (49152, 12288)]),     # Nemotron up
+    (1856, 2688, 32, [(768, 192), (49152, 12288)]),     # Nemotron down
+    (2560, 768, 128, [(256, 64), (16384, 4096)]),       # Ling gate / up
+    (768, 2560, 128, [(256, 64), (16384, 4096)]),       # Ling down
+    (7168, 2048, 16, [(128, 8), (8192, 512)]),          # DeepSeek-V3.2 up
+    (2048, 7168, 16, [(128, 8), (8192, 512)]),          # DeepSeek-V3.2 down
+]
+GMM_REPS = 10
+
+
+def sweep_gmm(args) -> dict:
+    """The grouped matmul (``ops/gmm.py``) beside ``jax.lax.ragged_dot``
+    and megablox's ``gmm`` on the same operands: at the tiles the rule
+    gives (``autotune.resolve_gmm``; megablox at the same), at row tiles
+    of 64 and 256, and at the two largest other legal expert pieces up
+    to 8 MiB. The held pairs are spread at random over the experts, ten
+    calls are chained in one program, the best of three counts. One JSON
+    line a point; ``share`` is the hit experts' bytes over 819 GB/s (or
+    the pairs' operations over 197 TFLOP/s) over the time."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from kubeflow_tpu.ops import autotune
+    from kubeflow_tpu.ops.gmm import grouped_matmul, lies_column_major
+
+    megablox = importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm").gmm
+
+    def call(impl, tiling, column_major):
+        if impl == "ragged_dot":
+            return lambda r, w, s: jax.lax.ragged_dot(
+                r, w, s, preferred_element_type=jnp.float32)
+        if impl == "gmm":
+            return lambda r, w, s: grouped_matmul(r, w, s, tiling=tiling)
+        if column_major:        # as ``ops/gmm.py`` reads such an expert
+            return lambda r, w, s: megablox(
+                r, jnp.swapaxes(w, 1, 2), s, jnp.float32, tiling,
+                transpose_rhs=True)
+        return lambda r, w, s: megablox(r, w, s, jnp.float32, tiling)
+
+    def timed(fn, rows, experts, sizes):
+        # operands are arguments: closed over, an expert tensor becomes a
+        # constant of 300-470 MB in the program and a point takes 15 s
+        # of compile (PR 36's second session lost a chip call to that)
+        @jax.jit
+        def chain(rows, experts, sizes):
+            def body(r, _):
+                y = fn(r, experts, sizes)
+                return r + (y[:, :1] * 0).astype(r.dtype), None
+            return jax.lax.scan(body, rows, None, length=GMM_REPS)[0]
+        return _time_best(lambda: chain(rows, experts, sizes)) / GMM_REPS
+
+    gen, points = autotune.backend_generation(), []
+    for k, n, groups, regimes in GMM_SHAPES:
+        experts = jax.random.normal(jax.random.PRNGKey(0), (groups, k, n),
+                                    jnp.bfloat16) * k ** -0.5
+        for m, pairs in regimes:
+            rule = autotune.resolve_gmm(m=m, k=k, n=n,
+                                        dtype=jnp.bfloat16).tiling
+            pieces = sorted(
+                ((tk, tn) for tk in autotune._gmm_axis_tiles(k)
+                 for tn in autotune._gmm_axis_tiles(n)
+                 if tk * tn * 2 <= 2 ** 23 and (tk, tn) != rule[1:]),
+                key=lambda p: -p[0] * p[1])[:2]
+            tried = ([("ragged_dot", None), ("megablox", rule),
+                      ("gmm", rule)]
+                     + [("gmm", (tm,) + rule[1:]) for tm in (64, 256)]
+                     + [("gmm", (rule[0],) + piece) for piece in pieces])
+            sizes = np.bincount(np.random.default_rng(0).integers(
+                0, groups, pairs), minlength=groups).astype(np.int32)
+            rows = jax.random.normal(jax.random.PRNGKey(1), (m, k),
+                                     jnp.bfloat16)
+            least = max(int((sizes > 0).sum()) * k * n * 2 / 819e9,
+                        2 * pairs * k * n / 197e12) * 1e3
+            for impl, tiling in tried:
+                point = {"gmm": True, "impl": impl, "tiling": tiling,
+                         "m": m, "k": k, "n": n, "groups": groups,
+                         "pairs": pairs, "generation": gen}
+                try:
+                    ms = timed(call(impl, tiling, lies_column_major(k, n)),
+                               rows, experts, jnp.asarray(sizes))
+                    point.update(call_ms=round(ms, 4),
+                                 share=round(100 * least / ms, 1))
+                    points.append(point)
+                except Exception as e:  # noqa: BLE001 — skip-on-failure
+                    point["skip"] = f"{type(e).__name__}: {e}"[:200]
+                print(json.dumps(point), flush=True)
+    return {"generation": gen, "points": points}
 
 
 def update_table(result: dict, paged_result: dict, path: str) -> None:
@@ -356,6 +453,10 @@ def main() -> None:
                    help="restrict the sweep to these seq lens")
     p.add_argument("--paged", action="store_true",
                    help="also sweep the paged kernel's head_block")
+    p.add_argument("--gmm", action="store_true",
+                   help="sweep the grouped matmul's tiles beside "
+                        "ragged_dot and megablox, and nothing else (it "
+                        "has no table rows: ops/autotune.py's rule)")
     p.add_argument("--out", default=None, help="write the JSON artifact")
     p.add_argument("--update-table", action="store_true",
                    help="merge measured winners into the table")
@@ -366,6 +467,13 @@ def main() -> None:
     table_path = args.table or autotune.DEFAULT_TABLE_PATH
     if args.validate:
         sys.exit(validate(table_path))
+
+    if args.gmm:
+        artifact = {"gmm": sweep_gmm(args)}
+        if args.out:
+            with open(args.out, "w", encoding="utf-8") as f:
+                json.dump(artifact, f, indent=2)
+        return
 
     # --seq restricts the flash grid (an empty intersection skips it —
     # the "paged only" spelling is --paged --seq 0)

@@ -156,6 +156,30 @@ def test_k_step_program_relays_no_stack_and_no_layer_slice(
     assert (in_loops, at_the_boundary, slices) == ([], [], [])
 
 
+def _grouped_products(text, cfg):
+    """The result shapes of the grouped matmul's calls in a compiled
+    text (``ops/gmm.py``; a Mosaic call is named by its scope, and the
+    benchmark's readers find the routed experts' products by
+    ``%ragged-dot*``). No tensor of the held experts may be copied on
+    its way there: the kernel reads it as the chip lays it out."""
+    assert "ragged_dot_tiling" not in text      # the compiler's own is gone
+    (_, n), d, f = cfg.held, cfg.d_model, cfg.d_expert
+    assert not re.findall(
+        rf"= bf16\[{n},(?:{d},{f}|{f},{d})\]\S* copy\(", text)
+    return [tuple(map(int, dims.split(","))) for dims in re.findall(
+        r"%ragged-dot\.gmm[\w.]* = f32\[([\d,]+)\]\S* custom-call\(", text)]
+
+
+def _compile_kernels(monkeypatch, *modules):
+    """This process's backend is the CPU: compile the kernels themselves,
+    not their interpreter."""
+    from kubeflow_tpu.ops import gmm
+
+    for module in modules + (gmm,):
+        monkeypatch.setattr(module, "resolve_interpret",
+                            lambda interpret: False)
+
+
 # The hybrid decoder (kubeflow_tpu/models/hybrid.py) at Ling-3.0-flash's
 # published widths, one layer of each kind: a dense-MLP KDA layer, a
 # routed MLA layer, a routed KDA layer; 128 of 512 experts held
@@ -168,15 +192,19 @@ HYBRID = dict(vocab_size=39296, d_model=2560, n_heads=32, head_dim=128,
 HYBRID_SLOTS = 32
 
 
+@pytest.mark.parametrize("program", ["step", "prefill"])
 def test_hybrid_k_step_program_rewrites_no_leaf_whole(one_chip, no_cache,
-                                                      monkeypatch):
+                                                      monkeypatch, program):
     """The K-step program of the hybrid decoder, on the TPU's own
     compiler: no stacked cache leaf (``kda_state``, ``kda_conv``,
     ``latent``) is copied, inside the loops or at the program's boundary,
     and the float32 state is written by nothing but the ``kda.step``
     kernel, in place (one call a KDA layer). The latent leaf's last axis
     is padded to whole lanes for this: at 576 the chip's default layout
-    differs from the step's and the leaf was re-laid twice a round."""
+    differs from the step's and the leaf was re-laid twice a round. The
+    routed layers' products are the grouped matmul's calls, three a
+    layer, of slots x 8 rows in the step and of 2 x 1024 x 8 in a batch
+    prefill (also compiled here)."""
     import jax
     import jax.numpy as jnp
 
@@ -184,9 +212,7 @@ def test_hybrid_k_step_program_rewrites_no_leaf_whole(one_chip, no_cache,
     from kubeflow_tpu.models.hybrid import HybridConfig, HybridDecoder
     from kubeflow_tpu.ops import kda
 
-    # this process's backend is the CPU: compile the kernel itself, not
-    # its interpreter
-    monkeypatch.setattr(kda, "resolve_interpret", lambda interpret: False)
+    _compile_kernels(monkeypatch, kda)
     cfg = HybridConfig(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
                        **HYBRID)
 
@@ -196,6 +222,13 @@ def test_hybrid_k_step_program_rewrites_no_leaf_whole(one_chip, no_cache,
     params = jax.tree_util.tree_map(place, jax.eval_shape(
         lambda k: HybridDecoder(cfg).init(k, jnp.zeros((1, 8), jnp.int32)),
         jax.random.key(0))["params"])
+    if program == "prefill":
+        text = jax.jit(lambda p, t, n: prefill(cfg, p, t, n)).lower(
+            params, place(jax.ShapeDtypeStruct((2, 1024), jnp.int32)),
+            place(jax.ShapeDtypeStruct((2,), jnp.int32))).compile().as_text()
+        assert _grouped_products(text, cfg) == [
+            (2 * 1024 * 8, 768), (2 * 1024 * 8, 768), (2 * 1024 * 8, 2560)] * 2
+        return
     cache = jax.tree_util.tree_map(place, jax.eval_shape(
         lambda p: prefill(cfg, p, jnp.zeros((HYBRID_SLOTS, 1), jnp.int32))[1],
         params))
@@ -226,6 +259,9 @@ def test_hybrid_k_step_program_rewrites_no_leaf_whole(one_chip, no_cache,
                if re.search(rf" = \(?{state}", line)}
     assert writers - {"parameter", "get-tuple-element"} == {"custom-call"}
     assert len(re.findall(r"%kda\.step[\w.]* = ", text)) == cfg.n_kda
+    rows = HYBRID_SLOTS * cfg.experts_per_token
+    assert _grouped_products(text, cfg) == [(rows, 768), (rows, 768),
+                                       (rows, 2560)] * 2
 
 
 # DeepSeek-V3.2's sparse latent attention (kubeflow_tpu/models/hybrid.py
@@ -265,7 +301,7 @@ def test_sparse_attention_programs_compile_with_three_kernels_a_layer(
     from kubeflow_tpu.models.hybrid import HybridConfig, HybridDecoder
     from kubeflow_tpu.ops import dsa
 
-    monkeypatch.setattr(dsa, "resolve_interpret", lambda interpret: False)
+    _compile_kernels(monkeypatch, dsa)
     cfg = HybridConfig(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, **DSA)
 
     def place(s):
@@ -318,6 +354,11 @@ def test_sparse_attention_programs_compile_with_three_kernels_a_layer(
     for leaf in (f"bf16[2,{rows},32768,640]", f"bf16[2,{rows},32768,128]"):
         copies = re.findall(rf"= {re.escape(leaf)}\S* copy\(", text)
         assert len(copies) <= (program == "chunk"), (leaf, copies)
+    # the one routed layer's three products, over the pairs of 16 slots'
+    # tokens or of the chunk's 1024
+    pairs = (DSA_SLOTS if program == "step" else cfg.prefill_chunk) * 8
+    assert _grouped_products(text, cfg) == [(pairs, 2048), (pairs, 2048),
+                                       (pairs, 7168)]
 
 
 # Nemotron-3-Nano's one-sublayer blocks (kubeflow_tpu/models/hybrid.py
@@ -348,7 +389,7 @@ def test_state_space_programs_compile_with_the_step_kernel_in_place(
     from kubeflow_tpu.models.hybrid import HybridConfig, HybridDecoder
     from kubeflow_tpu.ops import ssm
 
-    monkeypatch.setattr(ssm, "resolve_interpret", lambda interpret: False)
+    _compile_kernels(monkeypatch, ssm)
     cfg = HybridConfig(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, **SSM)
 
     def place(s):
@@ -363,6 +404,8 @@ def test_state_space_programs_compile_with_the_step_kernel_in_place(
             params, place(jax.ShapeDtypeStruct((4, 2048), jnp.int32)),
             place(jax.ShapeDtypeStruct((4,), jnp.int32))).compile()
         assert "ssm.step" not in compiled.as_text()
+        assert _grouped_products(compiled.as_text(), cfg) == [
+            (4 * 2048 * 6, 1856), (4 * 2048 * 6, 2688)]
         # 16 of the full model's 18 blocks' weights and the 128-slot cache
         # leave 5 GB of the chip
         assert compiled.memory_analysis().temp_size_in_bytes < 4e9
@@ -397,14 +440,15 @@ def test_state_space_programs_compile_with_the_step_kernel_in_place(
     for name in ("ssm_state", "k", "v"):
         assert not re.findall(rf"= {re.escape(shapes[name])}\S* copy\(",
                               text), name
-    # the published 1856 columns are stored at ``expert_width`` 2048: no
-    # expert tensor is re-laid (stored at 1856, ``up_proj`` was, 319 MB a
-    # round), and the grouped products' expert axis is tiled by 512
-    assert cfg.expert_width == 2048
-    assert params["layer_1"]["mlp"]["up_proj"].shape == (32, 2688, 2048)
-    assert not re.findall(r"= bf16\[32,\d+,\d+\]\S* copy\(", text)
-    tiles = re.findall(r'ragged_dot_tiling="([\d,]+)"', text)
-    assert len(tiles) == 2 and all("512" in t.split(",") for t in tiles)
+    # an expert is stored at the published 1856 columns and no expert
+    # tensor is re-laid: the chip lays ``up_proj`` out column-major
+    # (2688 along the lanes), under ``ragged_dot`` that was a copy of
+    # 319 MB a round, and the grouped matmul reads it as it lies
+    assert cfg.expert_width == 1856
+    assert params["layer_1"]["mlp"]["up_proj"].shape == (32, 2688, 1856)
+    assert re.search(r"bf16\[32,2688,1856\]\{1,2,0[:}]", text)
+    assert _grouped_products(text, cfg) == [(SSM_SLOTS * 6, 1856),
+                                       (SSM_SLOTS * 6, 2688)]
     state = re.escape(shapes["ssm_state"])
     writers = {re.search(r"\s([a-z][a-z0-9\-]*)\(", line).group(1)
                for line in text.splitlines()

@@ -125,10 +125,11 @@ def test_fresh_gqa_attends_in_query_blocks(monkeypatch):
         atol=2e-5)
 
 
-@pytest.mark.parametrize("width, stored", [(32, 32), (300, 512)])
+@pytest.mark.parametrize("width, stored", [(32, 32), (300, 300)])
 def test_relu2_routed_mlp_matches_reference_with_one_group(width, stored):
-    """At the published width, and at one the program stores wider
-    (``HybridConfig.expert_width``): the reference knows neither."""
+    """At a width of whole lanes' worth of nothing (32) and at one that
+    is no multiple of 128 (300, the shape class of 1856): the program
+    stores an expert at the published width, as the reference does."""
     cfg, w, pc, params = _setup(moe_intermediate_size=width)
     x = _x((2, 9, 64))
     want, chosen = ref.moe_block(x, ref.layer_weights(w, "moe", 1), cfg)
@@ -148,47 +149,36 @@ def test_relu2_routed_mlp_matches_reference_with_one_group(width, stored):
 
 
 @pytest.mark.parametrize("act", ["relu2", "swiglu"])
-def test_an_expert_is_stored_in_whole_tiles_and_the_fill_adds_nothing(act):
-    """Widths of whole 256-column tiles (Ling's 768, DeepSeek-V3.2's
-    2048) and the tests' narrow ones are stored as they are; 1856 is
-    stored at 2048. The module's own initialiser fills with zeros, what
-    lies in ``down_proj``'s filled rows cannot reach the result, and no
-    gradient reaches the fill, so a trained fill stays zero."""
+def test_an_expert_is_stored_at_its_published_width(act):
+    """Every width is stored as it is published (under ``ragged_dot``
+    1856 was stored at 2048, PR 35; the grouped matmul of ``ops/gmm.py``
+    needs no fill, PR 36): the module's own tensors have ``d_expert``
+    columns, a loader's tensors pass ``stored_expert`` untouched, and
+    one of another width is refused there."""
     _cfg, _w, pc, _params = _setup()
-    widths = {32: 32, 256: 256, 300: 512, 768: 768, 1856: 2048,
-              1920: 2048, 2048: 2048}
-    for f, stored in widths.items():
+    for f in (32, 256, 300, 768, 1856, 1920, 2048):
         assert hybrid.dataclasses.replace(pc, d_expert=f
-                                          ).expert_width == stored
+                                          ).expert_width == f
     c = hybrid.dataclasses.replace(pc, d_expert=300, expert_act=act)
     x = _x((2, 5, 64))
     mlp = hybrid.RoutedMlp(c)
     params = mlp.init(jax.random.key(3), x)["params"]
     names = ("up_proj", "gate_proj") if act == "swiglu" else ("up_proj",)
     for name in names:
-        assert params[name].shape == (16, 64, 512)
-        assert not np.any(params[name][:, :, 300:])
-        assert np.all(np.std(params[name][:, :, :300], axis=(1, 2)) > 0)
-    assert params["down_proj"].shape == (16, 512, 64)
-    assert not np.any(params["down_proj"][:, 300:])
-    want = mlp.apply({"params": params}, x)[0]
-    junk = dict(params, down_proj=params["down_proj"].at[:, 300:].set(7.0))
-    np.testing.assert_array_equal(mlp.apply({"params": junk}, x)[0], want)
-    grads = jax.grad(lambda p: jnp.sum(jnp.square(
-        mlp.apply({"params": p}, x)[0])))(params)
-    assert np.any(grads["up_proj"][:, :, :300])
-    for name in names:
-        assert not np.any(grads[name][:, :, 300:])
-    assert not np.any(grads["down_proj"][:, 300:])
+        assert params[name].shape == (16, 64, 300)
+        assert np.all(np.std(params[name], axis=(1, 2)) > 0)
+        assert hybrid.stored_expert(params[name], c, 2) is params[name]
+    assert params["down_proj"].shape == (16, 300, 64)
+    assert hybrid.stored_expert(params["down_proj"], c, 1) is params[
+        "down_proj"]
     with pytest.raises(ValueError):
-        hybrid.stored_expert(params["up_proj"], c, 2)   # stored already
-
-    # the program of a width is the program of its store, to the letter
-    def text(config):
-        return jax.jit(lambda p, x: hybrid.RoutedMlp(config).apply(
-            {"params": p}, x)[0]).lower(params, x).as_text()
-
-    assert text(c) == text(hybrid.dataclasses.replace(c, d_expert=512))
+        hybrid.stored_expert(jnp.zeros((16, 64, 512)), c, 2)
+    with pytest.raises(ValueError):
+        hybrid.stored_expert(params["down_proj"], c, 2)     # the wrong axis
+    # every column counts: what lies in down_proj's last rows reaches y
+    want = mlp.apply({"params": params}, x)[0]
+    moved = dict(params, down_proj=params["down_proj"].at[:, 299:].add(1.0))
+    assert np.any(np.asarray(mlp.apply({"params": moved}, x)[0]) != want)
 
 
 # -- (b) step = scan, chunk-wise = scan, kernel = jnp step ------------------------
@@ -442,24 +432,31 @@ def _digest(lowered):
 
 
 # sha256 (first 16 hex) of the engine's programs for Ling's and
-# DeepSeek-V3.2's toy twins, read with this test's code on the parent
-# commit (351dbc9, before the one-sublayer blocks). A PR that changes
-# such a program on purpose replaces them.
+# DeepSeek-V3.2's toy twins. PR 35 read them with this test's code on ITS
+# parent commit (351dbc9, before the one-sublayer blocks); PR 36 changed
+# all eight programs on purpose (the routed layers' products are
+# ``ops/gmm.py``'s kernel where they were ``jax.lax.ragged_dot``) and
+# re-pinned them from its own tree. A PR that changes such a program on
+# purpose replaces them.
 SERVED = {
-    "ling": {"_step": "746ca36c1874a277", "_step_greedy": "4839aa61ed2e9d9b",
-             "_prefill": "080faf1ab69688c0",
-             "_prefill_batch": "b2eecac58649f2d8"},
-    "deepseek-v3.2": {"_step": "b62118b18b04a4d3",
-                      "_step_greedy": "ab412194cb0b9a53",
-                      "_prefill": "0d7bb28a57936f66",
-                      "_prefill_batch": "06508231f3f59ca0"},
+    "ling": {"_step": "ba1b20864ee58630", "_step_greedy": "16733965848aaa0e",
+             "_prefill": "497fba429d7e72a1",
+             "_prefill_batch": "5aee8d327cdd867c"},
+    "deepseek-v3.2": {"_step": "292bb21c326ca689",
+                      "_step_greedy": "49700db9151180ac",
+                      "_prefill": "951b33553d7bc6e7",
+                      "_prefill_batch": "d50a35b77009e7d8"},
 }
 
 
 @pytest.mark.parametrize("family", sorted(SERVED))
 def test_mixer_layer_programs_lower_to_the_parents_text(family):
-    """``hybrid.py`` gained three kinds of block; a model of mixer + MLP
-    layers builds the programs it built before, letter for letter."""
+    """A model of mixer + MLP layers builds the programs pinned above,
+    letter for letter: ``hybrid.py``'s one-sublayer blocks (PR 35) left
+    them as they were, the grouped matmul (PR 36) replaced them, and a PR
+    that means to touch neither kind of layer has to leave them so. (The
+    dense decoder's served programs and the training step are pinned in
+    ``tests/test_remat_policy.py``.)"""
     import test_dsa
     import test_hybrid
 
