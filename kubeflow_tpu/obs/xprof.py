@@ -31,6 +31,11 @@ closes the platform's last accounting blind spot with two pieces:
   fingerprint at compile time — every executable carries its
   predicted footprint, every job its live watermark.
 
+Beside the ledger, :func:`compiles_total` is one process-wide count of
+backend compiles (cache loads too) that needs no ledger: the decode
+engine installs it and brackets its rounds and admissions with it
+(``compiles`` on ``engine.round`` / ``engine.admission``).
+
 Both degrade by contract: CPU backends return ``memory_stats() is
 None`` and the sampler goes silent; a backend without monitoring
 events simply never fires the listener (the wrapper fallback still
@@ -289,6 +294,49 @@ def _reset_job_totals() -> None:
     """Test/smoke isolation hook."""
     with _TOTALS_LOCK:
         _JOB_COMPILE_TOTALS.clear()
+
+
+# -- the process-wide compile count -------------------------------------------
+
+# programs this process built or loaded (a hit in the persistent cache
+# fires the backend-compile event too: it stalls the caller all the
+# same). One integer, written under a lock by the listener, READ bare by
+# whoever brackets a stretch of work with it (the decode engine: the two
+# ends of a round and of an admission, never a per-token path)
+_compiles = 0
+_COMPILES_LOCK = threading.Lock()
+_compiles_listening = False
+
+
+def compiles_total() -> int:
+    """Backend compiles (and cache loads) since
+    :func:`install_compile_count`; 0 where it was never installed."""
+    return _compiles
+
+
+def _count_compile(event: str, _duration: float, **_kwargs: Any) -> None:
+    global _compiles
+    if str(event).endswith(COMPILE_EVENT_SUFFIX):
+        with _COMPILES_LOCK:
+            _compiles += 1
+
+
+def install_compile_count() -> bool:
+    """Feed :func:`compiles_total` from ``jax.monitoring``'s duration
+    events, once a process (the first decode engine built calls this; it
+    needs no :class:`CompileLedger`, and a ledger's stale-listener sweep
+    leaves it alone). True when this call registered the listener."""
+    global _compiles_listening
+    with _COMPILES_LOCK:
+        if _compiles_listening:
+            return False
+        try:
+            from jax import monitoring
+        except Exception:  # noqa: BLE001 — no jax: the count stays 0
+            return False
+        monitoring.register_event_duration_secs_listener(_count_compile)
+        _compiles_listening = True
+    return True
 
 
 # -- the compile-event ledger ------------------------------------------------

@@ -73,6 +73,7 @@ from kubeflow_tpu.obs import (
     profiler_annotator,
 )
 from kubeflow_tpu.obs import requests as reqobs
+from kubeflow_tpu.obs import xprof
 from kubeflow_tpu.serving.kvcache import (  # noqa: F401 — re-exported
     EngineClosed,
     PagedCache,
@@ -93,6 +94,20 @@ _round_seconds = DEFAULT_REGISTRY.counter(
     "kftpu_engine_round_seconds_total",
     "engine-thread seconds by round phase (wait, admit, step, sync, "
     "emit): the rate by phase is where the thread's time goes")
+_admit_seconds = DEFAULT_REGISTRY.counter(
+    "kftpu_engine_admit_seconds_total",
+    "engine-thread seconds inside device admissions by phase (host, "
+    "launch, read, insert): launch + insert + host over the sum is the "
+    "share of admission in which the host is not waiting for the device")
+_admissions_c = DEFAULT_REGISTRY.counter(
+    "kftpu_engine_admissions_total",
+    "device admissions (one prefill the engine waits for) by kind (row, "
+    "prefix, chunked, batch)")
+_prefill_tokens_c = DEFAULT_REGISTRY.counter(
+    "kftpu_engine_prefill_tokens_total",
+    "tokens the admissions' prefill programs ran (what=scanned: rows x "
+    "width, pad rows and bucket padding included) and the requests' own "
+    "among them (what=prompt)")
 _occupancy = DEFAULT_REGISTRY.gauge(
     "kftpu_engine_active_slots", "active slots in the decode batch")
 _slots_g = DEFAULT_REGISTRY.gauge(
@@ -134,6 +149,10 @@ _END = object()  # per-request stream sentinel
 # an ``engine.round`` span's ``<phase>_s`` attrs and the ``phase`` label
 # of kftpu_engine_round_seconds_total, in the order a round passes them
 _ROUND_PHASES = ("wait", "admit", "step", "sync", "emit")
+# an ``engine.admission`` span's ``<phase>_s`` attrs, the ``phase`` label
+# of kftpu_engine_admit_seconds_total and the ``engine.admit.<phase>``
+# leaf annotations on the profiler's host timeline
+_ADMIT_PHASES = ("host", "launch", "read", "insert")
 
 
 @dataclasses.dataclass
@@ -199,6 +218,77 @@ class _Slot:
     emitted: List[int] = dataclasses.field(default_factory=list)
 
 
+class _Admission:
+    """The record of ONE device admission, one prefill the engine waits
+    for, while it runs: a context the cache manager opens where the
+    admission begins (``DecodeEngine._open_admission``) and leaves once
+    the slots are armed, naming each stretch as it reaches it. ``enter``
+    is the one place the engine thread changes its ``engine.admit.*``
+    leaf: it closes the open annotation, reads the clock ONCE, books the
+    stretch that ended and opens the next, so the four durations tile
+    ``[start, end]`` on the very boundaries the profiler's host plane
+    shows. What the programs ran is counted on the attributes: ``rows``
+    requests in ``rows_padded`` program rows, each scanned ``width``
+    tokens wide (in ``chunks`` chunk programs, where chunked), of which
+    ``prompt_tokens`` were the requests' own."""
+
+    def __init__(self, eng: "DecodeEngine", kind: str, *, rows: int = 1,
+                 rows_padded: int = 1, width: int = 0,
+                 prompt_tokens: int = 0) -> None:
+        self.eng, self.kind = eng, kind
+        self.rows, self.rows_padded = rows, rows_padded
+        self.width, self.prompt_tokens, self.chunks = width, prompt_tokens, 0
+        self.secs = dict.fromkeys(_ADMIT_PHASES, 0.0)
+        self.status = "OK"
+        self._phase = "host"        # the leaf run_once opened
+        self._compiles0 = xprof.compiles_total()
+        self.start = self.end = self._t = eng.clock()
+
+    def enter(self, phase: str) -> float:
+        """The thread passes into ``phase``; returns the boundary."""
+        eng = self.eng
+        eng._leave_leaf()
+        now = eng.clock()
+        self.secs[self._phase] += now - self._t
+        self._phase, self._t = phase, now
+        eng._enter_leaf("engine.admit." + phase)
+        return now
+
+    def __enter__(self) -> "_Admission":
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        """One ``engine.admission`` span, child of the engine's run as
+        the rounds are, and the same seconds and counts into the three
+        counters; the thread is left in the host leaf. A failure is
+        recorded too (``status``), with what had been counted."""
+        eng = self.eng
+        if exc_type is not None:
+            self.status = f"ERROR: {exc_type.__name__}"
+        self.end = (self.enter("host") if self._phase != "host"
+                    else eng.clock())
+        self.secs["host"] += self.end - self._t
+        scanned = self.rows_padded * self.width
+        attrs = {"model": eng.name, "round": eng.rounds_total,
+                 "kind": self.kind, "rows": self.rows,
+                 "rows_padded": self.rows_padded, "width": self.width,
+                 "prompt_tokens": self.prompt_tokens,
+                 "scanned_tokens": scanned,
+                 "compiles": xprof.compiles_total() - self._compiles0}
+        if self.chunks:
+            attrs["chunks"] = self.chunks
+        for phase, sec in self.secs.items():
+            attrs[phase + "_s"] = sec
+            _admit_seconds.inc(sec, model=eng.name, phase=phase)
+        _admissions_c.inc(model=eng.name, kind=self.kind)
+        _prefill_tokens_c.inc(self.prompt_tokens, model=eng.name,
+                              what="prompt")
+        _prefill_tokens_c.inc(scanned, model=eng.name, what="scanned")
+        eng.tracer.record("engine.admission", start=self.start,
+                          end=self.end, parent=eng._run_ctx, attrs=attrs,
+                          status=self.status)
+
+
 def _kv_attr(name: str) -> property:
     return property(lambda self: getattr(self._kv, name),
                     lambda self, value: setattr(self._kv, name, value))
@@ -247,9 +337,9 @@ class DecodeEngine:
         # host-side timing source for queue-wait/admit/decode spans; a
         # fake clock makes engine span trees deterministic in tests
         self.clock: Clock = clock if clock is not None else time.monotonic
-        # spans land in the shared collector; the profiler annotator
-        # mirrors live admit/prefill spans onto the XLA host timeline
-        # during a capture (docs/OBSERVABILITY.md)
+        # spans land in the shared collector; through the profiler
+        # annotator the loop names the leaf the thread is in on the XLA
+        # host timeline during a capture (docs/OBSERVABILITY.md)
         self.tracer = tracer if tracer is not None else Tracer(
             clock=self.clock, annotator=profiler_annotator())
         # what the engine THREAD did with its time hangs off one
@@ -261,6 +351,13 @@ class DecodeEngine:
                                     os.urandom(8).hex())
         self._t_run0: Optional[float] = self.clock()
         self._admitted = 0  # requests admitted in the round in progress
+        # the ONE ``engine.admit.*`` annotation open on the engine thread
+        # while admission runs (None outside it, and without a bridge)
+        self._leaf = None
+        # programs built or loaded, process-wide: read at a round's and
+        # an admission's two ends (``compiles`` on their spans)
+        xprof.install_compile_count()
+        self._compiles0 = 0
         # the request-lifecycle ledger (docs/OBSERVABILITY.md): phase
         # marks ride the clock reads this file already takes; the
         # process-wide default joins the edge's phases by trace id
@@ -335,6 +432,7 @@ class DecodeEngine:
         self.steps_total = 0
         self.rounds_total = 0  # run_once cycles that did work
         self.tokens_total = 0
+        self._tokens_exported = 0  # of them in kftpu_engine_tokens_total
         self.greedy_steps = 0  # steps served by the argmax fast path
         self.recoveries = 0      # cache rebuild-and-replay events
 
@@ -603,6 +701,12 @@ class DecodeEngine:
         self.rledger.mark(req.rid, reqobs.ADMISSION, now)
         return now
 
+    def _open_admission(self, kind: str, **counted: int) -> _Admission:
+        """A device admission of ``kind`` (``row`` | ``prefix`` |
+        ``chunked`` | ``batch``) begins on the engine thread; ``counted``
+        is what the caller knows of its program already."""
+        return _Admission(self, kind, **counted)
+
     def _arm_slot(self, req: _Request, slot: int, token: int, t: float, *,
                   produced: int = 0, emitted=(), fold: int = 0) -> bool:
         """``slot`` starts (or, replayed, resumes) decoding ``req``: emit
@@ -644,8 +748,7 @@ class DecodeEngine:
         neither this method nor the ledger reads a clock here."""
         slot.produced += 1
         slot.emitted.append(token)
-        self.tokens_total += 1
-        _tokens_total.inc(model=self.name)
+        self.tokens_total += 1  # the series follows once a round
         self.rledger.emit(slot.req.rid, t)
         slot.req.out.put(token)
 
@@ -669,16 +772,21 @@ class DecodeEngine:
         A cycle that did work is one ``engine.round`` span: the loop
         reads its phase boundaries itself (``marks``: admit, then step /
         sync / emit as the round reaches them) and names the same
-        phases on the profiler's host timeline, so every instant of the
-        engine thread lies inside one ``engine.*`` annotation."""
+        phases on the profiler's host timeline, admission by the leaf
+        it is in (``engine.admit.host`` but where an ``_Admission`` has
+        entered ``launch``, ``read`` or ``insert``), so every instant of
+        the engine thread lies inside exactly one ``engine.*``
+        annotation."""
         kv = self._kv
         marks = [self.clock()]
         self._admitted = 0
+        self._compiles0 = xprof.compiles_total()
         # the wait phase: only an engine with nothing to step or
         # prefill may block on its queue, and that time is no work
         head, wait_s = (self._wait_pending(timeout) if self._idle()
                         else (None, 0.0))
-        with self._annotate("engine.admit"):
+        self._enter_leaf("engine.admit.host")
+        try:
             try:
                 worked = self._admit(head)
             except _CacheInvalidated:
@@ -701,6 +809,8 @@ class DecodeEngine:
             # active slot is greedy the cheap argmax step is bit-identical
             # and skips the per-row sampler (vocab sort) each token
             all_greedy = all(s.req.temperature <= 0.0 for _, s in active)
+        finally:
+            self._leave_leaf()
         if not active:
             if worked:
                 self._record_round(marks, wait_s)
@@ -801,6 +911,20 @@ class DecodeEngine:
         ann = self.tracer.annotator
         return ann(name) if ann is not None else contextlib.nullcontext()
 
+    def _enter_leaf(self, name: str) -> None:
+        """``name`` becomes THE annotation the engine thread lies in,
+        until :meth:`_leave_leaf` (admission's leaves change mid-block,
+        which a ``with`` cannot say)."""
+        ann = self.tracer.annotator
+        if ann is not None:
+            self._leaf = ann(name)
+            self._leaf.__enter__()
+
+    def _leave_leaf(self) -> None:
+        leaf, self._leaf = self._leaf, None
+        if leaf is not None:
+            leaf.__exit__(None, None, None)
+
     def _record_round(self, marks: List[float], wait_s: float, *,
                       rows: int = 0, k: int = 0, greedy: bool = False,
                       moe: Optional[dict] = None) -> None:
@@ -812,7 +936,10 @@ class DecodeEngine:
         ``kftpu_engine_round_seconds_total{phase}``. ``moe`` is what a
         model's layers counted over the round's steps (the routed ones
         ``experts_hit`` and ``routed_pairs``, the sparse-attention ones
-        ``index_scored`` and ``index_selected``)."""
+        ``index_scored`` and ``index_selected``). ``compiles`` is the
+        programs the process built or loaded while the round ran, and
+        the tokens the round emitted (first tokens armed in admission
+        included) reach ``kftpu_engine_tokens_total`` here, once."""
         bounds = marks + [self.clock()]
         secs = dict.fromkeys(_ROUND_PHASES, 0.0)
         for phase, t_a, t_b in zip(_ROUND_PHASES[1:], bounds, bounds[1:]):
@@ -821,7 +948,11 @@ class DecodeEngine:
         secs["admit"] -= wait_s
         attrs = {"model": self.name, "round": self.rounds_total,
                  "rows": rows, "k": k, "admitted": self._admitted,
-                 "greedy": greedy}
+                 "greedy": greedy,
+                 "compiles": xprof.compiles_total() - self._compiles0}
+        _tokens_total.inc(self.tokens_total - self._tokens_exported,
+                          model=self.name)
+        self._tokens_exported = self.tokens_total
         for phase, sec in secs.items():
             attrs[f"{phase}_s"] = sec
             _round_seconds.inc(sec, model=self.name, phase=phase)

@@ -12,12 +12,15 @@ The seam is what the loop calls: ``check_submit``, ``admit``,
 (recovery), ``drain``, ``snapshot``, the ``in_admission`` / ``waiting``
 counts and ``admission_recovers`` (the manager's error scope). A manager
 calls back the engine's host services (clock, tracer, request ledger,
-``_note_queue_wait``, ``_next_pending``, ``_arm_slot``, ``_fail``); the
-engine imports this module, nothing here imports the engine.
+``_note_queue_wait``, ``_next_pending``, ``_arm_slot``, ``_fail``, and
+``_open_admission``: :class:`RowCache` runs each device admission inside
+one such record and tells it which stretch it is in); the engine imports
+this module, nothing here imports the engine.
 """
 # tpulint: disable-file=TPU018 — as in engine.py: the per-bucket program
 # inventory compiles lazily on first dispatch (billed by the CompileLedger
-# listener); timed_compile's AOT path would compile every program twice.
+# listener, and counted as ``compiles`` on the admission that stalled for
+# it); timed_compile's AOT path would compile every program twice.
 
 from __future__ import annotations
 
@@ -55,9 +58,6 @@ _prefix_misses = DEFAULT_REGISTRY.counter(
 _prefix_bytes_g = DEFAULT_REGISTRY.gauge(
     "kftpu_engine_prefix_cache_bytes",
     "HBM bytes held by cached prompt-prefix KV rows")
-_prefix_budget_g = DEFAULT_REGISTRY.gauge(
-    "kftpu_engine_prefix_cache_budget_bytes",
-    "prefix-cache byte budget (entries evict LRU to stay under it)")
 _kv_pages_g = DEFAULT_REGISTRY.gauge(
     "kftpu_engine_kv_pages_in_use",
     "physical KV pages allocated out of the paged engine's pool")
@@ -233,8 +233,6 @@ class _CacheManager:
         if budget_bytes is None:
             budget_bytes = max(0, int(entries)) * self._prefix_row_bytes
         self._prefix_budget_bytes = max(0, int(budget_bytes))
-        _prefix_budget_g.set(self._prefix_budget_bytes,
-                             model=self.eng.name)
 
     def _sample1(self, logits, seed, fold, temperature, top_k, top_p):
         """One row through the engine's sampler (prefill's first token;
@@ -513,22 +511,25 @@ class RowCache(_CacheManager):
             jnp.asarray([L], jnp.int32), temperature, top_k, top_p, seed,
             jnp.int32(fold))
 
-    def _prefix_cache_row(self, prefix: np.ndarray):
-        """The 1-row cache holding this prefilled prefix (LRU)."""
+    def _prefix_cache_row(self, prefix: np.ndarray) -> tuple:
+        """The 1-row cache holding this prefilled prefix (LRU), and the
+        width at which a miss just scanned it (0: a hit ran nothing)."""
         key = (prefix.size, prefix.tobytes())
         cached = self._prefix_store.get(key)
         self._count_prefix(cached is not None)
         if cached is not None:
             self._prefix_store.move_to_end(key)
-            return cached
+            return cached, 0
         N = prefix.size
         # sampling args are dummies — only the cache is kept
         if self._is_long(N):
-            _, pcache, _ = self._prefill_chunks(_no_sampling(), prefix, 0)
+            _, pcache, chunks = self._prefill_chunks(_no_sampling(), prefix,
+                                                     0)
+            width = chunks * self._chunk_width
         else:
+            width = pow2_bucket(N, self.cfg.max_seq_len)
             _, pcache = self._prefill(
-                self.eng._params,
-                _padded(prefix, pow2_bucket(N, self.cfg.max_seq_len)),
+                self.eng._params, _padded(prefix, width),
                 jnp.asarray([N], jnp.int32), *_no_sampling(), jnp.int32(0))
         # byte-budget admission: evict LRU until the new row fits
         # (check_submit already routed away callers that can never fit)
@@ -541,69 +542,105 @@ class RowCache(_CacheManager):
             self._prefix_store[key] = pcache
             self.prefix_cache_bytes += self._prefix_row_bytes
         _prefix_bytes_g.set(self.prefix_cache_bytes, model=self.eng.name)
-        return pcache
+        return pcache, width
 
     def _admit_row(self, req, slot: int) -> None:
         """Prefill the request's prompt and write it into ``slot``; a
-        failure surfaces to THIS caller only."""
+        failure surfaces to THIS caller only. One device admission, its
+        stretches named in the order this path runs them: the programs'
+        launch, the insert's dispatch behind them, then the wait for the
+        first token. The request's own ``engine.admit`` (queue's end to
+        insert dispatched) and ``engine.prefill`` (the launch) spans are
+        recorded post hoc on the same boundaries, as the batch path's."""
         eng = self.eng
+        S, Smax = int(req.prompt.size), self.cfg.max_seq_len
+        admit = {"model": eng.name, "slot": slot, "prompt_tokens": S,
+                 "batched": False, "round": eng.rounds_total}
+        prefill_attrs = {"prompt_tokens": S}
+        t_launch = t_insert = t_read = None
+
+        def note_spans(end: float, status: str = "OK") -> None:
+            span = eng.tracer.record(
+                "engine.admit", start=t_admit,
+                end=end if t_read is None else t_read,
+                parent=req.ctx, attrs=admit, status=status)
+            if t_launch is not None:
+                eng.tracer.record(
+                    "engine.prefill", start=t_launch,
+                    end=end if t_insert is None else t_insert,
+                    parent=span, attrs=prefill_attrs,
+                    status=status if t_insert is None else "OK")
+
+        adm = eng._open_admission(
+            "prefix" if req.prefix_len
+            else "chunked" if self._is_long(S) else "row",
+            prompt_tokens=S)        # less a reused prefix: _continue_row
+        t_admit = adm.start
         try:
-            eng._note_queue_wait(req)
-            S = req.prompt.size
-            Smax = self.cfg.max_seq_len
-            with eng.tracer.span("engine.admit", parent=req.ctx, attrs={
-                    "model": eng.name, "slot": slot,
-                    "prompt_tokens": int(S), "batched": False,
-                    "round": eng.rounds_total}) as adm, \
-                    eng._mesh_ctx():
-                # prefill phase opens here (prefix-row prep IS prefill
-                # work); admission was the gap since _note_queue_wait
-                eng.rledger.mark(req.rid, reqobs.PREFILL, eng.clock())
-                if req.prefix_len:
-                    N = req.prefix_len
-                    pcache = self._prefix_cache_row(req.prompt[:N])
-                    suf = S - N
-                    sbucket = pow2_bucket(suf, Smax)
-                    if N + sbucket > Smax:
-                        # a padded suffix would start-clamp its cache
-                        # write past the context end; serve the exact
-                        # length (a rare boundary compile)
-                        sbucket = suf
-                    with eng.tracer.span("engine.prefill", attrs={
-                            "prompt_tokens": int(S),
-                            "prefix_len": int(N)}) as span:
-                        if self._is_long(suf):
-                            tok, row_cache, chunks = self._prefill_chunks(
-                                _sampling_args(req), req.prompt, 0, pcache,
-                                N)
-                            span.attrs["chunks"] = adm.attrs["chunks"] = chunks
-                        else:
-                            tok, row_cache = self._continue(
-                                eng._params, pcache,
-                                _padded(req.prompt[N:], sbucket),
-                                jnp.asarray([suf], jnp.int32),
-                                jnp.asarray([S], jnp.int32),
-                                *_sampling_args(req))
-                elif self._is_long(S):
-                    with eng.tracer.span("engine.prefill", attrs={
-                            "prompt_tokens": int(S),
-                            "bucket": self._chunk_width}) as span:
-                        tok, row_cache, chunks = self._prefill_chunks(
+            with adm:
+                t_admit = eng._note_queue_wait(req)
+                with eng._mesh_ctx():
+                    # prefill phase opens here (prefix-row prep IS
+                    # prefill work); admission was the gap since
+                    # _note_queue_wait
+                    t_launch = adm.enter("launch")
+                    eng.rledger.mark(req.rid, reqobs.PREFILL, t_launch)
+                    if req.prefix_len:
+                        prefill_attrs["prefix_len"] = int(req.prefix_len)
+                        tok, row_cache = self._continue_row(req, adm)
+                    elif self._is_long(S):
+                        prefill_attrs["bucket"] = self._chunk_width
+                        tok, row_cache, adm.chunks = self._prefill_chunks(
                             _sampling_args(req), req.prompt, 0)
-                        span.attrs["chunks"] = adm.attrs["chunks"] = chunks
-                else:
-                    bucket = pow2_bucket(S, Smax)
-                    with eng.tracer.span("engine.prefill", attrs={
-                            "prompt_tokens": int(S), "bucket": bucket}):
+                        adm.width = adm.chunks * self._chunk_width
+                    else:
+                        adm.width = pow2_bucket(S, Smax)
+                        prefill_attrs["bucket"] = adm.width
                         tok, row_cache = self._prefill_row(
-                            req, req.prompt, 0, bucket)
-                self.cache = self._insert(self.cache, row_cache,
-                                          jnp.int32(slot))
-            # the prefill-sampled first token must surface NOW —
-            # emitting it is what makes TTFT one prefill + one step
-            eng._arm_slot(req, slot, int(tok), eng.clock())  # tpulint: disable=TPU017
+                            req, req.prompt, 0, adm.width)
+                    if adm.chunks:
+                        prefill_attrs["chunks"] = admit["chunks"] = \
+                            adm.chunks
+                    t_insert = adm.enter("insert")
+                    self.cache = self._insert(self.cache, row_cache,
+                                              jnp.int32(slot))
+                t_read = adm.enter("read")
+                # the prefill-sampled first token must surface NOW —
+                # emitting it is what makes TTFT one prefill + one step
+                tok = int(tok)  # tpulint: disable=TPU017
+                t_armed = adm.enter("host")
+                eng._arm_slot(req, slot, tok, t_armed)
+                note_spans(t_armed)
         except Exception as e:  # noqa: BLE001 — surface to the caller
-            eng._fail(req, e, eng.clock())
+            note_spans(adm.end, adm.status)
+            eng._fail(req, e, adm.end)
+
+    def _continue_row(self, req, adm) -> tuple:
+        """A row admission's launch on the prefix path: the stored
+        prefix's row (a miss prefills it first, and the admission counts
+        that scan too), continued by the request's suffix. Returns
+        (first token, the row's cache)."""
+        S, N, Smax = int(req.prompt.size), req.prefix_len, \
+            self.cfg.max_seq_len
+        pcache, adm.width = self._prefix_cache_row(req.prompt[:N])
+        suf = S - N
+        adm.prompt_tokens = suf + (N if adm.width else 0)
+        if self._is_long(suf):
+            tok, row_cache, adm.chunks = self._prefill_chunks(
+                _sampling_args(req), req.prompt, 0, pcache, N)
+            adm.width += adm.chunks * self._chunk_width
+            return tok, row_cache
+        sbucket = pow2_bucket(suf, Smax)
+        if N + sbucket > Smax:
+            # a padded suffix would start-clamp its cache write past the
+            # context end; serve the exact length (a rare boundary
+            # compile)
+            sbucket = suf
+        adm.width += sbucket
+        return self._continue(
+            self.eng._params, pcache, _padded(req.prompt[N:], sbucket),
+            jnp.asarray([suf], jnp.int32), jnp.asarray([S], jnp.int32),
+            *_sampling_args(req))
 
     def _admit_batch(self, bucket: int, members: List[tuple]) -> None:
         """One shared prefill for same-bucket requests, then their rows'
@@ -611,78 +648,86 @@ class RowCache(_CacheManager):
         program inventory stays batch buckets × prompt buckets); pad
         rows are length-1 junk nothing reads or inserts. Token-identical
         to the row path: same ragged per-row lengths, same
-        ``fold_in(key(seed), 0)`` sampling."""
+        ``fold_in(key(seed), 0)`` sampling. One device admission:
+        padding on the host, the launch, the wait for the first tokens,
+        the insert's dispatch, arming."""
         eng = self.eng
         k = len(members)
-        t0 = eng.clock()
-        for req, _slot in members:
-            eng._note_queue_wait(req)
         bb = pow2_bucket(k, min(eng.slots, eng.admit_batch_max))
         reqs, slot_list = zip(*members)
+        with eng._open_admission(
+                "batch", rows=k, rows_padded=bb, width=bucket,
+                prompt_tokens=int(sum(r.prompt.size for r in reqs))) as adm:
+            for req in reqs:
+                eng._note_queue_wait(req)
 
-        def col(values, pad, dtype):  # one per member, padded to bb rows
-            return np.asarray(list(values) + [pad] * (bb - k), dtype)
+            def col(values, pad, dtype):  # one per member, padded to bb
+                return np.asarray(list(values) + [pad] * (bb - k), dtype)
 
-        prompts = np.zeros((bb, bucket), np.int32)
-        for i, req in enumerate(reqs):
-            prompts[i, :req.prompt.size] = req.prompt
-        lens = col((r.prompt.size for r in reqs), 1, np.int32)
-        temps = col((r.temperature for r in reqs), 0.0, np.float32)
-        tks = col((r.top_k for r in reqs), 0, np.int32)
-        tps = col((r.top_p for r in reqs), 1.0, np.float32)
-        seeds = col((r.seed for r in reqs), 0, np.int32)
-        slot_ids = col(slot_list, 0, np.int32)
-        valid = np.arange(bb) < k
-        with eng._mesh_ctx():
-            # the shared device call is annotated on the profiler's
-            # timeline and recorded below as a child of each member's
-            # admit span (a context-managed span here would be an orphan
-            # root: the engine thread has no active span)
-            p0 = eng.clock()
-            for req, _slot in members:
-                # the shared device call opens every member's prefill
-                # phase on the same already-read timestamp
-                eng.rledger.mark(req.rid, reqobs.PREFILL, p0)
-            with eng._annotate("engine.prefill"):
+            prompts = np.zeros((bb, bucket), np.int32)
+            for i, req in enumerate(reqs):
+                prompts[i, :req.prompt.size] = req.prompt
+            lens = col((r.prompt.size for r in reqs), 1, np.int32)
+            temps = col((r.temperature for r in reqs), 0.0, np.float32)
+            tks = col((r.top_k for r in reqs), 0, np.int32)
+            tps = col((r.top_p for r in reqs), 1.0, np.float32)
+            seeds = col((r.seed for r in reqs), 0, np.int32)
+            slot_ids = col(slot_list, 0, np.int32)
+            valid = np.arange(bb) < k
+            with eng._mesh_ctx():
+                # the shared device call is one leaf on the profiler's
+                # timeline and recorded below as a child of each
+                # member's admit span (a context-managed span here would
+                # be an orphan root: the engine thread has no active
+                # span)
+                p0 = adm.enter("launch")
+                for req in reqs:
+                    # the shared device call opens every member's
+                    # prefill phase on the same already-read timestamp
+                    eng.rledger.mark(req.rid, reqobs.PREFILL, p0)
                 toks, bcache = self._prefill_batch(
                     eng._params, jnp.asarray(prompts), jnp.asarray(lens),
                     jnp.asarray(temps), jnp.asarray(tks),
                     jnp.asarray(tps), jnp.asarray(seeds))
-            # force completion (the host needs the tokens anyway) BEFORE
-            # the donating inserts: a device-side prefill failure must
-            # surface while the cache is intact, so that admit's
-            # row-path fallback retries against a live engine
-            toks = np.asarray(toks)  # tpulint: disable=TPU017 — deliberate barrier, see above
-            p1 = eng.clock()
-            try:
-                self.cache = self._insert_rows(
-                    self.cache, bcache, jnp.asarray(slot_ids),
-                    jnp.asarray(valid))
-            except Exception as e:  # noqa: BLE001 — donation consumed
-                # the cache: fail the chunk retryably and escalate so
-                # that the loop closes the engine
-                t_fail = eng.clock()
-                for req, _ in members:
-                    eng._fail(req, EngineClosed(
-                        "engine cache invalidated during admission"),
-                        t_fail)
-                raise _CacheInvalidated(str(e)) from e
-        self.batch_prefills += 1
-        t1 = eng.clock()
-        for i, (req, slot) in enumerate(members):
-            adm = eng.tracer.record(
-                "engine.admit", start=t0, end=t1, parent=req.ctx,
-                attrs={"model": eng.name, "slot": slot,
-                       "prompt_tokens": int(lens[i]),
-                       "batched": True, "batch": k,
-                       "round": eng.rounds_total})
-            # the shared prefill's time range, nested in THIS member's
-            # trace (same shape as the row path's admit→prefill)
-            eng.tracer.record(
-                "engine.prefill", start=p0, end=p1, parent=adm,
-                attrs={"prompt_tokens": int(lens[i]), "bucket": bucket,
-                       "batched": True, "batch": k})
-            eng._arm_slot(req, slot, int(toks[i]), t1)
+                adm.enter("read")
+                # force completion (the host needs the tokens anyway)
+                # BEFORE the donating inserts: a device-side prefill
+                # failure must surface while the cache is intact, so
+                # that admit's row-path fallback retries against a live
+                # engine
+                toks = np.asarray(toks)  # tpulint: disable=TPU017 — deliberate barrier, see above
+                p1 = adm.enter("insert")
+                try:
+                    self.cache = self._insert_rows(
+                        self.cache, bcache, jnp.asarray(slot_ids),
+                        jnp.asarray(valid))
+                except Exception as e:  # noqa: BLE001 — donation consumed
+                    # the cache: fail the chunk retryably and escalate
+                    # so that the loop closes the engine
+                    t_fail = eng.clock()
+                    for req in reqs:
+                        eng._fail(req, EngineClosed(
+                            "engine cache invalidated during admission"),
+                            t_fail)
+                    raise _CacheInvalidated(str(e)) from e
+            self.batch_prefills += 1
+            t1 = adm.enter("host")
+            for i, (req, slot) in enumerate(members):
+                span = eng.tracer.record(
+                    "engine.admit", start=adm.start, end=t1,
+                    parent=req.ctx,
+                    attrs={"model": eng.name, "slot": slot,
+                           "prompt_tokens": int(lens[i]),
+                           "batched": True, "batch": k,
+                           "round": eng.rounds_total})
+                # the shared prefill's time range, nested in THIS
+                # member's trace (same shape as the row path's
+                # admit→prefill)
+                eng.tracer.record(
+                    "engine.prefill", start=p0, end=p1, parent=span,
+                    attrs={"prompt_tokens": int(lens[i]), "bucket": bucket,
+                           "batched": True, "batch": k})
+                eng._arm_slot(req, slot, int(toks[i]), t1)
 
 
 @dataclasses.dataclass

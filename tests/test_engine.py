@@ -1046,6 +1046,215 @@ def test_admit_spans_name_their_round(lm):
     assert {a.attrs["round"] for a in admits} == {0, 1}
 
 
+_ADMIT_S = ("host_s", "launch_s", "read_s", "insert_s")
+
+
+def _admissions(collector):
+    return [s for s in collector.spans() if s.name == "engine.admission"]
+
+
+def _engine_counter(series, **labels):
+    from kubeflow_tpu.utils import DEFAULT_REGISTRY
+
+    return DEFAULT_REGISTRY.counter(series).get(**labels)
+
+
+# what each path's admissions must have counted, in the order they ran:
+# (rows, rows_padded, width, prompt_tokens, chunks)
+_ADMISSION_CASES = {
+    # 3 tokens in the bucket of 4
+    "row": ([dict(prompt=[5, 11, 17])], [(1, 1, 4, 3, 0)]),
+    # a miss prefills the 6-token prefix (bucket 8) and continues the
+    # 5-token suffix (bucket 8); the hit after it runs the suffix alone
+    "prefix": ([dict(prompt=list(range(1, 12)), prefix_len=6)] * 2,
+               [(1, 1, 16, 11, 0), (1, 1, 8, 5, 0)]),
+    # 20 tokens through a chunk program 8 wide: 3 chunks, read once
+    "chunked": ([dict(prompt=list(range(1, 21)))], [(1, 1, 24, 20, 3)]),
+    # a burst of 3 in the bucket of 4 runs as 4 rows
+    "batch": ([dict(prompt=[3, 2, 9]), dict(prompt=[4, 2, 9]),
+               dict(prompt=[5, 2, 9])], [(3, 4, 4, 9, 0)]),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(_ADMISSION_CASES))
+def test_admission_durations_tile_and_counters_agree(lm, kind):
+    """One ``engine.admission`` span a device admission on every
+    RowCache path: its four durations tile [start, end] on the fake
+    clock, it counts what the program ran, a round's admissions fit in
+    its ``admit_s``, and the three counters say what the spans say."""
+    name = f"admission-{kind}"
+    eng, collector = _round_engine(lm, name, slots=4, admit_batch_max=4)
+    if kind == "chunked":
+        # the dense toy declares no chunk: give its manager one, as a
+        # model with ``prefill_chunk`` would (tests/test_dsa.py has one)
+        eng._kv._chunk_width = 8
+    submits, expected = _ADMISSION_CASES[kind]
+    reqs = []
+    for kw in submits:
+        kw = dict(kw)
+        reqs.append(eng.submit(kw.pop("prompt"), max_new=3, **kw))
+        if kind != "batch":              # one admission a round
+            assert eng.run_once(timeout=0.01)
+    while eng.run_once(timeout=0.01):
+        pass
+    assert all(len(r.result()) == 3 for r in reqs)
+    adms = _admissions(collector)
+    got = [(a.attrs["rows"], a.attrs["rows_padded"], a.attrs["width"],
+            a.attrs["prompt_tokens"], a.attrs.get("chunks", 0))
+           for a in adms]
+    assert got == expected
+    run_span_parent = {r.parent_id for r in _rounds(collector)}
+    for a in adms:
+        assert a.attrs["kind"] == kind and a.attrs["model"] == name
+        assert a.status == "OK" and {a.parent_id} == run_span_parent
+        assert sum(a.attrs[p] for p in _ADMIT_S) == a.end - a.start
+        assert min(a.attrs[p] for p in _ADMIT_S) > 0
+        assert a.attrs["scanned_tokens"] == \
+            a.attrs["rows_padded"] * a.attrs["width"]
+    rounds = {r.attrs["round"]: r for r in _rounds(collector)}
+    for n, r in rounds.items():
+        mine = [a for a in adms if a.attrs["round"] == n]
+        assert all(r.start < a.start and a.end < r.end for a in mine)
+        assert sum(a.end - a.start for a in mine) <= r.attrs["admit_s"]
+    assert {a.attrs["round"] for a in adms} <= set(rounds)
+    for p in _ADMIT_S:
+        assert _engine_counter("kftpu_engine_admit_seconds_total",
+                               model=name, phase=p[:-2]) == \
+            sum(a.attrs[p] for a in adms)
+    assert _engine_counter("kftpu_engine_admissions_total", model=name,
+                           kind=kind) == len(adms)
+    for what in ("prompt", "scanned"):
+        assert _engine_counter("kftpu_engine_prefill_tokens_total",
+                               model=name, what=what) == \
+            sum(a.attrs[f"{what}_tokens"] for a in adms)
+    # the request's own spans keep their names, parents and attributes
+    spans = collector.spans()
+    admits = [s for s in spans if s.name == "engine.admit"]
+    prefills = [s for s in spans if s.name == "engine.prefill"]
+    assert len(admits) == len(prefills) == len(reqs)
+    assert {p.parent_id for p in prefills} == {a.span_id for a in admits}
+    assert all(a.attrs["model"] == name for a in admits)
+
+
+def test_a_burst_of_three_counts_its_pad_row(lm):
+    """3 prompts of one bucket at ``admit_batch_max`` 4 run as ONE
+    program of 4 rows, each scanned at the bucket's width: the pad row
+    and the buckets' padding are both in ``scanned_tokens``."""
+    eng, collector = _round_engine(lm, "admission-burst", slots=4,
+                                   admit_batch_max=4)
+    for n in (5, 6, 7):                              # all in the bucket of 8
+        eng.submit(list(range(1, n + 1)), max_new=2)
+    while eng.run_once(timeout=0.01):
+        pass
+    (a,) = _admissions(collector)
+    assert (a.attrs["kind"], a.attrs["rows"], a.attrs["rows_padded"]) == \
+        ("batch", 3, 4)
+    assert a.attrs["width"] == 8 and a.attrs["scanned_tokens"] == 4 * 8
+    assert a.attrs["prompt_tokens"] == 5 + 6 + 7
+    # what the members' own spans can say: the buckets' padding alone
+    prefills = [s for s in collector.spans() if s.name == "engine.prefill"]
+    assert sum(p.attrs["bucket"] for p in prefills) == 3 * 8
+
+
+def test_a_failed_admission_is_recorded_and_leaves_the_host_leaf(lm):
+    """A prefill that raises fails its own request; its admission is
+    still one span (status ERROR, the four durations tiling it), and the
+    annotations still tile: the leaf it died in is closed, the round
+    goes on in ``engine.admit.host``."""
+    import contextlib
+
+    from kubeflow_tpu.obs import SpanCollector, Tracer
+
+    config, params = lm
+    clock = _Tick()
+    collector = SpanCollector()
+    open_now, names = [], []
+
+    @contextlib.contextmanager
+    def annotator(name):
+        assert not open_now, (name, open_now)       # never two at once
+        open_now.append(name)
+        names.append(name)
+        try:
+            yield
+        finally:
+            open_now.pop()
+
+    eng = DecodeEngine(config, params, slots=2, autostart=False,
+                       clock=clock, name="admission-fails",
+                       tracer=Tracer(collector=collector, clock=clock,
+                                     annotator=annotator))
+    real = eng._kv._prefill
+
+    def boom(*a, **kw):
+        raise RuntimeError("injected prefill failure")
+
+    eng._kv._prefill = boom
+    bad = eng.submit([5, 11, 17], max_new=3)
+    assert eng.run_once(timeout=0.01) is True
+    eng._kv._prefill = real
+    good = eng.submit([5, 11, 17], max_new=3)
+    while eng.run_once(timeout=0.01):
+        pass
+    with pytest.raises(RuntimeError, match="injected"):
+        bad.result()
+    assert len(good.result()) == 3
+    failed, ok = _admissions(collector)
+    assert failed.status == "ERROR: RuntimeError" and ok.status == "OK"
+    for a in (failed, ok):
+        assert sum(a.attrs[p] for p in _ADMIT_S) == a.end - a.start
+    assert failed.attrs["read_s"] == failed.attrs["insert_s"] == 0.0
+    assert not open_now
+    assert names[:3] == ["engine.admit.host", "engine.admit.launch",
+                         "engine.admit.host"]
+    # the failed request's own spans say so too
+    (admit,) = [s for s in collector.spans() if s.name == "engine.admit"
+                and s.status != "OK"]
+    assert admit.status == "ERROR: RuntimeError"
+
+
+def test_compiles_name_the_admission_that_built_a_program(lm):
+    """A prompt bucket that no earlier admission ran (a shape a warm-up
+    missed) shows as ``compiles`` >= 1 on ITS admission and round, and 0
+    on its neighbours of buckets already built."""
+    eng, collector = _round_engine(lm, "admission-compiles", slots=4,
+                                   admit_batch_max=0)
+    eng.submit([5, 11, 17], max_new=40)              # builds bucket 4
+    assert eng.run_once(timeout=0.01)
+    assert eng.run_once(timeout=0.01)                # a step of its own
+    for prompt in ([7, 2, 9], list(range(1, 14)), [9, 9, 2]):
+        eng.submit(prompt, max_new=2)                # 4, then 16, then 4
+        assert eng.run_once(timeout=0.01)
+    first, warm, cold, warm_again = _admissions(collector)
+    assert first.attrs["compiles"] >= 1              # prefill and insert
+    assert (warm.attrs["width"], warm.attrs["compiles"]) == (4, 0)
+    assert cold.attrs["width"] == 16 and cold.attrs["compiles"] >= 1
+    assert (warm_again.attrs["width"], warm_again.attrs["compiles"]) == (4, 0)
+    rounds = {r.attrs["round"]: r.attrs["compiles"]
+              for r in _rounds(collector)}
+    assert rounds[cold.attrs["round"]] == cold.attrs["compiles"]
+    assert rounds[warm.attrs["round"]] == rounds[
+        warm_again.attrs["round"]] == 0
+    assert rounds[0] >= first.attrs["compiles"] + 1  # + the step program
+
+
+def test_tokens_series_follows_the_integer_once_a_round(lm):
+    """``_emit`` counts a token on the engine's own integer; the series
+    ``kftpu_engine_tokens_total`` gets a round's tokens (first tokens
+    armed in admission included) when the round is recorded, so it reads
+    ``tokens_total`` at every round's end."""
+    name = "tokens-once-a-round"
+    eng, _collector = _round_engine(lm, name, slots=2, steps_per_sync=4)
+    eng.submit([5, 11, 17], max_new=9)
+    eng.submit([9, 2], max_new=1)                    # ends at its first token
+    seen = []
+    while eng.run_once(timeout=0.01):
+        seen.append(eng.tokens_total)
+        assert _engine_counter("kftpu_engine_tokens_total",
+                               model=name) == eng.tokens_total
+    assert seen[0] == 2 + 4 and seen[-1] == 10       # two first tokens + K
+
+
 # The benchmark finds the engine's compiled programs in a device trace by
 # the names of these private functions (``jit__step`` …):
 # benchmark/harness/readers.py:decode_step_s matches

@@ -621,11 +621,14 @@ def test_trace_collector_component_renders():
 
 
 def test_engine_round_phases_on_the_profiler_timeline(lm):
-    """With a recording annotator in the profiler bridge's place: every
-    round is ``engine.admit`` (the row path's admit and prefill nested
-    in it), ``engine.step``, ``engine.sync``, ``engine.emit`` in that
-    order, and no clock read of ``run_once`` falls outside one of them
-    except the round's own phase boundaries."""
+    """With a recording annotator in the profiler bridge's place: the
+    ``engine.*`` annotations TILE the engine thread, none ever nested in
+    another. A round is admission's leaves (``engine.admit.host``, and
+    inside a row admission ``.launch``, ``.insert``, ``.read`` in the
+    order that path runs them), then ``engine.step``, ``engine.sync``,
+    ``engine.emit``; no clock read of ``run_once`` falls outside one of
+    them except the round's own phase boundaries and the admission's,
+    each of which is read once, between two leaves."""
     import contextlib
 
     from kubeflow_tpu.serving.engine import DecodeEngine
@@ -662,12 +665,18 @@ def test_engine_round_phases_on_the_profiler_timeline(lm):
         assert eng.run_once(timeout=0.01)
     rounds = [s for s in collector.spans() if s.name == "engine.round"]
     assert len(rounds) == 3
-    top = [n for kind, n, d in events if kind == "B" and d == 0]
-    assert top == ["engine.admit", "engine.step", "engine.sync",
-                   "engine.emit"] * 3
-    nested = [n for kind, n, d in events if kind == "B" and d > 0]
-    assert nested == ["engine.admit", "engine.prefill"]   # round 0's row
-    assert depth[0] == 0
+    # the tiling: depth never above 0, every begin closed by its own end
+    # before the next begins
+    assert {d for _kind, _n, d in events} == {0} and depth[0] == 0
+    assert [k for k, _n, _d in events] == ["B", "E"] * (len(events) // 2)
+    assert all(b[1] == e[1] for b, e in zip(events[::2], events[1::2]))
+    assert all(n.startswith("engine.") for _k, n, _d in events)
+    opened = [n for kind, n, _d in events if kind == "B"]
+    tail = ["engine.step", "engine.sync", "engine.emit"]
+    assert opened == (
+        ["engine.admit.host", "engine.admit.launch", "engine.admit.insert",
+         "engine.admit.read", "engine.admit.host"] + tail
+        + (["engine.admit.host"] + tail) * 2)
     boundaries = set()
     for r in rounds:
         t = r.start
@@ -677,12 +686,23 @@ def test_engine_round_phases_on_the_profiler_timeline(lm):
             t += r.attrs[p]
             boundaries.add(t)
         assert t == r.end
-    outside = {t for t, d in reads if d == 0 and t > t_first}
-    assert outside == boundaries
+    # round 0's row admission: its four leaf changes are the only other
+    # clock reads between two annotations, and they are the boundaries of
+    # its four durations (host is two stretches: before the launch and
+    # after the read)
+    (adm,) = [s for s in collector.spans() if s.name == "engine.admission"]
+    outside = sorted(t for t, d in reads if d == 0 and t > t_first)
+    inner = [t for t in outside if t not in boundaries]
+    assert set(outside) - set(inner) == boundaries
+    assert len(inner) == 4 and adm.start < inner[0] and inner[-1] < adm.end
+    assert [b - a for a, b in zip(inner, inner[1:])] == [
+        adm.attrs["launch_s"], adm.attrs["insert_s"], adm.attrs["read_s"]]
+    assert (inner[0] - adm.start) + (adm.end - inner[-1]) == \
+        adm.attrs["host_s"]
     # an engine with nothing to step blocks on its queue under its own
     # name, before (not inside) the admission phase
     while eng.run_once(timeout=0.01):
         pass
-    top = [n for kind, n, d in events if kind == "B" and d == 0]
-    assert top[-2:] == ["engine.wait", "engine.admit"]
-    assert "engine.wait" not in top[:-2]
+    opened = [n for kind, n, _d in events if kind == "B"]
+    assert opened[-2:] == ["engine.wait", "engine.admit.host"]
+    assert "engine.wait" not in opened[:-2]
