@@ -277,9 +277,10 @@ def prefill_chunk(config: TransformerConfig, params, cache,
 def decode_step_stats(config, params, cache, token: jnp.ndarray):
     """One token in, one token's logits out; cache advances by one.
     Returns (logits, cache, stats): ``stats`` holds what the model's
-    routed layers counted in this step (``experts_hit`` and
-    ``routed_pairs``, one entry a routed layer) and is empty, adding
-    nothing to the program, for a model that has none."""
+    layers counted in this step (the routed ones' ``experts_hit`` and
+    ``routed_pairs``, one entry a routed layer; the step attention's
+    ``kv_blocks_read`` and ``kv_blocks_reserved``) and is empty, adding
+    nothing to the program, for a model that counts nothing."""
     model = _decode_model(config)
     logits, variables = model.apply(
         {"params": params, "cache": cache}, token[:, None],

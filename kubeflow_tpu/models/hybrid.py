@@ -64,8 +64,11 @@ from kubeflow_tpu.models.transformer import (
     RMSNorm,
     _merged_step_attention,
     _rotate,
+    _step_kernel,
     rope_tables,
+    step_block_counts,
 )
+from kubeflow_tpu.ops import step_attention
 from kubeflow_tpu.ops.attention import NEG_INF
 from kubeflow_tpu.ops.dsa import index_scores, select_bias, sparse_attend
 from kubeflow_tpu.ops.gmm import grouped_matmul
@@ -828,7 +831,9 @@ class GqaAttention(nn.Module):
     ``n_kv_heads`` KV heads, positions entering by the mask alone. It
     keeps the dense decoder's ``k`` / ``v`` leaves, KV heads merged on
     the last axis, and one token attends against them as they lie
-    (``transformer._merged_step_attention``)."""
+    (``transformer._step_kernel`` on the TPU, which reads a row only as
+    far as it has grown; ``transformer._merged_step_attention``
+    elsewhere)."""
 
     config: HybridConfig
 
@@ -892,7 +897,9 @@ class GqaAttention(nn.Module):
                 ck = cache["k"].at[index, rows, q_pos].set(k)
                 cv = cache["v"].at[index, rows, q_pos].set(v)
                 mask = jnp.arange(S)[None, None, :] <= q_pos[:, :, None]
-                if T == 1:
+                if T == 1 and step_attention.use_kernel():
+                    o = _step_kernel(c, q[:, 0], ck, cv, index, pos)
+                elif T == 1:
                     o = _merged_step_attention(q[:, 0], ck[index], cv[index],
                                                mask[:, 0])
                 else:
@@ -1091,6 +1098,11 @@ class HybridDecoder(nn.Module):
         lens = None
         if true_len is not None:
             lens = jnp.broadcast_to(jnp.asarray(true_len, jnp.int32), (B,))
+        if T == 1 and not fresh and c.n_gqa:
+            for name, n in step_block_counts(
+                    c, cache["positions"], c.n_kv_heads, c.head_dim,
+                    c.n_heads).items():
+                self.sow("moe_stats", name, n)
 
         embed = self.param("token_embed",
                            nn.initializers.normal(c.d_model ** -0.5),

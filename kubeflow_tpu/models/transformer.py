@@ -307,6 +307,46 @@ def _merged_step_attention(q, kp, vp, mask):
     return jnp.where(own, full.reshape(B, KH, G, KH, Dh), 0).sum(axis=3)
 
 
+def _step_kernel(c, q, ck, cv, layer, pos):
+    """One token a row against the stacked ``k`` / ``v`` leaves at
+    ``layer`` by ``ops/step_attention.py``'s kernel, which reads a row
+    only as far as it has grown, under ``shard_kernel`` (whole KV heads a
+    tp rank; nothing is exchanged). ``q`` ``(B, KH, G, Dh)``, ``pos``
+    ``(B,)`` (the step's token is written there); ``c`` has
+    ``max_seq_len``, ``dtype`` and ``rules``. The dense decoder and the
+    hybrid decoder's ``"gqa"`` blocks call it where
+    ``step_attention.use_kernel()`` says, and
+    :func:`_merged_step_attention` (its reference) elsewhere."""
+    from kubeflow_tpu.ops import step_attention as sa
+
+    B, KH, G, Dh = q.shape
+    rows, block = sa.tiling(B, c.max_seq_len, KH, Dh, KH * G, c.dtype)
+    grouped, stacked = (None, "heads", None, None), (None, None, None, "heads")
+    return shard_kernel(
+        "step_attention",
+        functools.partial(sa.step_attention, block_rows=rows,
+                          block_len=block),
+        (q, ck, cv, jnp.asarray(layer, jnp.int32), pos),
+        (grouped, stacked, stacked, (), (None,)), q.shape, grouped, c.rules)
+
+
+def step_block_counts(c, pos, n_kv_heads: int, head_dim: int,
+                      n_heads: int) -> dict:
+    """What a one-token step's attention reads of the dense K / V leaves,
+    in ``ops/step_attention.py``'s blocks, for the engine's round:
+    ``kv_blocks_read`` (the rows' live blocks) and ``kv_blocks_reserved``
+    (rows x blocks a row), each for ONE attention layer (every layer of
+    the step reads the same). Empty where the step runs no kernel."""
+    from kubeflow_tpu.ops import step_attention as sa
+
+    if not sa.use_kernel():
+        return {}
+    _, block = sa.tiling(pos.shape[0], c.max_seq_len, n_kv_heads, head_dim,
+                         n_heads, c.dtype)
+    read, reserved = sa.block_counts(pos, c.max_seq_len, block)
+    return {"kv_blocks_read": read, "kv_blocks_reserved": reserved}
+
+
 class Attention(nn.Module):
     config: TransformerConfig
     decode: bool = False
@@ -396,10 +436,11 @@ class Attention(nn.Module):
         ``k`` / ``v`` hold a position's KV heads MERGED on the last axis
         (``(L, B, Smax, KH·Dh)``, ``TransformerConfig.cache_leaves``),
         and every form writes its tokens as such rows. The one-token
-        step attends against the merged slice as it lies
-        (:func:`_merged_step_attention`): un-merging it would re-lay a
-        whole layer's K and V out on every step at a head size under 128
-        lanes. The multi-token forms, whose FLOPs are not hidden under
+        step attends against the merged rows as they lie (on the TPU
+        :func:`_step_kernel`, which reads each row only as far as it has
+        grown; elsewhere :func:`_merged_step_attention` over the layer's
+        slice): un-merging them would re-lay a whole layer's K and V out
+        on every step at a head size under 128 lanes. The multi-token forms, whose FLOPs are not hidden under
         the cache's bytes, attend against the ``(B, Smax, KH, Dh)`` view.
         Returns ``(output, cache)``.
         """
@@ -460,6 +501,13 @@ class Attention(nn.Module):
                      positions=jax.lax.dynamic_update_index_in_dim(
                          cache["positions"], pos + S, layer, 0))
 
+        from kubeflow_tpu.ops import step_attention
+
+        if S == 1 and step_attention.use_kernel():
+            H = q.shape[2]
+            out = _step_kernel(c, q.reshape(B, KH, H // KH, Dh), ck, cv,
+                               layer, pos)
+            return out.reshape(B, 1, H, Dh), cache
         kp, vp = _layer_slice(ck, layer), _layer_slice(cv, layer)
         # (B or 1, S, Smax): per-row causal bound
         mask = jnp.arange(Smax)[None, None, :] <= q_pos[:, :, None]
@@ -864,6 +912,11 @@ class Transformer(nn.Module):
             if self.decode:
                 leaves = _declare_cache(self, c, B, (c.n_layers,))
                 cache = {name: var.value for name, var in leaves.items()}
+                if S == 1 and not c.kv_page_size:
+                    for name, n in step_block_counts(
+                            c, cache["positions"][0], c.n_kv_heads,
+                            c.head_dim, c.n_heads).items():
+                        self.sow("moe_stats", name, n)
                 (x, cache), _ = scan(Block, in_axes=(nn.broadcast, 0))(
                     c, decode=True, name="blocks")(
                         (x, cache), (sin, cos), jnp.arange(c.n_layers))

@@ -221,6 +221,39 @@ def _gmm_axis_tiles(size: int) -> List[int]:
                      if size % t == 0]
 
 
+# The one-token step attention (``ops/step_attention.py``) has no table
+# rows either; the rule below gave the best of the chip sweep's tilings
+# at both served shapes with every row live, and one within 5 % of the
+# best with two rows of eight live (PERF.md §6). Its block of
+# positions: the fewest positions whose K of one row reaches this many
+# bytes (a copy of a block is one DMA a row), and never under one lane
+# tile (a block's positions are the lane axis of its scores and the
+# contraction of its values' product: 64 ran 3 % slower than 128, 32 a
+# third slower)
+STEP_BLOCK_BYTES = 256 * 1024
+# its rows a program: as many as divide the rows and fit this much VMEM
+# (8 or 16 a program read within 2 % of each other), and no more than
+# this many: a row holds four DMA semaphores, which live in 2 KiB of
+# scoped semaphore memory on a v5e (128 rows a program ran out of it)
+STEP_VMEM_BYTES = 32 * 1024 * 1024
+STEP_MAX_ROWS = 64
+
+
+def step_vmem_bytes(block_rows: int, block_len: int, lanes: int,
+                    n_heads: int, head_dim: int, dtype_bytes: int) -> int:
+    """VMEM the step attention holds: K and V blocks in two slots, the
+    float32 accumulators, the query and output blocks in their two
+    pipeline buffers and the selection matrices, every last axis padded
+    to whole 128-lane tiles as VMEM lays it out."""
+    f32 = 4
+    pad = lambda x: -(-x // LANE_MULTIPLE) * LANE_MULTIPLE  # noqa: E731
+    kv = 2 * 2 * block_rows * block_len * pad(lanes) * dtype_bytes
+    scratch = block_rows * n_heads * (pad(lanes) + 2 * LANE_MULTIPLE) * f32
+    io = 2 * block_rows * n_heads * (pad(lanes) + pad(head_dim)) * dtype_bytes
+    consts = 2 * (n_heads * pad(lanes) + lanes * pad(head_dim)) * dtype_bytes
+    return kv + scratch + io + consts
+
+
 # ---------------------------------------------------------------------------
 # Table entries: schema, validation, matching
 # ---------------------------------------------------------------------------
@@ -517,13 +550,14 @@ class TileConfig:
     block_k: int = 0
     head_block: int = 0
     source: str = "fallback"
-    tiling: Tuple[int, ...] = ()      # gmm: (tm, tk, tn)
+    tiling: Tuple[int, ...] = ()      # gmm: (tm, tk, tn); step_attn:
+    #                                   (block_rows, block_len)
 
     def as_dict(self) -> Dict[str, Any]:
         d: Dict[str, Any] = {"kernel": self.kernel, "source": self.source}
         if self.kernel == "paged_attn":
             d["head_block"] = self.head_block
-        elif self.kernel == "gmm":
+        elif self.kernel in ("gmm", "step_attn"):
             d["tiling"] = list(self.tiling)
         else:
             d["block_q"] = self.block_q
@@ -691,3 +725,37 @@ def resolve_gmm(*, m: int, k: int, n: int, dtype: Any,
     floor = SUBLANE_FLOOR.get(name, SUBLANE_FLOOR_STRICTEST)
     return _record(TileConfig("gmm", tiling=(min(tm, -(-m // floor) * floor),
                                              tk, tn)), shape)
+
+
+def resolve_step_attention(*, rows: int, max_seq_len: int, lanes: int,
+                           n_heads: int, head_dim: int, dtype: Any,
+                           tiling: Optional[Tuple[int, int]] = None
+                           ) -> TileConfig:
+    """Resolve the step attention's ``(block_rows, block_len)`` for
+    ``rows`` rows of ``max_seq_len`` positions of ``lanes`` lanes: an
+    explicit ``tiling`` untouched, else the rule. ``block_len``: the
+    fewest positions, a power of two from one lane tile, whose K of one
+    row holds :data:`STEP_BLOCK_BYTES` (fitted to divide
+    ``max_seq_len``). ``block_rows``: the most rows, up to
+    :data:`STEP_MAX_ROWS`, that divide ``rows`` and keep
+    :func:`step_vmem_bytes` within :data:`STEP_VMEM_BYTES`.
+    Neither depends on what the rows hold: a row reads its own blocks
+    alone, so more rows a program cost VMEM and save programs."""
+    name = dtype_name(dtype)
+    shape = {"rows": rows, "max_seq_len": max_seq_len, "lanes": lanes,
+             "n_heads": n_heads, "head_dim": head_dim, "dtype": name}
+    if tiling is not None:
+        return _record(TileConfig("step_attn", tiling=tuple(map(int, tiling)),
+                                  source="override"), shape)
+    nbytes = DTYPE_BYTES.get(name, 4)
+    block_len = LANE_MULTIPLE
+    while (block_len < max_seq_len
+           and block_len * lanes * nbytes < STEP_BLOCK_BYTES):
+        block_len *= 2
+    block_len = fit_block(max_seq_len, block_len)
+    block_rows = max([1] + [
+        r for r in range(1, min(rows, STEP_MAX_ROWS) + 1) if rows % r == 0
+        and step_vmem_bytes(r, block_len, lanes, n_heads, head_dim,
+                            nbytes) <= STEP_VMEM_BYTES])
+    return _record(TileConfig("step_attn", tiling=(block_rows, block_len)),
+                   shape)

@@ -139,10 +139,21 @@ _dsa_selected_c = DEFAULT_REGISTRY.counter(
     "kftpu_dsa_selected_total",
     "cached positions sparse attention kept and read, summed likewise: "
     "over kftpu_dsa_scored_total, how sparse the traffic made the layer")
+_step_attn_blocks_c = DEFAULT_REGISTRY.counter(
+    "kftpu_step_attention_blocks_total",
+    "blocks of cached K/V positions the one-token step attention read "
+    "(what=read: each row's blocks through its position, none for an idle "
+    "row) and held (what=reserved: rows x blocks a row), for one attention "
+    "layer, summed over every decode step")
 # what a model's layers count in a decode step -> the counter it feeds
-_STEP_COUNTERS = {"experts_hit": _moe_hit_c, "routed_pairs": _moe_pairs_c,
-                  "index_scored": _dsa_scored_c,
-                  "index_selected": _dsa_selected_c}
+# and that counter's labels
+_STEP_COUNTERS = {"experts_hit": (_moe_hit_c, {}),
+                  "routed_pairs": (_moe_pairs_c, {}),
+                  "index_scored": (_dsa_scored_c, {}),
+                  "index_selected": (_dsa_selected_c, {}),
+                  "kv_blocks_read": (_step_attn_blocks_c, {"what": "read"}),
+                  "kv_blocks_reserved": (_step_attn_blocks_c,
+                                         {"what": "reserved"})}
 
 _END = object()  # per-request stream sentinel
 
@@ -502,8 +513,9 @@ class DecodeEngine:
         def _step(params, cache, tokens, seeds, step_idx, temps, top_k,
                   top_p):
             """K decode steps under one jit; returns (cache, (K, B)
-            tokens, stats): what a model's routed layers counted,
-            ``experts_hit`` and ``routed_pairs``, (K, L_moe) each; an
+            tokens, stats): what a model's layers counted, e.g. the
+            routed ones' ``experts_hit`` and ``routed_pairs``, (K, L_moe)
+            each, or the step attention's ``kv_blocks_read``, (K,); an
             empty dict, no output of the program, for a model with
             none."""
 
@@ -936,10 +948,12 @@ class DecodeEngine:
         ``kftpu_engine_round_seconds_total{phase}``. ``moe`` is what a
         model's layers counted over the round's steps (the routed ones
         ``experts_hit`` and ``routed_pairs``, the sparse-attention ones
-        ``index_scored`` and ``index_selected``). ``compiles`` is the
-        programs the process built or loaded while the round ran, and
-        the tokens the round emitted (first tokens armed in admission
-        included) reach ``kftpu_engine_tokens_total`` here, once."""
+        ``index_scored`` and ``index_selected``, the step attention over
+        dense K / V ``kv_blocks_read`` and ``kv_blocks_reserved``).
+        ``compiles`` is the programs the process built or loaded while
+        the round ran, and the tokens the round emitted (first tokens
+        armed in admission included) reach ``kftpu_engine_tokens_total``
+        here, once."""
         bounds = marks + [self.clock()]
         secs = dict.fromkeys(_ROUND_PHASES, 0.0)
         for phase, t_a, t_b in zip(_ROUND_PHASES[1:], bounds, bounds[1:]):
@@ -958,7 +972,8 @@ class DecodeEngine:
             _round_seconds.inc(sec, model=self.name, phase=phase)
         for counted, total in (moe or {}).items():
             attrs[counted] = total
-            _STEP_COUNTERS[counted].inc(total, model=self.name)
+            counter, labels = _STEP_COUNTERS[counted]
+            counter.inc(total, model=self.name, **labels)
         self.tracer.record("engine.round", start=bounds[0],
                            end=bounds[-1], parent=self._run_ctx,
                            attrs=attrs)

@@ -13,6 +13,7 @@ One JSON line per point for PERF.md.
     python scripts/tile_sweep.py --update-table        # merge winners
     python scripts/tile_sweep.py --paged               # head-group sweep
     python scripts/tile_sweep.py --gmm                 # grouped matmul only
+    python scripts/tile_sweep.py --step-attention      # decode step only
     python scripts/tile_sweep.py --validate            # no chip needed
 
 ``--validate`` is the preflight stage: strict table legality
@@ -273,6 +274,129 @@ def sweep_gmm(args) -> dict:
     return {"generation": gen, "points": points}
 
 
+# the one-token step attention of the served dense caches: (name, rows,
+# max_seq_len, KV heads, query heads a KV head, head size, stacked
+# layers, (median prompt, prompt range), (median output, output range))
+# as the cells' traffic mixes draw them; a row stands at a uniform point
+# of its output
+STEP_SHAPES = [
+    ("smollm2-1.7b", 8, 1024, 32, 1, 64, 24, (192, 32, 768), (96, 8, 256)),
+    ("nemotron-3-nano", 128, 8192, 2, 16, 128, 2, (512, 64, 2048),
+     (1024, 128, 4096)),
+]
+STEP_BLOCKS = (16, 32, 64, 128, 256, 512, 1024, 2048)
+# the points tried: one row's K block of 128 KiB to 1 MiB (a copy is one
+# DMA a row), and from a sixteenth of the rows to all of them a program
+STEP_SWEEP_BYTES = (128 * 1024, 1024 * 1024)
+STEP_REPS = 4
+
+
+def _step_positions(rows, smax, prompt, output, live, seed=0):
+    """Row positions as a cell's mix leaves them mid-run: a lognormal
+    prompt and output (median and range as the mix gives them, sigma
+    0.6), the row a uniform share through its output; the rows past
+    ``live`` idle (``smax``)."""
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+
+    def draw(median, lo, hi):
+        return np.clip(np.exp(rng.normal(np.log(median), 0.6, rows)), lo, hi)
+
+    pos = draw(*prompt) + rng.uniform(0, 1, rows) * draw(*output)
+    pos = np.minimum(pos.astype(np.int64), smax - 1)
+    pos[live:] = smax
+    return pos.astype(np.int32)
+
+
+def sweep_step_attention(args) -> dict:
+    """The step attention (``ops/step_attention.py``) at the served
+    shapes, rows per program x positions per block, beside the XLA
+    merged-lane products it replaces (``_merged_step_attention`` over
+    the layer's slice), on the same operands: every row live, and
+    (SmolLM2) two rows of eight as chat-steady keeps them. A program
+    runs the call once a stacked layer, ``STEP_REPS`` times over, the
+    layer a traced index as in the decoder's scan; the best of three
+    counts. One JSON line a point: ``call_ms``, the live K and V bytes
+    (``live_mb``), what the blocks round them to (``read_mb``), and
+    ``share``, the live bytes over 819 GB/s over the time."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from kubeflow_tpu.models.transformer import _merged_step_attention
+    from kubeflow_tpu.ops import autotune
+    from kubeflow_tpu.ops import step_attention as sa
+
+    gen, points = autotune.backend_generation(), []
+    for (name, B, S, KH, G, Dh, L, prompt, output) in STEP_SHAPES:
+        lanes = KH * Dh
+        kk, kv, kq = jax.random.split(jax.random.PRNGKey(0), 3)
+        k = jax.random.normal(kk, (L, B, S, lanes), jnp.bfloat16)
+        v = jax.random.normal(kv, (L, B, S, lanes), jnp.bfloat16)
+        q = jax.random.normal(kq, (B, KH, G, Dh), jnp.bfloat16)
+        rule = sa.tiling(B, S, KH, Dh, KH * G, jnp.bfloat16)
+        regimes = [("full", B)] + ([("chat", 2)] if B <= 8 else [])
+
+        def timed(fn, q, k, v, pos):
+            # operands are arguments (closed over, the stack would be a
+            # constant of the program)
+            @jax.jit
+            def chain(q, k, v, pos):
+                def body(q, i):
+                    o = fn(q, k, v, i % L, pos)
+                    return q + (o * 0).astype(q.dtype), None
+                return jax.lax.scan(body, q, jnp.arange(L * STEP_REPS))[0]
+            return _time_best(lambda: chain(q, k, v, pos)) / (L * STEP_REPS)
+
+        def xla(q, k, v, layer, pos):
+            mask = jnp.arange(S)[None, :] <= pos[:, None]
+            return _merged_step_attention(q, k[layer], v[layer], mask)
+
+        for regime, live in regimes:
+            pos_np = _step_positions(B, S, prompt, output, live)
+            pos = jnp.asarray(pos_np)
+            live_b = int(np.sum(np.where(pos_np < S, pos_np + 1, 0)))
+            per_pos = 2 * lanes * 2          # K and V, bf16
+            base = {"step_attention": True, "shape": name, "rows": B,
+                    "max_seq_len": S, "lanes": lanes, "regime": regime,
+                    "live_rows": live, "rule": list(rule),
+                    "live_mb": round(live_b * per_pos / 1e6, 3),
+                    "generation": gen}
+            least = live_b * per_pos / 819e9 * 1e3
+            lo, hi = STEP_SWEEP_BYTES
+            tiles = [(br, bl) for br in range(max(1, B // 16), B + 1)
+                     for bl in STEP_BLOCKS
+                     if B % br == 0 and S % bl == 0 and (br, bl) != rule
+                     and (br & (br - 1)) == 0 and lo <= bl * lanes * 2 <= hi]
+            tried = [("xla", None)] + [("kernel", t) for t in tiles + [rule]]
+            for impl, tiles in tried:
+                point = dict(base, impl=impl, tiling=tiles)
+                if impl == "kernel":
+                    br, bl = tiles
+                    vm = autotune.step_vmem_bytes(br, bl, lanes, KH * G, Dh, 2)
+                    if vm > 100 * 2 ** 20:
+                        continue
+                    point["read_mb"] = round(int(np.sum(
+                        sa.live_blocks(pos_np, S, bl))) * bl * per_pos / 1e6,
+                        3)
+                    fn = (lambda q, k, v, i, p, br=br, bl=bl:
+                          sa.step_attention(q, k, v, i, p, block_rows=br,
+                                            block_len=bl))
+                else:
+                    point["read_mb"] = round(B * S * per_pos / 1e6, 3)
+                    fn = xla
+                try:
+                    ms = timed(fn, q, k, v, pos)
+                    point.update(call_ms=round(ms, 4),
+                                 share=round(100 * least / ms, 1))
+                    points.append(point)
+                except Exception as e:  # noqa: BLE001 — skip-on-failure
+                    point["skip"] = f"{type(e).__name__}: {e}"[:300]
+                print(json.dumps(point), flush=True)
+    return {"generation": gen, "points": points}
+
+
 def update_table(result: dict, paged_result: dict, path: str) -> None:
     """Merge sweep winners into the committed table: one entry per
     (kernel key, shape class), fwd winner → flash_fwd, fwd+bwd winner →
@@ -457,6 +581,11 @@ def main() -> None:
                    help="sweep the grouped matmul's tiles beside "
                         "ragged_dot and megablox, and nothing else (it "
                         "has no table rows: ops/autotune.py's rule)")
+    p.add_argument("--step-attention", action="store_true",
+                   help="sweep the step attention's rows per program x "
+                        "positions per block at the served shapes beside "
+                        "the XLA products, and nothing else (no table "
+                        "rows: ops/autotune.py's rule)")
     p.add_argument("--out", default=None, help="write the JSON artifact")
     p.add_argument("--update-table", action="store_true",
                    help="merge measured winners into the table")
@@ -468,8 +597,9 @@ def main() -> None:
     if args.validate:
         sys.exit(validate(table_path))
 
-    if args.gmm:
-        artifact = {"gmm": sweep_gmm(args)}
+    if args.gmm or args.step_attention:
+        artifact = ({"gmm": sweep_gmm(args)} if args.gmm else
+                    {"step_attention": sweep_step_attention(args)})
         if args.out:
             with open(args.out, "w", encoding="utf-8") as f:
                 json.dump(artifact, f, indent=2)

@@ -133,9 +133,15 @@ def _cache_sized_results(text: str, leaf):
     return relayouts[False], relayouts[True], slices
 
 
+def _step_attention_calls(text):
+    """The result shapes of ``ops/step_attention.py``'s calls."""
+    return re.findall(
+        r"%step_attention[\w.]* = (\w+\[[\d,]*\])\S* custom-call\(", text)
+
+
 @pytest.mark.parametrize("n_heads", [32, 16], ids=["head64", "head128"])
 def test_k_step_program_relays_no_stack_and_no_layer_slice(
-        one_chip, no_cache, n_heads):
+        one_chip, no_cache, monkeypatch, n_heads):
     """On the TPU's own compiler, the cache in the chip's default layout
     and nothing pinned: the K-step program copies or transposes neither
     the stacked K/V nor one layer's slice of it, inside its loops or at
@@ -148,12 +154,20 @@ def test_k_step_program_relays_no_stack_and_no_layer_slice(
     declared shape is the lever (``TransformerConfig.cache_leaves``: KV
     heads merged on the last axis), since a layout pinned through
     ``jax.jit`` does not survive the persistent compile cache (jax
-    0.9.0). Head size 128 at the same width is the same leaf."""
+    0.9.0). Head size 128 at the same width is the same leaf. The step
+    attention kernel reads the stack where it lies (one call in the
+    layer loop's body, the layer by scalar prefetch): no copy of one
+    layer's K or V slice stands anywhere in the program."""
+    _compile_kernels(monkeypatch)
     text, leaf = _k_step_text(one_chip, n_heads)
     assert leaf.shape == (GEOMETRY["n_layers"], SLOTS,
                           GEOMETRY["max_seq_len"], GEOMETRY["d_model"])
     in_loops, at_the_boundary, slices = _cache_sized_results(text, leaf)
     assert (in_loops, at_the_boundary, slices) == ([], [], [])
+    one_layer = "bf16[%d,%d,%d]" % leaf.shape[1:]
+    assert not re.findall(rf"= {re.escape(one_layer)}\S* copy\(", text)
+    assert _step_attention_calls(text) == [
+        "bf16[%d,%d,%d]" % (SLOTS, n_heads, GEOMETRY["d_model"] // n_heads)]
 
 
 def _grouped_products(text, cfg):
@@ -172,12 +186,13 @@ def _grouped_products(text, cfg):
 
 def _compile_kernels(monkeypatch, *modules):
     """This process's backend is the CPU: compile the kernels themselves,
-    not their interpreter."""
-    from kubeflow_tpu.ops import gmm
+    not their interpreter, and take the paths a chip takes."""
+    from kubeflow_tpu.ops import gmm, step_attention
 
-    for module in modules + (gmm,):
+    for module in modules + (gmm, step_attention):
         monkeypatch.setattr(module, "resolve_interpret",
                             lambda interpret: False)
+    monkeypatch.setattr(step_attention, "use_kernel", lambda: True)
 
 
 # The hybrid decoder (kubeflow_tpu/models/hybrid.py) at Ling-3.0-flash's
@@ -380,8 +395,10 @@ def test_state_space_programs_compile_with_the_step_kernel_in_place(
     2048 tokens, on the TPU's own compiler: Mosaic takes the ``ssm.step``
     kernel at the published shapes (a row's 2 MB of state in, 2 MB out),
     the float32 state is written by nothing else, the step copies no
-    stacked cache leaf whole (state, conv tail, K, V), and the prefill's
-    temporaries fit beside the resident weights and cache."""
+    stacked cache leaf whole (state, conv tail, K, V) nor one block's K or
+    V slice (the step attention kernel reads the stack where it lies),
+    and the prefill's temporaries fit beside the resident weights and
+    cache."""
     import jax
     import jax.numpy as jnp
 
@@ -440,6 +457,8 @@ def test_state_space_programs_compile_with_the_step_kernel_in_place(
     for name in ("ssm_state", "k", "v"):
         assert not re.findall(rf"= {re.escape(shapes[name])}\S* copy\(",
                               text), name
+    assert not re.findall(r"= bf16\[128,8192,256\]\S* copy\(", text)
+    assert _step_attention_calls(text) == ["bf16[128,32,128]"] * cfg.n_gqa
     # an expert is stored at the published 1856 columns and no expert
     # tensor is re-laid: the chip lays ``up_proj`` out column-major
     # (2688 along the lanes), under ``ragged_dot`` that was a copy of
