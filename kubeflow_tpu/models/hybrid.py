@@ -1,13 +1,14 @@
 """A decoder whose layers differ: linear-attention (KDA), latent
-attention (MLA) and sparse latent attention (DSA: MLA over the positions
-a learned indexer keeps) mixers by a per-layer pattern, a dense SwiGLU
-MLP in the leading layers and a sigmoid-routed expert MLP with a shared
-expert after them, an untied output head. Served through the same
+attention (MLA), sparse latent attention (DSA: MLA over the positions a
+learned indexer keeps) and rotary GQA, global or over a sliding window,
+mixers by a per-layer pattern, a dense SwiGLU MLP in the leading layers
+and a sigmoid-routed expert MLP (with or without a shared expert) after
+them, an untied output head. Served through the same
 ``models/decode.py`` / ``DecodeEngine`` path as :class:`Transformer`.
 
 ``layer_types`` says, entry by entry, what a layer is. ``"kda"``,
-``"mla"`` and ``"dsa"`` are a mixer and then an MLP, each under its own
-norm and residual. ``"ssm"`` (a Mamba-2 state-space mixer), ``"gqa"``
+``"mla"``, ``"dsa"``, ``"attn"`` and ``"swa"`` are a mixer and then an
+MLP, each under its own norm and residual. ``"ssm"`` (a Mamba-2 state-space mixer), ``"gqa"``
 (grouped-query attention with no rotary embedding) and ``"moe"`` (the
 routed MLP) are blocks of ONE sublayer under one norm and one residual.
 
@@ -34,15 +35,19 @@ and idle value from there, never from its rank):
     k, v          (L_gqa, B, S_max, KH Dh)   a token's keys / values, the
                                              KV heads merged on the last
                                              axis (the dense decoder's leaf)
+                  (L_attn, B, S_max, KH Dh / KH Dv)  the same for "attn"
+                                             layers, V of its own width
+    swa_k, swa_v  (L_swa, B, window, KH Dh / KH Dv)  a "swa" layer's ring:
+                                             position p at slot p % window
 
 (a leaf exists only where some layer needs it). A DSA layer reads
 ``index_k`` as far as the row has grown and attends, of the ``latent``'s
 positions, to the ``index_topk`` it selects for each query.
 
-A recurrent state cannot be pulled back the way ``positions`` can, so
-``true_len`` reaches the mixers: past a row's own length a multi-token
-apply leaves ``kda_state`` / ``ssm_state`` and the conv tails as they
-were.
+A recurrent state cannot be pulled back the way ``positions`` can, nor
+can a ring that has wrapped, so ``true_len`` reaches the mixers: past a
+row's own length a multi-token apply leaves ``kda_state`` /
+``ssm_state``, the conv tails and the rings' slots as they were.
 
 The layers are few and unlike, so they are unrolled, not scanned; each
 reads and writes its own ``[index]`` slice of the stacked leaves, which
@@ -83,9 +88,10 @@ from kubeflow_tpu.parallel.mesh import DEFAULT_RULES, AxisRules
 
 HIGHEST = jax.lax.Precision.HIGHEST
 L2_EPS = 1e-6
-MIXERS = ("kda", "mla", "dsa")       # a layer: this mixer, then an MLP
+MIXERS = ("kda", "mla", "dsa", "attn", "swa")  # a layer: mixer, then MLP
 SUBLAYERS = ("ssm", "gqa", "moe")    # a block: this one sublayer
 GQA_Q_BLOCK = 512    # query rows a fresh prefill attends at once
+ATTN_KEY_BLOCK = 512  # cached positions an "attn" prefill attends at once
 
 
 @dataclasses.dataclass(frozen=True)
@@ -124,8 +130,23 @@ class HybridConfig:
     # a prompt longer than this is admitted by repeating ONE program of
     # this many tokens against the row (0: one program a prompt bucket)
     prefill_chunk: int = 0
-    # "gqa" blocks: n_heads query heads of head_dim over this many KV heads
+    # "gqa" blocks and "attn" layers: n_heads query heads of head_dim over
+    # this many KV heads
     n_kv_heads: int = 0
+    # "attn" (global) and "swa" (window) layers: rotary GQA whose value
+    # heads are ``v_head_dim`` wide, the first ``rotary_dim`` dims of a
+    # q / k head rotated (rotate-half) at ``rope_theta`` ("attn") or
+    # ``swa_rope_theta`` ("swa"), the output times ``value_scale``. A
+    # window layer has ``swa_kv_heads`` KV heads, attends to the last
+    # ``window`` positions (its own included), caches them in a ring of
+    # ``window`` positions a row, and with ``attn_sinks`` one learned
+    # logit a head takes probability mass and gives no value
+    rotary_dim: int = 0
+    swa_kv_heads: int = 0
+    swa_rope_theta: float = 10000.0
+    window: int = 0
+    attn_sinks: bool = False
+    value_scale: float = 1.0
     # "ssm" blocks: H heads of P, a state of N a head, B / C a group
     ssm_heads: int = 0
     ssm_head_dim: int = 0
@@ -201,6 +222,14 @@ class HybridConfig:
         return self.layer_types.count("gqa")
 
     @property
+    def n_attn(self) -> int:
+        return self.layer_types.count("attn")
+
+    @property
+    def n_swa(self) -> int:
+        return self.layer_types.count("swa")
+
+    @property
     def ssm_inner(self) -> int:
         return self.ssm_heads * self.ssm_head_dim
 
@@ -242,10 +271,13 @@ class HybridConfig:
     @property
     def has_recurrent_state(self) -> bool:
         """A per-row state that no position indexes (KDA's, a
-        state-space block's): such a cache cannot be paged, sliced at a
-        prefix or rolled back, so the paths that do those refuse the
-        model, and a multi-token apply is given each row's length."""
-        return any(name.endswith("_state") for name in self.cache_leaves(1))
+        state-space block's), or a window layer's ring, whose slots
+        positions overwrite as the row grows: such a cache cannot be
+        paged, sliced at a prefix or rolled back, so the paths that do
+        those refuse the model, and a multi-token apply is given each
+        row's length (pad tokens must not reach the state or the ring)."""
+        return self.n_swa > 0 or any(name.endswith("_state")
+                                     for name in self.cache_leaves(1))
 
     def decoder(self) -> "HybridDecoder":
         """The decode-mode module that ``models/decode.py`` applies."""
@@ -281,6 +313,19 @@ class HybridConfig:
             kv = CacheLeaf((self.n_gqa, batch, S, self.n_kv_heads * dk),
                            self.dtype, 1, heads_axis=3, head_width=dk)
             leaves["k"] = leaves["v"] = kv
+        dv = self.v_head_dim
+        for n, kh, length, k_name, v_name in (
+                (self.n_attn, self.n_kv_heads, S, "k", "v"),
+                (self.n_swa, self.swa_kv_heads, self.window, "swa_k",
+                 "swa_v")):
+            if n:
+                # a window layer's ring: position p at slot p % window
+                leaves[k_name] = CacheLeaf((n, batch, length, kh * dk),
+                                           self.dtype, 1, heads_axis=3,
+                                           head_width=dk)
+                leaves[v_name] = CacheLeaf((n, batch, length, kh * dv),
+                                           self.dtype, 1, heads_axis=3,
+                                           head_width=dv)
         return leaves
 
     def validate(self) -> None:
@@ -290,6 +335,23 @@ class HybridConfig:
                            or self.n_heads % self.n_kv_heads):
             raise ValueError("a gqa block needs n_kv_heads that divide "
                              "n_heads")
+        if self.n_attn and self.n_gqa:
+            raise ValueError("attn layers and gqa blocks would share the "
+                             "k / v leaves")
+        for n, kh in ((self.n_attn, self.n_kv_heads),
+                      (self.n_swa, self.swa_kv_heads)):
+            if n and (kh < 1 or self.n_heads % kh):
+                raise ValueError("attn / swa layers need KV heads that "
+                                 "divide n_heads")
+        if (self.n_attn or self.n_swa) and not (
+                0 <= self.rotary_dim <= self.head_dim
+                and self.rotary_dim % 2 == 0 and self.v_head_dim > 0):
+            raise ValueError("attn / swa layers need an even rotary_dim "
+                             "within head_dim and a v_head_dim")
+        if self.n_swa and not 0 < self.window <= self.max_seq_len:
+            raise ValueError("a swa layer needs 0 < window <= max_seq_len")
+        if (self.window or self.attn_sinks) and not self.n_swa:
+            raise ValueError("window and attn_sinks belong to swa layers")
         if self.n_ssm and (min(self.ssm_heads, self.ssm_head_dim,
                                self.ssm_state, self.ssm_groups) < 1
                            or self.ssm_heads % self.ssm_groups):
@@ -910,6 +972,245 @@ class GqaAttention(nn.Module):
         return out.astype(c.dtype), dict(cache, k=ck, v=cv)
 
 
+# -- rotary GQA: global and window layers ------------------------------------------
+
+def _softmax_pv(s, v, sinks, dtype):
+    """Probabilities of the scores ``s`` (B, KH, R, Q, K) float32, masked
+    to NEG_INF, times ``v`` (B, K, KH, Dv) -> (B, Q, KH, R, Dv) float32.
+    ``sinks`` (KH, R) or None: a logit a head in the denominator that
+    gives no value."""
+    if sinks is None:
+        p = jax.nn.softmax(s, axis=-1)
+    else:
+        b = sinks[None, :, :, None, None]
+        m = jnp.maximum(jnp.max(s, axis=-1, keepdims=True), b)
+        e = jnp.exp(s - m)
+        p = e / (jnp.exp(b - m) + jnp.sum(e, axis=-1, keepdims=True))
+    return jnp.einsum("bhrqk,bkhd->bqhrd", p.astype(dtype), v,
+                      preferred_element_type=jnp.float32)
+
+
+def _scores(q, k, scale):
+    """q (B, Q, KH, R, Dh) . k (B, K, KH, Dh) -> (B, KH, R, Q, K) f32."""
+    return jnp.einsum("bqhrd,bkhd->bhrqk", q, k,
+                      preferred_element_type=jnp.float32) * scale
+
+
+def ring_slots(pos, window: int):
+    """(B, window): the position each ring slot of a row holds once the
+    row has ``pos`` positions, oldest first (negative: none yet); slot
+    ``p % window`` holds position p."""
+    return pos[:, None] - window + jnp.arange(window)[None, :]
+
+
+def ring_update(ring, new, start, end, window: int):
+    """A ring ``(B, window, lanes)`` after a row's positions [start, end)
+    (``new`` (B, T, lanes) holds them from ``start`` on, pad past
+    ``end``): each slot takes the last real position that falls on it,
+    and keeps what it held where none of them does."""
+    s = jnp.arange(window)[None, :]
+    last = end[:, None] - 1 - (end[:, None] - 1 - s) % window     # (B, W)
+    fresh = last >= start[:, None]
+    at = jnp.clip(last - start[:, None], 0, new.shape[1] - 1)
+    got = jnp.take_along_axis(new, at[..., None], axis=1)
+    return jnp.where(fresh[..., None], got.astype(ring.dtype), ring)
+
+
+class RotaryAttention(nn.Module):
+    """Softmax attention of ``n_heads`` query heads over the kind's KV
+    heads, keys and queries of ``head_dim`` with their first
+    ``rotary_dim`` dims rotated, values of ``v_head_dim``, the output
+    times ``value_scale``. ``window`` False (an ``"attn"`` layer): every
+    earlier position, the whole row cached in ``k`` / ``v``. True (a
+    ``"swa"`` layer): the last ``window`` positions, a learned sink a
+    head with ``attn_sinks``, and a ring of ``window`` positions a row
+    in ``swa_k`` / ``swa_v``. One token attends against the leaves as
+    they lie (``transformer._step_kernel`` on the TPU, which reads a
+    row's live blocks, a ring's live slots;
+    ``transformer._merged_step_attention`` elsewhere)."""
+
+    config: HybridConfig
+    window: bool = False
+
+    @nn.compact
+    def __call__(self, x, cache, index: int, lens, fresh: bool):
+        """x (B, T, D); ``lens`` (B,) the real tokens of each row (None:
+        all T): only those reach a ring. ``fresh`` (static): the rows'
+        caches are empty. Returns (out, cache)."""
+        c = self.config
+        B, T, D = x.shape
+        H, Dh, Dv, r = c.n_heads, c.head_dim, c.v_head_dim, c.rotary_dim
+        KH = c.swa_kv_heads if self.window else c.n_kv_heads
+        R = H // KH
+        init = nn.initializers.normal(stddev=D ** -0.5)
+        w_q = self.param("q_proj", init, (D, H * Dh), c.param_dtype)
+        w_k = self.param("k_proj", init, (D, KH * Dh), c.param_dtype)
+        w_v = self.param("v_proj", init, (D, KH * Dv), c.param_dtype)
+        w_o = self.param("o_proj", nn.initializers.normal((H * Dv) ** -0.5),
+                         (H * Dv, D), c.param_dtype)
+        sinks = None
+        if self.window and c.attn_sinks:
+            sinks = self.param("sinks", nn.initializers.zeros, (H,),
+                               jnp.float32).reshape(KH, R)
+        pos = cache["positions"]
+        q_pos = pos[:, None] + jnp.arange(T)[None, :]               # (B, T)
+        theta = c.swa_rope_theta if self.window else c.rope_theta
+
+        def turned(y):             # (B, T, N, Dh): rotate the first r dims
+            if not r:
+                return y
+            sin_t, cos_t = rope_tables(c.max_seq_len, r, theta)
+            # an idle row's position runs past the table: clip, its
+            # output is read by nobody
+            sin, cos = (jnp.take(t, q_pos, axis=0, mode="clip")[:, :, None]
+                        for t in (sin_t, cos_t))
+            return jnp.concatenate(
+                [_rotate(y[..., :r], sin, cos), y[..., r:]], -1)
+
+        q = turned(_dense(x, w_q, c.dtype).reshape(B, T, H, Dh))
+        q = q.astype(c.dtype).reshape(B, T, KH, R, Dh)
+        k = turned(_dense(x, w_k, c.dtype).reshape(B, T, KH, Dh))
+        k = k.astype(c.dtype).reshape(B, T, KH * Dh)
+        v = _dense(x, w_v, c.dtype).astype(c.dtype)            # (B, T, KH Dv)
+        scale = Dh ** -0.5
+        if self.window:
+            o, cache = self._window(q, k, v, cache, index, lens, pos, q_pos,
+                                    sinks, scale)
+        else:
+            o, cache = self._global(q, k, v, cache, index, fresh, pos,
+                                    q_pos, scale)
+        o = o.reshape(B, T, H * Dv) * c.value_scale
+        return _dense(o, w_o, c.dtype).astype(c.dtype), cache
+
+    def _global(self, q, k, v, cache, index, fresh, pos, q_pos, scale):
+        c = self.config
+        B, T = q.shape[:2]
+        with jax.named_scope("attn.attend"):
+            if fresh:
+                # rows start at 0 and share the slice
+                at = (index, 0, 0, 0)
+                ck = jax.lax.dynamic_update_slice(cache["k"], k[None], at)
+                cv = jax.lax.dynamic_update_slice(cache["v"], v[None], at)
+            else:
+                rows = jnp.arange(B)[:, None]
+                ck = cache["k"].at[index, rows, q_pos].set(k)
+                cv = cache["v"].at[index, rows, q_pos].set(v)
+            if T == 1 and not fresh:
+                if step_attention.use_kernel():
+                    o = _step_kernel(c, q[:, 0], ck, cv, index, pos)
+                else:
+                    mask = jnp.arange(c.max_seq_len)[None, :] <= pos[:, None]
+                    o = _merged_step_attention(q[:, 0], ck[index],
+                                               cv[index], mask)
+                return o, dict(cache, k=ck, v=cv)
+        with jax.named_scope("attn.prefill_chunk"):
+            o = _blocked_attend(q, ck, cv, index, q_pos, scale, c.dtype)
+        return o, dict(cache, k=ck, v=cv)
+
+    def _window(self, q, k, v, cache, index, lens, pos, q_pos, sinks,
+                scale):
+        c = self.config
+        B, T, KH, R, Dh = q.shape
+        W, Dv = c.window, v.shape[-1] // KH
+        rk, rv = cache["swa_k"], cache["swa_v"]
+        rows = jnp.arange(B)
+        if T == 1:
+            with jax.named_scope("swa.ring_write"):
+                slot = pos % W
+                rk = rk.at[index, rows, slot].set(k[:, 0])
+                rv = rv.at[index, rows, slot].set(v[:, 0])
+            with jax.named_scope("swa.attend"):
+                if step_attention.use_kernel():
+                    o = _step_kernel(c, q[:, 0], rk, rv, index, pos, sinks)
+                else:
+                    # after the write a slot s holds a position <= pos
+                    # and within the window iff s <= pos
+                    mask = jnp.arange(W)[None, :] <= pos[:, None]
+                    o = _merged_step_attention(q[:, 0], rk[index],
+                                               rv[index], mask, sinks)
+            return o, dict(cache, swa_k=rk, swa_v=rv)
+        with jax.named_scope("swa.attend"):
+            # the ring oldest first, then this call's tokens: positions
+            # pos - W .. pos + T - 1, one band of W per query
+            slots = ring_slots(pos, W)
+            ext_pos = jnp.concatenate([slots, q_pos], axis=1)   # (B, W + T)
+            idx = (slots % W)[..., None]
+            ek = jnp.concatenate(
+                [jnp.take_along_axis(rk[index], idx, axis=1), k], 1)
+            ev = jnp.concatenate(
+                [jnp.take_along_axis(rv[index], idx, axis=1), v], 1)
+            ek = ek.reshape(B, W + T, KH, Dh)
+            ev = ev.reshape(B, W + T, KH, Dv)
+            qb = min(T, GQA_Q_BLOCK)
+
+            def band(q_blk, qp, k_blk, v_blk, kp):
+                """Queries at ``qp`` (B, Q) against keys at ``kp`` (B, K):
+                i - W < j <= i, j a position the row holds."""
+                live = ((kp[:, None, :] <= qp[:, :, None])
+                        & (kp[:, None, :] > qp[:, :, None] - W)
+                        & (kp[:, None, :] >= 0))
+                s = jnp.where(live[:, None, None],
+                              _scores(q_blk, k_blk, scale), NEG_INF)
+                return _softmax_pv(s, v_blk, sinks, c.dtype)
+
+            if T % qb:
+                o = band(q, q_pos, ek, ev, ext_pos)
+            else:
+                def block(i):
+                    # queries [at, at + qb) read the extended keys
+                    # [at, at + qb + W): their positions less W + 1 on
+                    at = i * qb
+                    sl = lambda y, n: jax.lax.dynamic_slice_in_dim(  # noqa: E731
+                        y, at, n, 1)
+                    return band(sl(q, qb), sl(q_pos, qb), sl(ek, qb + W),
+                                sl(ev, qb + W), sl(ext_pos, qb + W))
+
+                o = jnp.moveaxis(jax.lax.map(block, jnp.arange(T // qb)),
+                                 0, 1).reshape(B, T, KH, R, Dv)
+        with jax.named_scope("swa.ring_write"):
+            n = jnp.full((B,), T, jnp.int32) if lens is None else lens
+            rk = rk.at[index].set(ring_update(rk[index], k, pos, pos + n, W))
+            rv = rv.at[index].set(ring_update(rv[index], v, pos, pos + n, W))
+        return o.astype(c.dtype), dict(cache, swa_k=rk, swa_v=rv)
+
+
+def _blocked_attend(q, ck, cv, index: int, q_pos, scale, dtype):
+    """q (B, T, KH, R, Dh) at ``q_pos`` (B, T) against layer ``index`` of
+    the written rows ``ck`` / ``cv`` (L, B, S, KH Dh / KH Dv), causally,
+    ``ATTN_KEY_BLOCK`` positions at a time with an online softmax, as
+    far as the rows' last query: no (T, S) score is ever whole."""
+    B, T, KH, R, Dh = q.shape
+    S = ck.shape[2]
+    Dv = cv.shape[-1] // KH
+    kb = math.gcd(S, ATTN_KEY_BLOCK)
+    n_blocks = jnp.minimum(jnp.max(q_pos) // kb + 1, S // kb)
+
+    def body(j, carry):
+        m, l, acc = carry
+        at = (index, 0, j * kb, 0)
+        kj = jax.lax.dynamic_slice(ck, at, (1, B, kb, ck.shape[-1]))[0]
+        vj = jax.lax.dynamic_slice(cv, at, (1, B, kb, cv.shape[-1]))[0]
+        kp = j * kb + jnp.arange(kb)
+        s = _scores(q, kj.reshape(B, kb, KH, Dh), scale)
+        s = jnp.where((kp[None, None, :] <= q_pos[:, :, None])[:, None, None],
+                      s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        pv = jnp.einsum("bhrqk,bkhd->bhrqd", p.astype(dtype),
+                        vj.reshape(B, kb, KH, Dv),
+                        preferred_element_type=jnp.float32)
+        return (m_new, alpha * l + jnp.sum(p, axis=-1, keepdims=True),
+                alpha * acc + pv)
+
+    m = jnp.full((B, KH, R, T, 1), NEG_INF, jnp.float32)
+    l = jnp.zeros((B, KH, R, T, 1), jnp.float32)
+    acc = jnp.zeros((B, KH, R, T, Dv), jnp.float32)
+    m, l, acc = jax.lax.fori_loop(0, n_blocks, body, (m, l, acc))
+    o = acc / jnp.maximum(l, 1e-30)
+    return jnp.moveaxis(o, 3, 1).astype(dtype)           # (B, T, KH, R, Dv)
+
+
 # -- routed MLP ------------------------------------------------------------------
 
 def route(scores_logits, bias, c: HybridConfig):
@@ -937,7 +1238,7 @@ def route(scores_logits, bias, c: HybridConfig):
 class RoutedMlp(nn.Module):
     """Routes over all ``n_experts``, holds ``experts_held`` of them and
     computes what those add for the tokens routed to them, plus the
-    shared expert. Tokens are grouped by expert (one sort, then
+    shared expert where ``d_shared`` is not 0. Tokens are grouped by expert (one sort, then
     ``ops/gmm.py``'s grouped matmul over the groups, one call a
     product): no token is dropped, the work follows the routed (token,
     expert) pairs held here, and an expert no pair reached is not read.
@@ -967,7 +1268,7 @@ class RoutedMlp(nn.Module):
               for name, shape in (("gate", (D, c.d_shared)),
                                   ("up", (D, c.d_shared)),
                                   ("down", (c.d_shared, D)))
-              if gated or name != "gate"]
+              if c.d_shared and (gated or name != "gate")]
         flat = x.reshape(B * T, D)
         N = B * T
 
@@ -996,6 +1297,8 @@ class RoutedMlp(nn.Module):
             y = jnp.sum(jnp.where(held[..., None],
                                   back * w[..., None], 0.0), axis=1)
 
+        if not sh:
+            return y.astype(c.dtype).reshape(B, T, D), hit, pairs
         with jax.named_scope("moe.shared"):
             y = y.astype(c.dtype) + (_swiglu if gated else _relu2)(
                 flat, *sh, c.dtype)
@@ -1036,6 +1339,10 @@ class HybridLayer(nn.Module):
         elif self.mixer == "dsa":
             out, cache, picked = MlaAttention(c, sparse=True, name="mixer")(
                 h, cache, index[0], fresh, index[1])
+        elif self.mixer in ("attn", "swa"):
+            out, cache = RotaryAttention(c, self.mixer == "swa",
+                                         name="mixer")(h, cache, index, lens,
+                                                       fresh)
         else:
             out, cache, _ = MlaAttention(c, name="mixer")(h, cache, index,
                                                           fresh)
@@ -1098,11 +1405,18 @@ class HybridDecoder(nn.Module):
         lens = None
         if true_len is not None:
             lens = jnp.broadcast_to(jnp.asarray(true_len, jnp.int32), (B,))
-        if T == 1 and not fresh and c.n_gqa:
+        if T == 1 and not fresh and (c.n_gqa or c.n_attn):
+            # the dense rows of "gqa" blocks (V as wide as K) or of
+            # "attn" layers (V of v_head_dim)
             for name, n in step_block_counts(
                     c, cache["positions"], c.n_kv_heads, c.head_dim,
-                    c.n_heads).items():
+                    c.n_heads, c.v_head_dim if c.n_attn else c.head_dim
+            ).items():
                 self.sow("moe_stats", name, n)
+        if T == 1 and not fresh and c.n_swa and step_attention.use_kernel():
+            self.sow("moe_stats", "window_positions_read",
+                     step_attention.window_positions(
+                         cache["positions"], c.max_seq_len, c.window))
 
         embed = self.param("token_embed",
                            nn.initializers.normal(c.d_model ** -0.5),

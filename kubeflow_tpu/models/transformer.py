@@ -273,13 +273,15 @@ def _layer_slice(stacked: jnp.ndarray, layer) -> jnp.ndarray:
     return jax.lax.dynamic_index_in_dim(stacked, layer, 0, keepdims=False)
 
 
-def _merged_step_attention(q, kp, vp, mask):
+def _merged_step_attention(q, kp, vp, mask, sinks=None):
     """One token's attention against K / V rows whose KV heads lie
     merged on the last axis, without un-merging them.
 
     ``q``: ``(B, KH, G, Dh)``, rotated, the ``G`` query heads of a KV
-    head side by side; ``kp`` / ``vp``: ``(B, T, KH·Dh)``; ``mask``:
-    ``(B, T)``, True where the row may look. Returns ``(B, KH, G, Dh)``.
+    head side by side; ``kp`` / ``vp``: ``(B, T, KH·Dh)`` and ``(B, T,
+    KH·Dv)``; ``mask``: ``(B, T)``, True where the row may look;
+    ``sinks``: None, or ``(KH, G)`` float32, a logit a head in the
+    softmax's denominator with no value. Returns ``(B, KH, G, Dv)``.
     Both products run over the whole merged axis: QKᵀ against a query
     that is zero outside its own KV head's lanes (``qz[b, kvh·Dh + d, h]
     = q[b, h, d]`` where ``kvh = h // G``), PV into all ``KH·Dh`` lanes,
@@ -301,37 +303,53 @@ def _merged_step_attention(q, kp, vp, mask):
     logits = jnp.einsum("btl,blh->bht", kp, qz).astype(jnp.float32)
     logits = logits * (Dh ** -0.5)
     logits = jnp.where(mask[:, None, :], logits, NEG_INF)
-    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    full = jnp.einsum("bht,btl->bhl", probs, vp)      # (B, H, KH·Dh)
+    if sinks is None:
+        probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    else:
+        b = sinks.reshape(1, KH * G, 1).astype(jnp.float32)
+        m = jnp.maximum(jnp.max(logits, axis=-1, keepdims=True), b)
+        e = jnp.exp(logits - m)
+        probs = (e / (jnp.exp(b - m) + jnp.sum(e, axis=-1, keepdims=True))
+                 ).astype(q.dtype)
+    full = jnp.einsum("bht,btl->bhl", probs, vp)      # (B, H, KH·Dv)
+    Dv = vp.shape[-1] // KH
     # (B, j, g, kvh, d): head (j, g) keeps the lanes of KV head j
-    return jnp.where(own, full.reshape(B, KH, G, KH, Dh), 0).sum(axis=3)
+    return jnp.where(own, full.reshape(B, KH, G, KH, Dv), 0).sum(axis=3)
 
 
-def _step_kernel(c, q, ck, cv, layer, pos):
+def _step_kernel(c, q, ck, cv, layer, pos, sinks=None):
     """One token a row against the stacked ``k`` / ``v`` leaves at
     ``layer`` by ``ops/step_attention.py``'s kernel, which reads a row
     only as far as it has grown, under ``shard_kernel`` (whole KV heads a
     tp rank; nothing is exchanged). ``q`` ``(B, KH, G, Dh)``, ``pos``
     ``(B,)`` (the step's token is written there); ``c`` has
     ``max_seq_len``, ``dtype`` and ``rules``. The dense decoder and the
-    hybrid decoder's ``"gqa"`` blocks call it where
-    ``step_attention.use_kernel()`` says, and
-    :func:`_merged_step_attention` (its reference) elsewhere."""
+    hybrid decoder's ``"gqa"`` blocks and ``"attn"`` / ``"swa"`` layers
+    call it where ``step_attention.use_kernel()`` says, and
+    :func:`_merged_step_attention` (its reference) elsewhere. V may be
+    narrower than K; a leaf shorter than ``max_seq_len`` is a window
+    layer's ring; ``sinks`` ``(KH, G)`` float32 or None."""
     from kubeflow_tpu.ops import step_attention as sa
 
     B, KH, G, Dh = q.shape
-    rows, block = sa.tiling(B, c.max_seq_len, KH, Dh, KH * G, c.dtype)
+    Smax, Dv = ck.shape[2], cv.shape[-1] // KH
+    rows, block = sa.tiling(B, Smax, KH, Dh, KH * G, c.dtype,
+                            None if Dv == Dh else Dv)
     grouped, stacked = (None, "heads", None, None), (None, None, None, "heads")
+    args = (q, ck, cv, jnp.asarray(layer, jnp.int32), pos)
+    specs = (grouped, stacked, stacked, (), (None,))
+    if sinks is not None:
+        args, specs = args + (sinks,), specs + (("heads", None),)
     return shard_kernel(
         "step_attention",
-        functools.partial(sa.step_attention, block_rows=rows,
-                          block_len=block),
-        (q, ck, cv, jnp.asarray(layer, jnp.int32), pos),
-        (grouped, stacked, stacked, (), (None,)), q.shape, grouped, c.rules)
+        functools.partial(
+            sa.step_attention, block_rows=rows, block_len=block,
+            max_seq_len=None if Smax == c.max_seq_len else c.max_seq_len),
+        args, specs, (B, KH, G, Dv), grouped, c.rules)
 
 
 def step_block_counts(c, pos, n_kv_heads: int, head_dim: int,
-                      n_heads: int) -> dict:
+                      n_heads: int, v_head_dim: Optional[int] = None) -> dict:
     """What a one-token step's attention reads of the dense K / V leaves,
     in ``ops/step_attention.py``'s blocks, for the engine's round:
     ``kv_blocks_read`` (the rows' live blocks) and ``kv_blocks_reserved``
@@ -342,7 +360,8 @@ def step_block_counts(c, pos, n_kv_heads: int, head_dim: int,
     if not sa.use_kernel():
         return {}
     _, block = sa.tiling(pos.shape[0], c.max_seq_len, n_kv_heads, head_dim,
-                         n_heads, c.dtype)
+                         n_heads, c.dtype,
+                         None if v_head_dim == head_dim else v_head_dim)
     read, reserved = sa.block_counts(pos, c.max_seq_len, block)
     return {"kv_blocks_read": read, "kv_blocks_reserved": reserved}
 

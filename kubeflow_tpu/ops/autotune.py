@@ -240,17 +240,24 @@ STEP_MAX_ROWS = 64
 
 
 def step_vmem_bytes(block_rows: int, block_len: int, lanes: int,
-                    n_heads: int, head_dim: int, dtype_bytes: int) -> int:
+                    n_heads: int, head_dim: int, dtype_bytes: int,
+                    v_lanes: Optional[int] = None,
+                    v_head_dim: Optional[int] = None) -> int:
     """VMEM the step attention holds: K and V blocks in two slots, the
     float32 accumulators, the query and output blocks in their two
     pipeline buffers and the selection matrices, every last axis padded
-    to whole 128-lane tiles as VMEM lays it out."""
+    to whole 128-lane tiles as VMEM lays it out. V is ``v_lanes`` wide,
+    a head of it ``v_head_dim`` (None: as K)."""
     f32 = 4
     pad = lambda x: -(-x // LANE_MULTIPLE) * LANE_MULTIPLE  # noqa: E731
-    kv = 2 * 2 * block_rows * block_len * pad(lanes) * dtype_bytes
-    scratch = block_rows * n_heads * (pad(lanes) + 2 * LANE_MULTIPLE) * f32
-    io = 2 * block_rows * n_heads * (pad(lanes) + pad(head_dim)) * dtype_bytes
-    consts = 2 * (n_heads * pad(lanes) + lanes * pad(head_dim)) * dtype_bytes
+    if v_lanes is None:
+        v_lanes, v_head_dim = lanes, head_dim
+    kv = 2 * block_rows * block_len * (pad(lanes) + pad(v_lanes)) * dtype_bytes
+    scratch = block_rows * n_heads * (pad(v_lanes) + 2 * LANE_MULTIPLE) * f32
+    io = 2 * block_rows * n_heads * (pad(lanes) + pad(v_head_dim)) \
+        * dtype_bytes
+    consts = 2 * (n_heads * pad(v_lanes) + v_lanes * pad(v_head_dim)) \
+        * dtype_bytes
     return kv + scratch + io + consts
 
 
@@ -729,8 +736,8 @@ def resolve_gmm(*, m: int, k: int, n: int, dtype: Any,
 
 def resolve_step_attention(*, rows: int, max_seq_len: int, lanes: int,
                            n_heads: int, head_dim: int, dtype: Any,
-                           tiling: Optional[Tuple[int, int]] = None
-                           ) -> TileConfig:
+                           tiling: Optional[Tuple[int, int]] = None,
+                           v_lanes: Optional[int] = None) -> TileConfig:
     """Resolve the step attention's ``(block_rows, block_len)`` for
     ``rows`` rows of ``max_seq_len`` positions of ``lanes`` lanes: an
     explicit ``tiling`` untouched, else the rule. ``block_len``: the
@@ -740,10 +747,16 @@ def resolve_step_attention(*, rows: int, max_seq_len: int, lanes: int,
     :data:`STEP_MAX_ROWS`, that divide ``rows`` and keep
     :func:`step_vmem_bytes` within :data:`STEP_VMEM_BYTES`.
     Neither depends on what the rows hold: a row reads its own blocks
-    alone, so more rows a program cost VMEM and save programs."""
+    alone, so more rows a program cost VMEM and save programs. V may be
+    narrower (``v_lanes``, as many heads as K: a window layer's)."""
     name = dtype_name(dtype)
     shape = {"rows": rows, "max_seq_len": max_seq_len, "lanes": lanes,
              "n_heads": n_heads, "head_dim": head_dim, "dtype": name}
+    v_widths = {}
+    if v_lanes is not None and v_lanes != lanes:
+        shape["v_lanes"] = v_lanes
+        v_widths = {"v_lanes": v_lanes,
+                    "v_head_dim": v_lanes * head_dim // lanes}
     if tiling is not None:
         return _record(TileConfig("step_attn", tiling=tuple(map(int, tiling)),
                                   source="override"), shape)
@@ -756,6 +769,6 @@ def resolve_step_attention(*, rows: int, max_seq_len: int, lanes: int,
     block_rows = max([1] + [
         r for r in range(1, min(rows, STEP_MAX_ROWS) + 1) if rows % r == 0
         and step_vmem_bytes(r, block_len, lanes, n_heads, head_dim,
-                            nbytes) <= STEP_VMEM_BYTES])
+                            nbytes, **v_widths) <= STEP_VMEM_BYTES])
     return _record(TileConfig("step_attn", tiling=(block_rows, block_len)),
                    shape)

@@ -35,6 +35,14 @@ row's output is zeros.
 - **Numerics.** Scores in float32 at ``Dh ** -0.5`` after the product,
   the ``kv_pos <= pos`` bound, an online softmax in float32, the values'
   product in the cache's dtype with float32 sums.
+- **Window layers** (``models/hybrid.py`` ``"swa"``). V may be narrower
+  than K (its own lanes and selection matrix). ``sinks`` starts a row's
+  online softmax at ``m = b_h, l = 1`` in place of ``m = -inf, l = 0``:
+  a logit a head that takes probability mass and gives no value. A
+  leaf shorter than ``max_seq_len`` is a ring (position p at slot
+  ``p % Smax``): a row reads ``min(pos + 1, Smax)`` slots, all of them
+  once it has wrapped (the ``kv_pos <= pos`` bound then masks none), in
+  whatever order they lie, which a softmax does not see.
 
 ``rows`` per program and ``block_len`` come from one shape rule,
 :func:`kubeflow_tpu.ops.autotune.resolve_step_attention`, keyed by the
@@ -66,20 +74,35 @@ def use_kernel() -> bool:
 
 
 def tiling(rows: int, max_seq_len: int, n_kv_heads: int, head_dim: int,
-           n_heads: int, dtype) -> Tuple[int, int]:
+           n_heads: int, dtype,
+           v_head_dim: Optional[int] = None) -> Tuple[int, int]:
     """``(block_rows, block_len)`` for a step over ``rows`` rows of
     ``max_seq_len`` positions (the shape rule)."""
     return autotune.resolve_step_attention(
         rows=rows, max_seq_len=max_seq_len, lanes=n_kv_heads * head_dim,
-        n_heads=n_heads, head_dim=head_dim, dtype=dtype).tiling
+        n_heads=n_heads, head_dim=head_dim, dtype=dtype,
+        v_lanes=None if v_head_dim is None else n_kv_heads * v_head_dim
+    ).tiling
 
 
-def live_blocks(pos, max_seq_len: int, block_len: int):
+def live_blocks(pos, max_seq_len: int, block_len: int, ring: int = 0):
     """``(B,)`` int32: the blocks of ``block_len`` positions a row's step
-    reads, through the one that holds its position ``pos``; none for an
-    idle row (``pos >= max_seq_len``)."""
+    reads, through the one that holds its position ``pos``, or of a ring
+    of ``ring`` slots at most all of them; none for an idle row (``pos
+    >= max_seq_len``)."""
     pos = jnp.asarray(pos, jnp.int32)
+    if ring:
+        return jnp.where(pos < max_seq_len, jnp.minimum(
+            pos // block_len + 1, ring // block_len), 0)
     return jnp.where(pos < max_seq_len, pos // block_len + 1, 0)
+
+
+def window_positions(pos, max_seq_len: int, window: int):
+    """What one step of one window layer reads: the ring slots of the
+    live rows, ``min(pos + 1, window)`` a row; an int32 scalar."""
+    pos = jnp.asarray(pos, jnp.int32)
+    return jnp.sum(jnp.where(pos < max_seq_len,
+                             jnp.minimum(pos + 1, window), 0))
 
 
 def block_counts(pos, max_seq_len: int, block_len: int):
@@ -91,8 +114,11 @@ def block_counts(pos, max_seq_len: int, block_len: int):
 
 
 def _kernel(layer_ref, pos_ref, nblk_ref, last_ref, q_ref, own_ref, sel_ref,
-            k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, acc_ref, m_ref, l_ref, *,
-            rows: int, block_len: int, scale: float, precision):
+            *refs, rows: int, block_len: int, scale: float, precision,
+            sinks: bool):
+    if sinks:
+        sink_ref, *refs = refs
+    (k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, acc_ref, m_ref, l_ref) = refs
     g = pl.program_id(0)
     layer = layer_ref[0]
     base = g * rows
@@ -113,8 +139,13 @@ def _kernel(layer_ref, pos_ref, nblk_ref, last_ref, q_ref, own_ref, sel_ref,
                     c.start()
 
     acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
-    m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
-    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    if sinks:
+        # the sink is a key of logit b_h and no value: exp(b_h - m) in l
+        m_ref[...] = jnp.broadcast_to(sink_ref[...][None], m_ref.shape)
+        l_ref[...] = jnp.ones(l_ref.shape, jnp.float32)
+    else:
+        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
 
     @pl.when(n > 0)
     def _():
@@ -165,26 +196,34 @@ def _kernel(layer_ref, pos_ref, nblk_ref, last_ref, q_ref, own_ref, sel_ref,
             preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
-def step_attention(q, k, v, layer, pos, *, block_rows: int, block_len: int,
+def step_attention(q, k, v, layer, pos, sinks=None, *, block_rows: int,
+                   block_len: int, max_seq_len: Optional[int] = None,
                    interpret: Optional[bool] = None):
     """One token a row against the cached rows.
 
     - ``q``: ``(B, KH, G, Dh)``, rotated, the ``G`` query heads of a KV
       head side by side;
-    - ``k`` / ``v``: the stacked leaves, ``(L, B, Smax, KH·Dh)``, read at
-      layer ``layer`` (an int or an int32 scalar, traced or not); the
-      step's own token must already be written at ``pos``;
+    - ``k`` / ``v``: the stacked leaves, ``(L, B, Smax, KH·Dh)`` and
+      ``(L, B, Smax, KH·Dv)``, read at layer ``layer`` (an int or an
+      int32 scalar, traced or not); the step's own token must already
+      be written at ``pos`` (at ``pos % Smax`` in a ring);
     - ``pos``: ``(B,)`` int32, each row's position (keys ``<= pos``
-      attend); ``>= Smax`` marks an idle row, whose output is zeros.
+      attend); ``>= max_seq_len`` marks an idle row, whose output is
+      zeros;
+    - ``sinks``: None, or ``(KH, G)`` float32, a logit a query head;
+    - ``max_seq_len``: None (the leaf's ``Smax``), or more than ``Smax``:
+      the leaf is a ring of the last ``Smax`` positions.
 
-    Returns ``(B, KH, G, Dh)`` in ``q``'s dtype. ``block_rows`` must
+    Returns ``(B, KH, G, Dv)`` in ``q``'s dtype. ``block_rows`` must
     divide ``B`` and ``block_len`` must divide ``Smax``."""
     B, KH, G, Dh = q.shape
     L, _, Smax, lanes = k.shape
+    v_lanes = v.shape[-1]
+    Dv = v_lanes // KH
     H = KH * G
-    if lanes != KH * Dh or k.shape[1] != B:
-        raise ValueError(f"cache {k.shape} does not hold {B} rows of "
-                         f"{KH} x {Dh} lanes")
+    if lanes != KH * Dh or k.shape[1] != B or v_lanes != KH * Dv:
+        raise ValueError(f"cache {k.shape} / {v.shape} does not hold {B} "
+                         f"rows of {KH} heads x {Dh} lanes")
     if B % block_rows or Smax % block_len:
         raise ValueError(f"{block_rows} rows x {block_len} positions do "
                          f"not tile {B} rows x {Smax}")
@@ -192,49 +231,60 @@ def step_attention(q, k, v, layer, pos, *, block_rows: int, block_len: int,
     eye = np.eye(KH, dtype=bool)[None, :, None, :, None]
     qz = jnp.where(eye, q[:, :, :, None, :], 0).reshape(B, H, lanes)
     qz = qz.astype(k.dtype)
-    lane = np.arange(lanes)
-    own = (lane[None, :] // Dh == np.arange(H)[:, None] // G)
-    sel = (lane[:, None] % Dh == np.arange(Dh)[None, :])
+    lane = np.arange(v_lanes)
+    own = (lane[None, :] // Dv == np.arange(H)[:, None] // G)
+    sel = (lane[:, None] % Dv == np.arange(Dv)[None, :])
     own, sel = (jnp.asarray(x, q.dtype) for x in (own, sel))
     pos = jnp.asarray(pos, jnp.int32).reshape(B)
-    nblk = live_blocks(pos, Smax, block_len)
+    if max_seq_len is None:
+        nblk = live_blocks(pos, Smax, block_len)
+    else:
+        nblk = live_blocks(pos, max_seq_len, block_len, ring=Smax)
     last = jnp.max(nblk.reshape(B // block_rows, block_rows), axis=1)
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
     nbytes = jnp.dtype(k.dtype).itemsize
-    vmem = autotune.step_vmem_bytes(block_rows, block_len, lanes, H, Dh,
-                                    nbytes)
+    vmem = autotune.step_vmem_bytes(
+        block_rows, block_len, lanes, H, Dh, nbytes,
+        **({} if v_lanes == lanes else {"v_lanes": v_lanes,
+                                        "v_head_dim": Dv}))
     precision = (jax.lax.Precision.HIGHEST if q.dtype == jnp.float32
                  else None)
 
     const = lambda g, *_: (0, 0)       # noqa: E731
     per_program = lambda g, *_: (g, 0, 0)  # noqa: E731
+    extra, extra_specs = (), []
+    if sinks is not None:
+        extra = (jnp.asarray(sinks, jnp.float32).reshape(H, 1),)
+        extra_specs = [pl.BlockSpec((H, 1), const)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(B // block_rows,),
         in_specs=[pl.BlockSpec((block_rows, H, lanes), per_program),
-                  pl.BlockSpec((H, lanes), const),
-                  pl.BlockSpec((lanes, Dh), const),
+                  pl.BlockSpec((H, v_lanes), const),
+                  pl.BlockSpec((v_lanes, Dv), const),
+                  *extra_specs,
                   pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((block_rows, H, Dh), per_program),
+        out_specs=pl.BlockSpec((block_rows, H, Dv), per_program),
         scratch_shapes=[
             pltpu.VMEM((2, block_rows, block_len, lanes), k.dtype),
-            pltpu.VMEM((2, block_rows, block_len, lanes), v.dtype),
+            pltpu.VMEM((2, block_rows, block_len, v_lanes), v.dtype),
             pltpu.SemaphoreType.DMA((2, 2, block_rows)),
-            pltpu.VMEM((block_rows, H, lanes), jnp.float32),
+            pltpu.VMEM((block_rows, H, v_lanes), jnp.float32),
             pltpu.VMEM((block_rows, H, 1), jnp.float32),
             pltpu.VMEM((block_rows, H, 1), jnp.float32)],
     )
     kernel = functools.partial(_kernel, rows=block_rows, block_len=block_len,
-                               scale=Dh ** -0.5, precision=precision)
+                               scale=Dh ** -0.5, precision=precision,
+                               sinks=sinks is not None)
     with jax.named_scope("step_attention"):   # the call's name on a trace
         out = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((B, H, Dh), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((B, H, Dv), q.dtype),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",),
                 vmem_limit_bytes=vmem + 4 * 2 ** 20),
             interpret=resolve_interpret(interpret),
-        )(layer, pos, nblk, last, qz, own, sel, k, v)
-    return out.reshape(B, KH, G, Dh)
+        )(layer, pos, nblk, last, qz, own, sel, *extra, k, v)
+    return out.reshape(B, KH, G, Dv)

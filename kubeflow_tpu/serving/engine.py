@@ -145,6 +145,11 @@ _step_attn_blocks_c = DEFAULT_REGISTRY.counter(
     "(what=read: each row's blocks through its position, none for an idle "
     "row) and held (what=reserved: rows x blocks a row), for one attention "
     "layer, summed over every decode step")
+_step_window_c = DEFAULT_REGISTRY.counter(
+    "kftpu_step_attention_window_positions_total",
+    "ring slots of cached K/V positions the one-token step attention of a "
+    "window layer read (min(position + 1, window) a live row), for one "
+    "window layer, summed over every decode step")
 # what a model's layers count in a decode step -> the counter it feeds
 # and that counter's labels
 _STEP_COUNTERS = {"experts_hit": (_moe_hit_c, {}),
@@ -153,7 +158,8 @@ _STEP_COUNTERS = {"experts_hit": (_moe_hit_c, {}),
                   "index_selected": (_dsa_selected_c, {}),
                   "kv_blocks_read": (_step_attn_blocks_c, {"what": "read"}),
                   "kv_blocks_reserved": (_step_attn_blocks_c,
-                                         {"what": "reserved"})}
+                                         {"what": "reserved"}),
+                  "window_positions_read": (_step_window_c, {})}
 
 _END = object()  # per-request stream sentinel
 
@@ -586,8 +592,8 @@ class DecodeEngine:
         if prefix_len and self.config.has_recurrent_state:
             raise ValueError(
                 "prefix reuse continues a row from a stored prefix; this "
-                "model keeps a recurrent state, which the prefix store "
-                "does not snapshot")
+                "model keeps a recurrent state or a window ring, which "
+                "the prefix store does not snapshot")
         # what only this cache can refuse (a request over the whole
         # pool) or must downgrade (a prefix its store can never hold)
         prefix_len = self._kv.check_submit(prompt.size, max_new,

@@ -511,3 +511,90 @@ def test_fused_flash_backward_compiles_at_its_table_tile(one_chip, no_cache,
         flags=re.M)]
     # the forward (a tensor and its row statistics) and the fused call
     assert sorted(returns) == [1, 3]
+
+
+# MiMo-V2-Flash's global and window layers (kubeflow_tpu/models/hybrid.py
+# "attn" / "swa") at the published widths, one of each: the global layer
+# with the dense MLP, the window layer routed (16 of 256 experts held);
+# 32 slots x 32768, rings of 128
+SWA = dict(vocab_size=19072, d_model=4096, n_heads=64, head_dim=192,
+           v_head_dim=128, layer_types=("attn", "swa"), first_k_dense=1,
+           norm_eps=1e-5, d_ff=16384, max_seq_len=32768, n_kv_heads=4,
+           swa_kv_heads=8, rotary_dim=64, rope_theta=5e6,
+           swa_rope_theta=1e4, window=128, attn_sinks=True,
+           value_scale=0.707, prefill_chunk=2048, n_experts=256,
+           experts_per_token=8, n_group=1, topk_group=1, routed_scaling=1.0,
+           d_expert=2048, d_shared=0, experts_held=(0, 16))
+SWA_SLOTS = 32
+
+
+@pytest.mark.parametrize("program", ["step", "chunk"])
+def test_window_and_global_programs_compile_with_the_step_kernel(
+        one_chip, no_cache, monkeypatch, program):
+    """The K-step program at 32 slots and the admission chunk of 2048
+    tokens, on the TPU's own compiler: the step attention kernel takes
+    both kinds (a global layer's rows, qk 192 / v 128; a window layer's
+    ring of 128 with its sinks), one call a layer; the step copies no
+    stacked cache leaf whole; the chunk's temporaries (a global layer
+    attends in key blocks of 512, never to a 2048 x 32768 score) stay
+    under 1.5 GB beside the 1-row cache, which it copies once on the way
+    in as DeepSeek-V3.2's chunk does."""
+    import jax
+    import jax.numpy as jnp
+
+    from kubeflow_tpu.models.decode import (
+        decode_step_stats,
+        prefill,
+        prefill_continue,
+    )
+    from kubeflow_tpu.models.hybrid import HybridConfig, HybridDecoder
+
+    _compile_kernels(monkeypatch)
+    cfg = HybridConfig(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, **SWA)
+
+    def place(s):
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(place, jax.eval_shape(
+        lambda k: HybridDecoder(cfg).init(k, jnp.zeros((1, 8), jnp.int32)),
+        jax.random.key(0))["params"])
+
+    def cache_of(rows):
+        return jax.tree_util.tree_map(place, jax.eval_shape(
+            lambda p: prefill(cfg, p, jnp.zeros((rows, 1), jnp.int32))[1],
+            params))
+
+    if program == "step":
+        rows = SWA_SLOTS
+
+        def step(params, cache, tokens):
+            def body(carry, _):
+                cache, tokens = carry
+                logits, cache, stats = decode_step_stats(cfg, params, cache,
+                                                         tokens)
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                return (cache, nxt), (nxt, stats)
+            (cache, _), out = jax.lax.scan(body, (cache, tokens), None,
+                                           length=K)
+            return cache, out
+
+        compiled = jax.jit(step, donate_argnums=(1,)).lower(
+            params, cache_of(rows),
+            place(jax.ShapeDtypeStruct((rows,), jnp.int32))).compile()
+    else:
+        rows = 1
+        one = place(jax.ShapeDtypeStruct((1,), jnp.int32))
+        compiled = jax.jit(
+            lambda p, c, t, n, total: prefill_continue(cfg, p, c, t, n, total)
+        ).lower(params, cache_of(rows),
+                place(jax.ShapeDtypeStruct((1, cfg.prefill_chunk),
+                                           jnp.int32)), one, one).compile()
+        assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+    text = compiled.as_text()
+    assert _step_attention_calls(text) == (
+        [f"bf16[{rows},64,128]"] * 2 if program == "step" else [])
+    for leaf in (f"bf16[1,{rows},32768,768]", f"bf16[1,{rows},32768,512]",
+                 f"bf16[1,{rows},128,1536]", f"bf16[1,{rows},128,1024]"):
+        copies = re.findall(rf"= {re.escape(leaf)}\S* copy\(", text)
+        assert len(copies) <= (program == "chunk" and "32768" in leaf), (
+            leaf, copies)
