@@ -119,6 +119,12 @@ _queue_depth = DEFAULT_REGISTRY.gauge(
 _queue_wait_h = DEFAULT_REGISTRY.histogram(
     "engine_queue_wait_seconds",
     "time a generate request waits for a decode slot")
+_rounds_ahead_c = DEFAULT_REGISTRY.counter(
+    "kftpu_engine_rounds_ahead_total",
+    "rounds whose K steps were enqueued before the host read the tokens "
+    "of the round before them (an engine.round's ``ahead``): over the "
+    "rounds that stepped, how often the device had work queued while the "
+    "host read, emitted and admitted")
 _recoveries_c = DEFAULT_REGISTRY.counter(
     "kftpu_engine_recoveries_total",
     "engine cache rebuild-and-replay events after a failed donating "
@@ -164,7 +170,8 @@ _STEP_COUNTERS = {"experts_hit": (_moe_hit_c, {}),
 _END = object()  # per-request stream sentinel
 
 # an ``engine.round`` span's ``<phase>_s`` attrs and the ``phase`` label
-# of kftpu_engine_round_seconds_total, in the order a round passes them
+# of kftpu_engine_round_seconds_total (a round passes ``admit`` twice
+# where it lands what it launched: ``run_once``)
 _ROUND_PHASES = ("wait", "admit", "step", "sync", "emit")
 # an ``engine.admission`` span's ``<phase>_s`` attrs, the ``phase`` label
 # of kftpu_engine_admit_seconds_total and the ``engine.admit.<phase>``
@@ -233,6 +240,52 @@ class _Slot:
     # every token emitted, in order — the cache-recovery replay prompt
     # is (request prompt + emitted); delivery itself rides req.out
     emitted: List[int] = dataclasses.field(default_factory=list)
+    # tokens the stream will have once every round launched with it is
+    # read: the launch that reaches max_new is its last
+    launched: int = 0
+    done: bool = False  # its last token went out (budget or EOS)
+
+
+@dataclasses.dataclass
+class _Flight:
+    """A round of K steps enqueued on the device whose tokens the host
+    has not read yet."""
+    rows: List[tuple]   # (slot, _Slot) of the streams it steps
+    toks: Any           # (K, slots) int32 on the device
+    stats: dict         # what the model's layers counted, on the device
+    greedy: bool
+    ahead: bool         # enqueued before the round before it was read
+    t_launch: float
+
+
+class _Round:
+    """The ``engine.round`` record of one ``run_once`` cycle while it
+    runs: the phase the thread is in and the seconds booked to each.
+    ``enter`` is the one place the round reads the clock at a boundary,
+    once, so the phases tile ``[start, end]`` whatever order the cycle
+    passes them in; what the round read and stepped is filled in as it
+    gets there."""
+
+    def __init__(self, eng: "DecodeEngine", phase: str) -> None:
+        self.eng, self.phase = eng, phase
+        self.secs = dict.fromkeys(_ROUND_PHASES, 0.0)
+        self.start = self._t = eng.clock()
+        # a cycle with nothing in flight may wait: carved out of admit,
+        # the phase it starts in
+        self.wait_s = 0.0
+        self.rows = self.k = 0
+        self.greedy = self.ahead = False
+        self.counts: dict = {}
+        self.compiles0 = xprof.compiles_total()
+
+    def enter(self, phase: str) -> float:
+        """The thread passes into ``phase``; returns the boundary (the
+        phase's start where the thread is in it already)."""
+        if phase != self.phase:
+            now = self.eng.clock()
+            self.secs[self.phase] += now - self._t
+            self.phase, self._t = phase, now
+        return self._t
 
 
 class _Admission:
@@ -244,10 +297,15 @@ class _Admission:
     leaf: it closes the open annotation, reads the clock ONCE, books the
     stretch that ended and opens the next, so the four durations tile
     ``[start, end]`` on the very boundaries the profiler's host plane
-    shows. What the programs ran is counted on the attributes: ``rows``
-    requests in ``rows_padded`` program rows, each scanned ``width``
-    tokens wide (in ``chunks`` chunk programs, where chunked), of which
-    ``prompt_tokens`` were the requests' own."""
+    shows. An admission launched behind a round in flight is
+    ``suspend``-ed once its programs are enqueued: the thread leaves for
+    the round's read and emission while ``read`` runs on, and ``resume``
+    brings it back to wait for the first tokens and arm the slots, so
+    ``read_s`` is the time from the launch's end to the first tokens on
+    the host. What the programs ran is counted on the attributes:
+    ``rows`` requests in ``rows_padded`` program rows, each scanned
+    ``width`` tokens wide (in ``chunks`` chunk programs, where chunked),
+    of which ``prompt_tokens`` were the requests' own."""
 
     def __init__(self, eng: "DecodeEngine", kind: str, *, rows: int = 1,
                  rows_padded: int = 1, width: int = 0,
@@ -258,6 +316,7 @@ class _Admission:
         self.secs = dict.fromkeys(_ADMIT_PHASES, 0.0)
         self.status = "OK"
         self._phase = "host"        # the leaf run_once opened
+        self._suspended = False
         self._compiles0 = xprof.compiles_total()
         self.start = self.end = self._t = eng.clock()
 
@@ -271,6 +330,35 @@ class _Admission:
         eng._enter_leaf("engine.admit." + phase)
         return now
 
+    def suspend(self) -> float:
+        """The programs are enqueued: ``read`` starts here and runs on
+        while the thread goes back to the admission's host code and on
+        to the round's other work. Returns the boundary."""
+        eng = self.eng
+        eng._leave_leaf()
+        now = eng.clock()
+        self.secs[self._phase] += now - self._t
+        self._phase, self._t = "read", now
+        self._suspended = True
+        eng._enter_leaf("engine.admit.host")
+        return now
+
+    def resume(self) -> None:
+        """The thread is back to wait for the first tokens: the read
+        phase goes on, no clock is read."""
+        self._suspended = False
+        self.eng._leave_leaf()
+        self.eng._enter_leaf("engine.admit.read")
+
+    def abandon(self) -> None:
+        """A recovery or the engine's close dropped the launched
+        admission unread: record it as failed, touching no leaf (the
+        thread is in none of admission's)."""
+        self.status = "ERROR: abandoned"
+        self.end = self.eng.clock()
+        self.secs[self._phase] += self.end - self._t
+        self._record()
+
     def __enter__(self) -> "_Admission":
         return self
 
@@ -278,13 +366,20 @@ class _Admission:
         """One ``engine.admission`` span, child of the engine's run as
         the rounds are, and the same seconds and counts into the three
         counters; the thread is left in the host leaf. A failure is
-        recorded too (``status``), with what had been counted."""
+        recorded too (``status``), with what had been counted. A
+        suspended admission is recorded by its landing."""
         eng = self.eng
+        if exc_type is None and self._suspended:
+            return
         if exc_type is not None:
             self.status = f"ERROR: {exc_type.__name__}"
         self.end = (self.enter("host") if self._phase != "host"
                     else eng.clock())
         self.secs["host"] += self.end - self._t
+        self._record()
+
+    def _record(self) -> None:
+        eng = self.eng
         scanned = self.rows_padded * self.width
         attrs = {"model": eng.name, "round": eng.rounds_total,
                  "kind": self.kind, "rows": self.rows,
@@ -306,6 +401,13 @@ class _Admission:
                           status=self.status)
 
 
+def _put(host: np.ndarray) -> jax.Array:
+    """A host array on the device, from a copy of its own: the transfer
+    may read its source after the call returns, and the engine's host
+    arrays change while a round is in flight."""
+    return jnp.asarray(host.copy())
+
+
 def _kv_attr(name: str) -> property:
     return property(lambda self: getattr(self._kv, name),
                     lambda self, value: setattr(self._kv, name, value))
@@ -315,8 +417,9 @@ class DecodeEngine:
     """One engine per loaded transformer model version.
 
     ``submit()`` is thread-safe and returns a handle whose ``stream()``
-    yields tokens as decode steps complete. The engine thread runs
-    admit → step forever; ``close()`` drains it. An option left ``None``
+    yields tokens as decode steps complete. The engine thread runs the
+    round loop forever (``run_once``), one round ahead of the tokens it
+    reads; ``close()`` drains it. An option left ``None``
     takes the default written here: the engine reads no environment
     (``serving/server.py`` reads a deployment's sizing into keywords).
     """
@@ -374,7 +477,6 @@ class DecodeEngine:
         # programs built or loaded, process-wide: read at a round's and
         # an admission's two ends (``compiles`` on their spans)
         xprof.install_compile_count()
-        self._compiles0 = 0
         # the request-lifecycle ledger (docs/OBSERVABILITY.md): phase
         # marks ride the clock reads this file already takes; the
         # process-wide default joins the edge's phases by trace id
@@ -426,6 +528,7 @@ class DecodeEngine:
         # exported at 0 from the start: "never recovered" must be a
         # readable fact, not an absent series
         _recoveries_c.inc(0, model=self.name)
+        _rounds_ahead_c.inc(0, model=self.name)
         # an obs.xprof.HbmSampler sampled once per admit cycle, so that
         # kftpu_hbm_bytes{model=...} shows admission's watermark
         # (weights + KV + prefill spike); no series on CPU backends
@@ -441,6 +544,9 @@ class DecodeEngine:
         self._lock = threading.Lock()  # guards _active between admit/step
         # host-side per-slot sampling state, padded to the batch
         self._tokens = np.zeros((slots,), np.int32)
+        # armed since the last launch: its next input token is the host's
+        # (``_tokens``), the others' the last token of the round in flight
+        self._fresh = np.zeros((slots,), bool)
         self._seeds = np.zeros((slots,), np.int32)
         self._stepidx = np.zeros((slots,), np.int32)
         self._temps = np.zeros((slots,), np.float32)
@@ -452,6 +558,9 @@ class DecodeEngine:
         self._tokens_exported = 0  # of them in kftpu_engine_tokens_total
         self.greedy_steps = 0  # steps served by the argmax fast path
         self.recoveries = 0      # cache rebuild-and-replay events
+        # rounds enqueued and not yet read, oldest first (at most two,
+        # inside a cycle); guarded by _lock, as _active is
+        self._flights: List[_Flight] = []
 
         self._sample_rows = self._build_sampler()
         # the cache manager: the ONE place that knows "rows or pages"
@@ -467,6 +576,12 @@ class DecodeEngine:
         else:
             self._kv = RowCache(self, **prefix)
         self._step, self._step_greedy = self._build_steps(self._kv.cfg)
+        self._splice = self._build_splice()
+        # what a launch with no round in flight splices its (host)
+        # tokens over: every row is fresh then (a transfer, where
+        # jnp.zeros would build a program of its own)
+        self._no_round = _put(np.zeros((self.steps_per_sync, slots),
+                                       np.int32))
         if precompile:
             self._precompile_steps()
         if autostart:
@@ -557,18 +672,49 @@ class DecodeEngine:
         return (jax.jit(_step, donate_argnums=(1,)),
                 jax.jit(_step_greedy, donate_argnums=(1,)))
 
+    def _build_splice(self):
+        """The engine's own splice program, through which every round's
+        input tokens pass, so that each step program sees one kind of
+        token argument (on a mesh: replicated, as the host's were)."""
+
+        def _splice_tokens(fresh, host, toks):
+            """A round's (slots,) input tokens: the host's where
+            ``fresh`` (a row armed since the last launch), else the last
+            of the (K, slots) tokens of the round enqueued before it,
+            which never leave the device. One program for every
+            admission size and prompt bucket."""
+            return jnp.where(fresh, host, toks[-1])
+
+        if self.mesh is None:
+            return jax.jit(_splice_tokens)
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        return jax.jit(_splice_tokens, out_shardings=NamedSharding(
+            self.mesh, PartitionSpec()))
+
+    def _round_tokens(self, prev: Optional["_Flight"]) -> jax.Array:
+        """The next round's (slots,) input tokens: the host's for the
+        rows armed since the last launch (every row, with nothing in
+        flight), the last tokens of ``prev`` for the others."""
+        fresh = self._fresh if prev is not None else np.ones_like(self._fresh)
+        return self._splice(_put(fresh), _put(self._tokens),
+                            prev.toks if prev is not None else self._no_round)
+
     def _precompile_steps(self) -> None:
         """Run BOTH step programs once on the empty batch so the
         greedy↔sampled dispatch switch never stalls in-flight streams
         on a mid-serving XLA compile. Every slot is idle, so the junk
-        tokens land in rows the next insert fully overwrites."""
+        tokens land in rows the next insert fully overwrites. The
+        splice of a round's input tokens is built here too."""
         kv = self._kv
         vec_i = jnp.zeros((self.slots,), jnp.int32)
         ones_f = jnp.ones((self.slots,), jnp.float32)
         with self._mesh_ctx():
-            kv.cache, _, _ = self._step_greedy(self._params, kv.cache, vec_i)
+            tokens = self._round_tokens(None)
+            kv.cache, _, _ = self._step_greedy(self._params, kv.cache,
+                                               tokens)
             kv.cache, _, _ = self._step(
-                self._params, kv.cache, vec_i, vec_i, vec_i, ones_f,
+                self._params, kv.cache, tokens, vec_i, vec_i, ones_f,
                 vec_i, ones_f)
 
     # -- public API --------------------------------------------------------
@@ -655,10 +801,12 @@ class DecodeEngine:
 
     @property
     def active_count(self) -> int:
-        """Slots serving a stream: decoding, or mid-admission (a
-        chunk-prefilling prompt holds pages and a slot either way)."""
+        """Streams being served: decoding (a stream whose last round is
+        enqueued has left its slot but not yet delivered), or
+        mid-admission (a chunk-prefilling prompt holds pages and a slot
+        either way)."""
         with self._lock:
-            n = sum(s is not None for s in self._active)
+            n = len(self._live_streams())
         return n + self._kv.in_admission
 
     @property
@@ -745,7 +893,9 @@ class DecodeEngine:
                 attrs={"model": self.name,
                        "ttft_ms": round((t - req.t_submit) * 1000.0, 3)})
         self._emit(st, token, t)
+        st.launched = st.produced
         self._tokens[slot] = token
+        self._fresh[slot] = True
         self._seeds[slot] = req.seed
         self._stepidx[slot] = fold + 1
         self._temps[slot] = req.temperature
@@ -774,6 +924,7 @@ class DecodeEngine:
         done = (slot.produced >= slot.req.max_new or
                 (slot.req.eos_id is not None and token == slot.req.eos_id))
         if done:
+            slot.done = True
             slot.req.out.put(_END)
             # last token: fold the request's record (histograms +
             # flight ring) on the same already-read timestamp
@@ -781,147 +932,221 @@ class DecodeEngine:
         return done
 
     def run_once(self, timeout: float = 0.1) -> bool:
-        """One admit (+ prefill-chunk) + step cycle; True if any work
-        happened. The background loop calls this forever; tests call it
-        directly (``autostart=False``) for deterministic schedules. A
-        donating device call that fails mid-decode is recovered in place
-        (cache rebuild + slot replay) while the recovery budget lasts.
+        """One cycle of the round loop; True if any work happened. The
+        background loop calls this forever; tests call it directly
+        (``autostart=False``) for deterministic schedules.
 
-        A cycle that did work is one ``engine.round`` span: the loop
-        reads its phase boundaries itself (``marks``: admit, then step /
-        sync / emit as the round reaches them) and names the same
-        phases on the profiler's host timeline, admission by the leaf
-        it is in (``engine.admit.host`` but where an ``_Admission`` has
-        entered ``launch``, ``read`` or ``insert``), so every instant of
-        the engine thread lies inside exactly one ``engine.*``
-        annotation."""
+        The device runs a round ahead of the host. While round n runs,
+        the host has all that round n + 1 needs (each row's last token
+        is round n's, on the device; its step index moves on by K; its
+        sampling parameters hold), so a cycle
+
+        1. launches round n + 1 on round n's last tokens;
+        2. launches this cycle's admissions, whose prefills queue behind
+           round n + 1 on the device;
+        3. reads round n's tokens and counts in one transfer;
+        4. emits them, and retires the streams that ended, while round
+           n + 1 runs;
+        5. lands the admissions: reads their first tokens and arms their
+           slots, which join the next launch with those tokens spliced
+           into the device's token vector (``_build_splice``).
+
+        With nothing in flight (an idle engine, or after a recovery) a
+        cycle admits first, then launches the first round and the one
+        after it, and reads the first. A row that ends by EOS in round
+        n also runs in round n + 1, its tokens there discarded as those
+        past EOS inside a round are. A stream leaves its slot at the
+        launch that covers its budget, so the slot takes an admission
+        behind that round. A cycle after which no stream is live leaves
+        nothing in flight. A donating device call that fails is
+        recovered in place (cache rebuild, every surviving stream
+        replayed from what it has emitted) while the recovery budget
+        lasts.
+
+        A cycle that did work is one ``engine.round`` span (``_Round``):
+        its phases tile it, every instant of the engine thread lies
+        inside exactly one ``engine.*`` annotation, and ``rows``, ``k``
+        and ``ahead`` are those of the round it read."""
         kv = self._kv
-        marks = [self.clock()]
         self._admitted = 0
-        self._compiles0 = xprof.compiles_total()
-        # the wait phase: only an engine with nothing to step or
-        # prefill may block on its queue, and that time is no work
-        head, wait_s = (self._wait_pending(timeout) if self._idle()
-                        else (None, 0.0))
-        self._enter_leaf("engine.admit.host")
+        steady = bool(self._flights)
+        rnd = _Round(self, "step" if steady else "admit")
+        head = None
+        if not steady and self._idle():
+            # only an engine with nothing to step or prefill may block
+            # on its queue, and that time is no work
+            head, rnd.wait_s = self._wait_pending(timeout)
+        launched = False
+        where = "decode step"
         try:
-            try:
-                worked = self._admit(head)
-            except _CacheInvalidated:
-                raise  # the close protocol, whatever the manager
-            except Exception:  # noqa: BLE001 — donated cache consumed
-                # the manager's error scope: admission that donates the
-                # cache recovers under the step's budget; the other kind
-                # has handled its errors per request
-                if not kv.admission_recovers:
-                    raise
-                log.exception("admission/prefill failed")
-                if self._maybe_recover("admission/prefill"):
-                    self._record_round(marks, wait_s)
-                    return True
-                raise
-            with self._lock:
-                active = [(i, s) for i, s in enumerate(self._active)
-                          if s is not None]
-            # greedy rows ignore seeds/filters entirely, so when EVERY
-            # active slot is greedy the cheap argmax step is bit-identical
-            # and skips the per-row sampler (vocab sort) each token
-            all_greedy = all(s.req.temperature <= 0.0 for _, s in active)
-        finally:
-            self._leave_leaf()
-        if not active:
-            if worked:
-                self._record_round(marks, wait_s)
-            return worked
-        t_step0 = self.clock()
-        marks.append(t_step0)
-        try:
-            with self._annotate("engine.step"):
-                # readying the cache may donate it (page growth arms
-                # device rows) — same recovery scope as the step itself
-                kv.before_step(active)
-                with self._mesh_ctx():
-                    if all_greedy:
-                        kv.cache, toks, stats = self._step_greedy(
-                            self._params, kv.cache,
-                            jnp.asarray(self._tokens))
-                    else:
-                        kv.cache, toks, stats = self._step(
-                            self._params, kv.cache,
-                            jnp.asarray(self._tokens),
-                            jnp.asarray(self._seeds),
-                            jnp.asarray(self._stepidx),
-                            jnp.asarray(self._temps),
-                            jnp.asarray(self._topk),
-                            jnp.asarray(self._topp))
-            # the K steps are enqueued; what follows is the wait for
-            # them and the device→host read
-            marks.append(self.clock())
-            with self._annotate("engine.sync"):
-                # (K, B); the transfer surfaces device-side failures
-                # HERE, while recovery can still replay. The routed
-                # layers' counts come in the SAME readback: device_get
-                # starts every leaf's copy before it waits for one (an
-                # np.asarray a leaf pays one round trip each, in turn;
-                # np.sum of a device array would build a reduction)
-                toks, stats = jax.device_get((toks, stats))
-                moe = {name: int(v.sum()) for name, v in stats.items()}
+            if steady:
+                launched = self._launch(rnd)
+            where = "admission/prefill"
+            worked = self._admit_stage(rnd, head, land=not steady)
+            if not steady:
+                where = "decode step"
+                launched = self._launch(rnd)
+                if launched:
+                    self._launch(rnd)          # the round after it, ahead
+            if self._flights:
+                where = "decode step"
+                flight, toks, counts, t_read = self._read(rnd)
+                # emission only delivers; its retirements may donate
+                where = "slot retirement"
+                self._emit_round(flight, toks, counts, t_read, rnd)
+            if kv.landing:
+                where = "admission/prefill"
+                self._admit_stage(rnd, None, launch=False, land=True)
+        except _CacheInvalidated:
+            raise  # the close protocol, whatever the manager
         except Exception:  # noqa: BLE001 — donated cache consumed
-            log.exception("decode step failed")
-            if self._maybe_recover("decode step"):
-                self._record_round(marks, wait_s, rows=len(active))
+            # the manager's error scope: admission that donates the cache
+            # recovers under the step's budget; the other kind has
+            # handled its errors per request
+            if where == "admission/prefill" and not kv.admission_recovers:
+                raise
+            log.exception("%s failed", where)
+            if self._maybe_recover(where):
+                self._record_round(rnd)
                 return True
             raise
-        # ONE wall-clock read per sync batch, after the host transfer:
-        # the moment every token of this chunk became user-visible. The
-        # emit loop below stamps K×B tokens with it — per-token emit
-        # takes zero additional clock reads (the ledger contract)
-        t_step_end = self.clock()
-        marks.append(t_step_end)
+        with self._lock:
+            if not self._live_streams():
+                # what is still in flight delivers to no one
+                self._flights.clear()
+        worked = worked or launched or rnd.k > 0
+        if worked:
+            self._record_round(rnd)
+        return worked
+
+    def _admit_stage(self, rnd: _Round, head: Optional[_Request], *,
+                     launch: bool = True, land: bool = False) -> bool:
+        """Admission's stretch of the cycle, in its host leaf: launch
+        what is pending into the free slots, and/or land what was
+        launched (read the first tokens, arm the slots)."""
+        rnd.enter("admit")
+        self._enter_leaf("engine.admit.host")
+        try:
+            worked = self._admit(head) if launch else False
+            if land:
+                self._kv.land()
+        finally:
+            self._leave_leaf()
+        return worked
+
+    def _launch(self, rnd: _Round) -> bool:
+        """Enqueue the next round over every stream that needs tokens
+        past those already launched; False where none does. Its input
+        tokens are the host's where nothing is in flight, else the last
+        tokens of the round enqueued before it with the rows armed since
+        spliced in. A stream whose budget this round covers leaves its
+        slot here (the manager retires it behind the round)."""
+        kv = self._kv
+        with self._lock:
+            rows = [(i, s) for i, s in enumerate(self._active)
+                    if s is not None]
+            prev = self._flights[-1] if self._flights else None
+        if not rows:
+            return False
+        t_launch = rnd.enter("step")
+        K = self.steps_per_sync
+        # greedy rows ignore seeds/filters entirely, so when EVERY row is
+        # greedy the cheap argmax step is bit-identical and skips the
+        # per-row sampler (vocab sort) each token
+        greedy = all(s.req.temperature <= 0.0 for _, s in rows)
+        with self._annotate("engine.step"):
+            # readying the cache may donate it (page growth arms device
+            # rows) — same recovery scope as the step itself
+            kv.before_step(rows)
+            with self._mesh_ctx():
+                tokens = self._round_tokens(prev)
+                if greedy:
+                    kv.cache, toks, stats = self._step_greedy(
+                        self._params, kv.cache, tokens)
+                else:
+                    kv.cache, toks, stats = self._step(
+                        self._params, kv.cache, tokens, _put(self._seeds),
+                        _put(self._stepidx), _put(self._temps),
+                        _put(self._topk), _put(self._topp))
+            self._stepidx += K
+            self._fresh[:] = False
+            covered = []
+            with self._lock:
+                self._flights.append(_Flight(rows, toks, stats, greedy,
+                                             prev is not None, t_launch))
+                for i, s in rows:
+                    s.launched += K
+                    if s.launched >= s.req.max_new:
+                        self._active[i] = None
+                        covered.append(i)
+            for i in covered:
+                kv.retire(i)
+        return True
+
+    def _read(self, rnd: _Round) -> tuple:
+        """Wait for the oldest round in flight and read its (K, slots)
+        tokens and what the model's layers counted in ONE transfer (the
+        read surfaces device-side failures HERE, while recovery can
+        still replay). Returns the round, its tokens, the counts and
+        the moment they became user-visible."""
+        flight = self._flights[0]
+        rnd.enter("sync")
+        with self._annotate("engine.sync"):
+            # device_get starts every leaf's copy before it waits for one
+            # (an np.asarray a leaf pays one round trip each, in turn;
+            # np.sum of a device array would build a reduction)
+            toks, stats = jax.device_get((flight.toks, flight.stats))
+            counts = {name: int(v.sum()) for name, v in stats.items()}
+        # ONE wall-clock read per round read: the emit loop stamps K×B
+        # tokens with it — per-token emit takes zero clock reads (the
+        # ledger contract)
+        return flight, toks, counts, rnd.enter("emit")
+
+    def _emit_round(self, flight: _Flight, toks: np.ndarray, counts: dict,
+                    t_read: float, rnd: _Round) -> None:
+        """Hand out a round's tokens to its streams, end those that
+        reached EOS or their budget (tokens past that are discarded) and
+        retire the slots they still held."""
+        kv = self._kv
         with self._annotate("engine.emit"):
             K = toks.shape[0]
+            rnd.rows, rnd.k, rnd.counts = len(flight.rows), K, counts
+            rnd.greedy, rnd.ahead = flight.greedy, flight.ahead
             self.steps_total += K
-            if all_greedy:
+            if flight.greedy:
                 self.greedy_steps += K
             _steps_total.inc(K, model=self.name)
-            self._stepidx += K
-            self._tokens = toks[-1].copy()
-            kv.after_step(active, K, t_step0, t_step_end)
+            with self._lock:
+                self._flights.pop(0)
+            kv.after_step(flight.rows, K, flight.t_launch, t_read)
             retired: List[int] = []
-            for i, slot in active:
+            for i, slot in flight.rows:
+                if slot.done:       # ended in the round before this one
+                    continue
                 for t in range(K):
                     tok = int(toks[t, i])
-                    self._emit(slot, tok, t_step_end)
-                    if self._finished(slot, tok, t_step_end):
-                        # tokens past EOS/budget in this chunk are
-                        # discarded
+                    self._emit(slot, tok, t_read)
+                    if self._finished(slot, tok, t_read):
                         with self._lock:
-                            self._active[i] = None
-                        retired.append(i)
+                            if self._active[i] is slot:
+                                self._active[i] = None
+                                retired.append(i)
                         # the request's decode phase is over: one span
                         # with the token count — the per-request cost
                         # record
                         self.tracer.record(
                             "engine.decode", start=slot.t_decode0,
-                            end=t_step_end, parent=slot.req.ctx,
+                            end=t_read, parent=slot.req.ctx,
                             attrs={"model": self.name,
                                    "tokens": slot.produced})
                         break
             # retirement may disarm rows with a donating call: it runs
             # AFTER the emit loop, so a device failure lands with the
-            # emitted/fold accounting complete and recovery replays the
+            # emitted accounting complete and recovery replays the
             # surviving streams (not the close protocol failing all)
-            try:
-                for i in retired:
-                    kv.retire(i)
-            except Exception:  # noqa: BLE001 — donated cache consumed
-                log.exception("slot retirement failed")
-                if not self._maybe_recover("slot retirement"):
-                    raise
+            for i in retired:
+                kv.retire(i)
             _occupancy.set(self.active_count, model=self.name)
-        self._record_round(marks, wait_s, rows=len(active), k=K,
-                           greedy=all_greedy, moe=moe)
-        return True
 
     def _annotate(self, name: str):
         """``name`` on the profiler's host timeline while the block
@@ -943,53 +1168,67 @@ class DecodeEngine:
         if leaf is not None:
             leaf.__exit__(None, None, None)
 
-    def _record_round(self, marks: List[float], wait_s: float, *,
-                      rows: int = 0, k: int = 0, greedy: bool = False,
-                      moe: Optional[dict] = None) -> None:
-        """Close the round that ``marks`` opened: one ``engine.round``
-        span whose five phase durations tile ``[start, end]`` (a phase
-        the round never reached is 0; one that a recovery cut short runs
-        to the end; ``wait_s`` is carved out of admission's stretch),
-        and the same seconds into
-        ``kftpu_engine_round_seconds_total{phase}``. ``moe`` is what a
-        model's layers counted over the round's steps (the routed ones
-        ``experts_hit`` and ``routed_pairs``, the sparse-attention ones
-        ``index_scored`` and ``index_selected``, the step attention over
-        dense K / V ``kv_blocks_read`` and ``kv_blocks_reserved``).
-        ``compiles`` is the programs the process built or loaded while
-        the round ran, and the tokens the round emitted (first tokens
-        armed in admission included) reach ``kftpu_engine_tokens_total``
-        here, once."""
-        bounds = marks + [self.clock()]
-        secs = dict.fromkeys(_ROUND_PHASES, 0.0)
-        for phase, t_a, t_b in zip(_ROUND_PHASES[1:], bounds, bounds[1:]):
-            secs[phase] = t_b - t_a
-        secs["wait"] = wait_s
-        secs["admit"] -= wait_s
+    def _record_round(self, rnd: _Round) -> None:
+        """Close the round ``rnd`` opened: one ``engine.round`` span whose
+        five phase durations tile ``[start, end]`` (a phase the round
+        never reached is 0; one that a recovery cut short runs to the
+        end; the wait is carved out of admission's first stretch), and
+        the same seconds into ``kftpu_engine_round_seconds_total{phase}``.
+        ``counts`` is what a model's layers counted over the steps of the
+        round read (the routed ones ``experts_hit`` and ``routed_pairs``,
+        the sparse-attention ones ``index_scored`` and
+        ``index_selected``, the step attention over dense K / V
+        ``kv_blocks_read`` and ``kv_blocks_reserved``). ``ahead`` says
+        the round read was launched before the round before it was read
+        (``kftpu_engine_rounds_ahead_total``). ``compiles`` is the
+        programs the process built or loaded while the cycle ran, and
+        the tokens the cycle emitted (first tokens armed in admission
+        included) reach ``kftpu_engine_tokens_total`` here, once."""
+        end = self.clock()
+        secs = rnd.secs
+        secs[rnd.phase] += end - rnd._t
+        secs["wait"] = rnd.wait_s
+        secs["admit"] -= rnd.wait_s
         attrs = {"model": self.name, "round": self.rounds_total,
-                 "rows": rows, "k": k, "admitted": self._admitted,
-                 "greedy": greedy,
-                 "compiles": xprof.compiles_total() - self._compiles0}
+                 "rows": rnd.rows, "k": rnd.k, "admitted": self._admitted,
+                 "greedy": rnd.greedy, "ahead": rnd.ahead,
+                 "compiles": xprof.compiles_total() - rnd.compiles0}
         _tokens_total.inc(self.tokens_total - self._tokens_exported,
                           model=self.name)
         self._tokens_exported = self.tokens_total
         for phase, sec in secs.items():
             attrs[f"{phase}_s"] = sec
             _round_seconds.inc(sec, model=self.name, phase=phase)
-        for counted, total in (moe or {}).items():
+        for counted, total in rnd.counts.items():
             attrs[counted] = total
             counter, labels = _STEP_COUNTERS[counted]
             counter.inc(total, model=self.name, **labels)
-        self.tracer.record("engine.round", start=bounds[0],
-                           end=bounds[-1], parent=self._run_ctx,
-                           attrs=attrs)
+        if rnd.ahead:
+            _rounds_ahead_c.inc(model=self.name)
+        self.tracer.record("engine.round", start=rnd.start, end=end,
+                           parent=self._run_ctx, attrs=attrs)
         self.rounds_total += 1
 
+    def _live_streams(self) -> List[tuple]:
+        """``(slot, stream)`` of every stream not yet ended: those that
+        hold a slot first, then those whose last round is in flight (the
+        slot they stepped in may hold the next request already). Call
+        under ``_lock``."""
+        live = [(i, s) for i, s in enumerate(self._active) if s is not None]
+        seen = {id(s) for _, s in live}
+        for flight in self._flights:
+            for i, s in flight.rows:
+                if not s.done and id(s) not in seen:
+                    seen.add(id(s))
+                    live.append((i, s))
+        return live
+
     def _idle(self) -> bool:
-        """Nothing to step and nothing mid-admission: the one state in
-        which the engine thread may block on its queue."""
+        """Nothing to step, nothing in flight and nothing mid-admission:
+        the one state in which the engine thread may block on its
+        queue."""
         with self._lock:
-            if any(s is not None for s in self._active):
+            if self._flights or any(s is not None for s in self._active):
                 return False
         return not (self._kv.in_admission or self._kv.waiting)
 
@@ -1049,18 +1288,38 @@ class DecodeEngine:
         return True
 
     def _rebuild_and_replay(self) -> None:
+        """Every stream not yet ended resumes from what it has emitted:
+        the rounds in flight are dropped unread, and a stream's next
+        token is sampled at fold index ``produced`` (its tokens' folds
+        run 0, 1, …), so none is lost or sent twice. A stream whose last
+        round was in flight goes back to its slot, or to a free one
+        where an admission took it; with none free it fails retryably."""
         with self._lock:
-            live = [(i, s) for i, s in enumerate(self._active)
-                    if s is not None]
+            live = self._live_streams()
             self._active = [None] * self.slots
-        replays = [(i, st.req,
-                    np.concatenate([st.req.prompt,
-                                    np.asarray(st.emitted, np.int32)]),
-                    st.produced, int(self._stepidx[i]))
-                   for i, st in live]
+            self._flights.clear()
+        # the manager's fresh cache, and the admissions it held mid-way
+        held = self._kv.reset()
+        taken = {args[0] for args in held}
+        replays = []
+        for i, st in live:
+            if i in taken:
+                free = [j for j in range(self.slots) if j not in taken]
+                if not free:
+                    log.warning("slot %d's stream has no slot to replay "
+                                "into; failing it retryably", i)
+                    st.done = True
+                    self._fail(st.req, EngineClosed(
+                        "engine cache recovered; stream evicted — retry"),
+                        self.clock())
+                    continue
+                i = free[0]
+            taken.add(i)
+            replays.append((i, st.req, np.concatenate(
+                [st.req.prompt, np.asarray(st.emitted, np.int32)]),
+                st.produced, st.produced))
         # the live streams first, then whatever the manager held
-        # mid-admission (its reset hands those back)
-        for args in replays + self._kv.reset():
+        for args in replays + held:
             self._kv.replay(*args)
 
     def _drain(self, why: str) -> float:
@@ -1071,8 +1330,9 @@ class DecodeEngine:
         after this none can enqueue. Returns when the streams ended."""
         with self._lock:
             self._stop.set()
-            held = [s.req for s in self._active if s is not None]
+            held = [s.req for _, s in self._live_streams()]
             self._active = [None] * self.slots
+            self._flights.clear()
             held.extend(self._kv.drain())
             while (req := self._next_pending()) is not None:
                 held.append(req)

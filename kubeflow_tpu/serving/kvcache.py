@@ -7,11 +7,13 @@ device cache tree, every compiled program that writes it other than the
 step, the prefix store and the admission scheduler of its kind, and the
 host accounting only it needs (:class:`RowCache`, :class:`PagedCache`).
 
-The seam is what the loop calls: ``check_submit``, ``admit``,
-``before_step``, ``after_step``, ``retire``, ``reset`` + ``replay``
-(recovery), ``drain``, ``snapshot``, the ``in_admission`` / ``waiting``
-counts and ``admission_recovers`` (the manager's error scope). A manager
-calls back the engine's host services (clock, tracer, request ledger,
+The seam is what the loop calls: ``check_submit``, ``admit`` (which
+launches) and ``land`` (which reads the first tokens and arms the
+slots, once the loop has read the round before), ``before_step``,
+``after_step``, ``retire``, ``reset`` + ``replay`` (recovery),
+``drain``, ``snapshot``, the ``in_admission`` / ``waiting`` counts,
+``landing`` and ``admission_recovers`` (the manager's error scope). A
+manager calls back the engine's host services (clock, tracer, request ledger,
 ``_note_queue_wait``, ``_next_pending``, ``_arm_slot``, ``_fail``, and
 ``_open_admission``: :class:`RowCache` runs each device admission inside
 one such record and tells it which stretch it is in); the engine imports
@@ -27,7 +29,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import logging
-from typing import Any, List, Optional
+from typing import Any, Callable, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -136,6 +138,22 @@ def _no_sampling() -> tuple:
     return (jnp.float32(0.0), jnp.int32(0), jnp.float32(1.0), jnp.int32(0))
 
 
+@dataclasses.dataclass
+class _Landing:
+    """An admission whose programs are enqueued and whose first tokens
+    the host has not read: ``finish`` reads them and arms the slots;
+    ``replays`` are the ``replay`` arguments that admit its requests
+    anew after a recovery; ``adm`` is its record, where it keeps one."""
+
+    finish: Callable[[], None]
+    replays: List[tuple]
+    adm: Any = None
+
+    def abandon(self) -> None:
+        if self.adm is not None:
+            self.adm.abandon()
+
+
 class _CacheManager:
     """What both cache policies share: the model's leaf contract, the
     cache tree at ``slots`` rows (sharded over the engine's mesh), the
@@ -170,6 +188,8 @@ class _CacheManager:
         self._prefilling: "collections.OrderedDict[int, _PrefillJob]" = \
             collections.OrderedDict()
         self._waiting: collections.deque = collections.deque()
+        # admissions launched and not landed, in launch order
+        self._landing: List[_Landing] = []
         # the decode cache shape at batch 1: eval_shape on prefill gives
         # the layout without running it
         probe = jnp.zeros((1, 1), jnp.int32)
@@ -252,7 +272,13 @@ class _CacheManager:
 
     @property
     def in_admission(self) -> int:
-        return len(self._prefilling)
+        return len(self._prefilling) + sum(len(a.replays)
+                                           for a in self._landing)
+
+    @property
+    def landing(self) -> bool:
+        """Admissions are launched and wait to be landed."""
+        return bool(self._landing)
 
     @property
     def waiting(self) -> int:
@@ -272,17 +298,43 @@ class _CacheManager:
         """The K steps of ``[t0, t1]`` are on the host."""
 
     def retire(self, slot: int) -> None:
-        """The slot's stream is over."""
+        """The slot's stream is over, or its last round is enqueued: the
+        slot takes its next request behind that round."""
+
+    def land(self) -> None:
+        """Land the launched admissions in launch order: read their
+        first tokens and arm their slots. One that raises stays queued,
+        so that a recovery replays its requests."""
+        while self._landing:
+            landing = self._landing.pop(0)
+            try:
+                landing.finish()
+            except _CacheInvalidated:
+                raise
+            except Exception:
+                self._landing.insert(0, landing)
+                raise
+
+    def _forget_landings(self) -> List[tuple]:
+        """Drop the launched admissions unread (their records closed as
+        abandoned); returns their ``replay`` arguments."""
+        replays = []
+        for landing in self._landing:
+            landing.abandon()
+            replays.extend(landing.replays)
+        self._landing = []
+        return replays
 
     def reset(self) -> List[tuple]:
         """Recovery: a fresh cache in place of the consumed one. Returns
         the ``replay`` arguments of admissions that were under way."""
         self.cache = self._fresh_cache()
-        return []
+        return self._forget_landings()
 
     def drain(self) -> list:
         """Hand back, and forget, every request held mid-admission."""
-        held = [j.req for j in self._prefilling.values()]
+        held = [args[1] for args in self._forget_landings()]
+        held.extend(j.req for j in self._prefilling.values())
         held.extend(self._waiting)
         self._prefilling.clear()
         self._waiting.clear()
@@ -545,13 +597,15 @@ class RowCache(_CacheManager):
         return pcache, width
 
     def _admit_row(self, req, slot: int) -> None:
-        """Prefill the request's prompt and write it into ``slot``; a
-        failure surfaces to THIS caller only. One device admission, its
-        stretches named in the order this path runs them: the programs'
-        launch, the insert's dispatch behind them, then the wait for the
-        first token. The request's own ``engine.admit`` (queue's end to
-        insert dispatched) and ``engine.prefill`` (the launch) spans are
-        recorded post hoc on the same boundaries, as the batch path's."""
+        """Launch the prefill of the request's prompt and its insert into
+        ``slot``, and queue the landing that reads the first token and
+        arms the slot; a failure surfaces to THIS caller only. One device
+        admission, its stretches named in the order this path runs them:
+        the programs' launch, the insert's dispatch behind them, then the
+        wait for the first token, which the landing ends. The request's
+        own ``engine.admit`` (queue's end to insert dispatched) and
+        ``engine.prefill`` (the launch) spans are recorded post hoc on
+        the same boundaries, as the batch path's."""
         eng = self.eng
         S, Smax = int(req.prompt.size), self.cfg.max_seq_len
         admit = {"model": eng.name, "slot": slot, "prompt_tokens": S,
@@ -604,16 +658,29 @@ class RowCache(_CacheManager):
                     t_insert = adm.enter("insert")
                     self.cache = self._insert(self.cache, row_cache,
                                               jnp.int32(slot))
-                t_read = adm.enter("read")
-                # the prefill-sampled first token must surface NOW —
-                # emitting it is what makes TTFT one prefill + one step
-                tok = int(tok)  # tpulint: disable=TPU017
-                t_armed = adm.enter("host")
-                eng._arm_slot(req, slot, tok, t_armed)
-                note_spans(t_armed)
+                t_read = adm.suspend()
         except Exception as e:  # noqa: BLE001 — surface to the caller
             note_spans(adm.end, adm.status)
             eng._fail(req, e, adm.end)
+            return
+
+        def land() -> None:
+            try:
+                with adm:
+                    adm.resume()
+                    # the prefill-sampled first token must surface NOW —
+                    # emitting it is what makes TTFT one prefill + one
+                    # step
+                    first = int(tok)  # tpulint: disable=TPU017
+                    t_armed = adm.enter("host")
+                    eng._arm_slot(req, slot, first, t_armed)
+                    note_spans(t_armed)
+            except Exception as e:  # noqa: BLE001 — surface to the caller
+                note_spans(adm.end, adm.status)
+                eng._fail(req, e, adm.end)
+
+        self._landing.append(
+            _Landing(land, [(slot, req, req.prompt, 0, 0)], adm))
 
     def _continue_row(self, req, adm) -> tuple:
         """A row admission's launch on the prefix path: the stored
@@ -649,15 +716,16 @@ class RowCache(_CacheManager):
         rows are length-1 junk nothing reads or inserts. Token-identical
         to the row path: same ragged per-row lengths, same
         ``fold_in(key(seed), 0)`` sampling. One device admission:
-        padding on the host, the launch, the wait for the first tokens,
-        the insert's dispatch, arming."""
+        padding on the host, the launch; then its landing: the wait for
+        the first tokens, the insert's dispatch, arming."""
         eng = self.eng
         k = len(members)
         bb = pow2_bucket(k, min(eng.slots, eng.admit_batch_max))
         reqs, slot_list = zip(*members)
-        with eng._open_admission(
-                "batch", rows=k, rows_padded=bb, width=bucket,
-                prompt_tokens=int(sum(r.prompt.size for r in reqs))) as adm:
+        adm = eng._open_admission(
+            "batch", rows=k, rows_padded=bb, width=bucket,
+            prompt_tokens=int(sum(r.prompt.size for r in reqs)))
+        with adm:
             for req in reqs:
                 eng._note_queue_wait(req)
 
@@ -689,11 +757,40 @@ class RowCache(_CacheManager):
                     eng._params, jnp.asarray(prompts), jnp.asarray(lens),
                     jnp.asarray(temps), jnp.asarray(tks),
                     jnp.asarray(tps), jnp.asarray(seeds))
-                adm.enter("read")
+            adm.suspend()
+
+        def land() -> None:
+            try:
+                self._land_batch(adm, members, toks, bcache, lens,
+                                 slot_ids, valid, bucket, p0)
+            except _CacheInvalidated:
+                raise  # run_once/_loop closes the engine
+            except Exception:  # noqa: BLE001
+                # as a failed launch: retry each member through the row
+                # path, per request (the cache is intact: the prefill
+                # is read before any donation)
+                log.exception("batched admission failed; retrying %d "
+                              "request(s) individually", k)
+                for req, slot in members:
+                    self._admit_row(req, slot)
+
+        self._landing.append(_Landing(
+            land, [(slot, req, req.prompt, 0, 0) for req, slot in members],
+            adm))
+
+    def _land_batch(self, adm, members, toks, bcache, lens, slot_ids,
+                    valid, bucket: int, p0: float) -> None:
+        """A batch admission's landing: its first tokens, then the
+        inserts of its rows, then each member armed."""
+        eng = self.eng
+        k = len(members)
+        with adm:
+            adm.resume()
+            with eng._mesh_ctx():
                 # force completion (the host needs the tokens anyway)
                 # BEFORE the donating inserts: a device-side prefill
                 # failure must surface while the cache is intact, so
-                # that admit's row-path fallback retries against a live
+                # that the row-path fallback retries against a live
                 # engine
                 toks = np.asarray(toks)  # tpulint: disable=TPU017 — deliberate barrier, see above
                 p1 = adm.enter("insert")
@@ -705,7 +802,7 @@ class RowCache(_CacheManager):
                     # the cache: fail the chunk retryably and escalate
                     # so that the loop closes the engine
                     t_fail = eng.clock()
-                    for req in reqs:
+                    for req, _ in members:
                         eng._fail(req, EngineClosed(
                             "engine cache invalidated during admission"),
                             t_fail)
@@ -748,7 +845,9 @@ class _PrefillJob:
     fold0: int = 0
     produced0: int = 0
     store_prefix: int = 0     # prefix tokens to trie-pin after prefill
-    last_tok: int = 0         # sampled next token, set by the final chunk
+    # the sampled next token: the final chunk's, on the device until the
+    # job lands
+    last_tok: Any = 0
 
 
 class PagedCache(_CacheManager):
@@ -872,7 +971,9 @@ class PagedCache(_CacheManager):
     def before_step(self, active: List[tuple]) -> None:
         """Map pages covering each active slot's next K writes (drawing
         down its reservation) and re-arm rows whose tables changed: page
-        growth tracks LIVE tokens. The arm donates: the step's scope."""
+        growth tracks LIVE tokens. The arm donates: the step's scope.
+        The host's positions move on by K here, at the launch: the next
+        round is launched before this one is read."""
         eng = self.eng
         for i, st in active:
             need = min(int(self._pos_host[i]) + eng.steps_per_sync,
@@ -886,11 +987,11 @@ class PagedCache(_CacheManager):
                 self._export_page_gauges()
                 eng.rledger.stall(st.req.rid, reqobs.KV_FAULT, t0,
                                   eng.clock())
+        self._pos_host[[i for i, _ in active]] += eng.steps_per_sync
 
     def after_step(self, active: List[tuple], k: int, t0: float,
                    t1: float) -> None:
         eng = self.eng
-        self._pos_host[[i for i, _ in active]] += k
         # one span per shared step: the burst-interleave evidence (chunk
         # spans between step spans bound any decode stall)
         eng.tracer.record(
@@ -1075,7 +1176,10 @@ class PagedCache(_CacheManager):
                     budget -= 1
             if done:
                 del self._prefilling[slot]
-                self._finalize(job)
+                self._landing.append(_Landing(
+                    lambda job=job: self._finalize(job),
+                    [(job.slot, job.req, job.tokens, job.produced0,
+                      job.fold0)]))
             # idle engine: the first stream is live, decode now
             # interleaves with the remaining jobs
             if budget is None or budget <= 0:
@@ -1105,9 +1209,10 @@ class PagedCache(_CacheManager):
                 jnp.int32(job.slot), jnp.int32(job.next), jnp.int32(n),
                 seed, jnp.int32(job.fold0), temperature, top_k, top_p)
             if final:
-                # host transfer forces completion while the failure is
-                # still recoverable in this cycle
-                job.last_tok = int(tok)
+                # the sampled next token, read when the job lands (the
+                # read surfaces a failure while it is still recoverable
+                # in this cycle)
+                job.last_tok = tok
         job.next += n
         job.chunks += 1
         self.prefill_chunks += 1
@@ -1121,10 +1226,12 @@ class PagedCache(_CacheManager):
         return final
 
     def _finalize(self, job: _PrefillJob) -> None:
-        """Prompt fully in the pool: pin shareable prefix pages, emit
-        the sampled token and arm the slot's host-side decode state."""
+        """Prompt fully in the pool (the job's landing): read the sampled
+        token, pin shareable prefix pages, emit the token and arm the
+        slot's host-side decode state."""
         eng = self.eng
         req, slot = job.req, job.slot
+        job.last_tok = int(job.last_tok)  # tpulint: disable=TPU017
         now = eng.clock()
         if job.store_prefix:
             # idempotent trie insert: stored chain pages are only

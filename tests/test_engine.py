@@ -1479,3 +1479,288 @@ def test_step_returns_the_cache_layout_it_was_given(lm, program, paged):
     assert spec["k"].shape[0] == spec["v"].shape[0] == L
     assert sorted(spec) == (["k", "pages", "positions", "v"] if paged
                             else ["k", "positions", "v"])
+
+
+# -- a round ahead ------------------------------------------------------------
+# The engine launches round n + 1 on the device before it reads round n's
+# tokens (DecodeEngine.run_once). Each case below drives it over either
+# cache manager and holds every stream to its single-request oracle.
+
+def _run_out(eng):
+    """Cycle until the engine does no work; then nothing is in flight."""
+    while eng.run_once(timeout=0.01):
+        pass
+    assert eng._flights == [] and eng.active_count == 0
+    assert eng.run_once(timeout=0.01) is False
+
+
+def _solo_sampled(lm, prompt, max_new, **kw):
+    """A sampled request alone in an engine of one step a round."""
+    config, params = lm
+    eng = DecodeEngine(config, params, slots=1, autostart=False)
+    req = eng.submit(prompt, max_new=max_new, **kw)
+    _run_out(eng)
+    return req.result()
+
+
+def _case_greedy(lm, engine):
+    config, params = lm
+    eng = engine(steps_per_sync=4)
+    prompts = [[5, 11, 17], [3, 2, 9, 23, 41], [7]]
+    reqs = [eng.submit(p, max_new=n) for p, n in zip(prompts, (13, 9, 6))]
+    _run_out(eng)
+    for p, n, r in zip(prompts, (13, 9, 6), reqs):
+        assert r.result() == _oracle(config, params, p, n), p
+
+
+def _case_sampled(lm, engine):
+    """fold_in(key(seed), step) survives the round ahead: a sampled row
+    reads the same tokens beside co-tenants at 4 steps a round as alone
+    at 1."""
+    sampling = [dict(temperature=0.8, top_k=7, top_p=0.9, seed=42),
+                dict(temperature=1.3, seed=7)]
+    prompts = [[5, 11, 17], [9, 23, 41, 2]]
+    for steps in (2, 4):
+        eng = engine(steps_per_sync=steps)
+        reqs = [eng.submit(p, max_new=10, **kw)
+                for p, kw in zip(prompts, sampling)]
+        greedy = eng.submit([3, 2], max_new=10)
+        _run_out(eng)
+        for p, kw, r in zip(prompts, sampling, reqs):
+            assert r.result() == _solo_sampled(lm, p, 10, **kw), (steps, p)
+        assert greedy.result() == _oracle(*lm, [3, 2], 10)
+
+
+def _case_eos(lm, engine):
+    """A row that stops at EOS in round n was launched in round n + 1
+    too: it stops AT the EOS token, its slot is retired, and the next
+    request takes the slot."""
+    config, params = lm
+    want = _oracle(config, params, [5, 11, 17], 12)
+    stop, eos = _eos_pick(want)
+    assert stop <= 8                  # inside a round with one after it
+    eng = engine(steps_per_sync=4)
+    stopped = eng.submit([5, 11, 17], max_new=12, eos_id=eos)
+    other = eng.submit([3, 2, 9], max_new=12)
+    assert eng.run_once(timeout=0.01)
+    if not eng.paged:                 # both decode from the first cycle
+        assert len(eng._flights) == 1     # round 2 enqueued, round 1 read
+    later = eng.submit([9, 23, 41, 2], max_new=5)
+    _run_out(eng)
+    assert stopped.result() == want[:stop + 1]
+    assert other.result() == _oracle(config, params, [3, 2, 9], 12)
+    assert later.result() == _oracle(config, params, [9, 23, 41, 2], 5)
+
+
+def _case_budget(lm, engine):
+    """Budgets that end inside a round and on its last step: no token
+    past the budget reaches a stream, and the slot a budget frees takes
+    the next request behind the round that ends it."""
+    config, params = lm
+    eng = engine(steps_per_sync=4)
+    prompts = {(5, 11, 17): 7, (3, 2): 9, (9, 23, 41): 3, (13, 1, 8): 4}
+    reqs = {p: eng.submit(list(p), max_new=n) for p, n in prompts.items()}
+    _run_out(eng)
+    for p, n in prompts.items():
+        assert reqs[p].result() == _oracle(config, params, list(p), n), p
+    if not eng.paged:                 # all four admitted in one cycle:
+        assert eng.steps_total == 2 * 4   # two rounds cover every budget
+
+
+def _case_admission(lm, engine):
+    """An admission launched while a round is in flight: its prefill
+    queues behind that round, its first token is read after the round
+    before it, and it joins the launch after."""
+    config, params = lm
+    eng = engine(steps_per_sync=2)
+    first = eng.submit([5, 11, 17], max_new=12)
+    eng.run_once(timeout=0.01)
+    assert len(eng._flights) == 1
+    late = [eng.submit([7, 2], max_new=5),
+            eng.submit([9, 23, 41, 2, 8], max_new=6, temperature=0.9,
+                       seed=3)]
+    assert eng.run_once(timeout=0.01)
+    assert eng.active_count == 3 and len(eng._flights) == 1
+    _run_out(eng)
+    assert first.result() == _oracle(config, params, [5, 11, 17], 12)
+    assert late[0].result() == _oracle(config, params, [7, 2], 5)
+    assert late[1].result() == _solo_sampled(
+        lm, [9, 23, 41, 2, 8], 6, temperature=0.9, seed=3)
+
+
+def _case_idle(lm, engine):
+    """An engine left with no live stream has nothing in flight: a
+    request that ends at its prefill's token costs one cycle (the
+    benchmark's warm-up runs one cycle, then reads the handles), and a
+    round launched for rows that all stopped at EOS is dropped."""
+    from kubeflow_tpu.serving.engine import _END
+
+    config, params = lm
+    eng = engine(steps_per_sync=2)
+    reqs = [eng.submit([5 + i, 11, 17], max_new=1) for i in range(3)]
+    assert eng.run_once(timeout=0.01)
+    # rows admit all three in the one cycle; pages prefill one prompt a
+    # cycle on an idle engine (PagedCache._prefill_tick)
+    for _ in range(1 if not eng.paged else len(reqs)):
+        assert eng._flights == []
+        if all(r.out.qsize() == 2 for r in reqs):    # a token and the end
+            break
+        assert eng.run_once(timeout=0.01)
+    assert all(r.out.qsize() == 2 for r in reqs)
+    assert eng._flights == [] and eng.active_count == 0
+    want = _oracle(config, params, [5, 11, 17], 8)
+    stop, eos = _eos_pick(want)
+    req = eng.submit([5, 11, 17], max_new=8, eos_id=eos)
+    while not req.out.qsize() or req.out.queue[-1] is not _END:
+        assert eng.run_once(timeout=0.01)
+    assert eng._flights == [] and eng.active_count == 0
+    assert req.result() == want[:stop + 1]
+    assert eng.run_once(timeout=0.01) is False
+
+
+_PIPELINE_CASES = {"greedy": _case_greedy, "sampled": _case_sampled,
+                   "eos": _case_eos, "budget": _case_budget,
+                   "admission": _case_admission, "idle": _case_idle}
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["rows", "pages"])
+@pytest.mark.parametrize("case", sorted(_PIPELINE_CASES))
+def test_a_round_ahead_matches_the_single_request_oracle(lm, case, paged):
+    """Every stream the engine serves a round ahead is token for token
+    the one it would be alone, over either cache manager."""
+    config, params = lm
+
+    def engine(**kw):
+        return DecodeEngine(config, params, slots=4, autostart=False,
+                            **(_PAGED_KW if paged else {}), **kw)
+
+    _PIPELINE_CASES[case](lm, engine)
+
+
+def test_the_loop_adds_no_step_program(lm):
+    """Round n + 1 takes round n's device tokens where round n took the
+    host's: after precompile, a warm-up and rounds of greedy and sampled
+    rows, each step program holds the one jit entry precompile made, and
+    the splice one."""
+    config, params = lm
+    eng = DecodeEngine(config, params, slots=4, steps_per_sync=2,
+                       precompile=True, autostart=False)
+    assert (eng._step._cache_size(), eng._step_greedy._cache_size(),
+            eng._splice._cache_size()) == (1, 1, 1)
+    warm = [eng.submit([3, 2, 9][:n], max_new=1, temperature=0.7, seed=n)
+            for n in (1, 2, 3)]
+    eng.run_once(timeout=0.01)
+    assert all(len(r.result()) == 1 for r in warm)
+    reqs = [eng.submit([5, 11, 17], max_new=11),
+            eng.submit([7, 2], max_new=8, temperature=0.8, seed=1)]
+    rounds = eng.rounds_total
+    for _ in range(5):
+        assert eng.run_once(timeout=0.01)
+    assert eng.rounds_total == rounds + 5
+    _run_out(eng)
+    assert reqs[0].result() == _oracle(config, params, [5, 11, 17], 11)
+    assert (eng._step._cache_size(), eng._step_greedy._cache_size(),
+            eng._splice._cache_size()) == (1, 1, 1)
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["rows", "pages"])
+def test_a_fault_at_the_read_replays_every_stream_once(lm, paged,
+                                                       monkeypatch):
+    """A device fault that surfaces at round n's read, with round n + 1
+    enqueued: the engine rebuilds its cache and replays every surviving
+    stream from what it has emitted, and each stream delivers its
+    oracle's tokens, each once. Over rows the fault also finds a stream
+    whose last round was in flight after its slot took an admission: it
+    is replayed into a free slot."""
+    config, params = lm
+    eng = DecodeEngine(config, params, slots=3, steps_per_sync=2,
+                       autostart=False, **(_PAGED_KW if paged else {}))
+    ending = eng.submit([5, 11, 17], max_new=5)    # budget covered by r2
+    going = eng.submit([3, 2, 9], max_new=9, temperature=0.8, seed=5)
+    assert eng.run_once(timeout=0.01)              # r1 read, r2 in flight
+    late = eng.submit([9, 23, 41, 2], max_new=4)   # into ending's slot
+    fired = []
+    real_get = jax.device_get
+
+    def get(x):
+        if not fired and len(eng._flights) == 2:
+            fired.append(([s is None for s in eng._active],
+                          eng._kv.landing))
+            raise RuntimeError("injected fault at the read")
+        return real_get(x)
+
+    monkeypatch.setattr(jax, "device_get", get)
+    _run_out(eng)
+    assert fired and eng.recoveries == 1 and not eng.closed
+    if not paged:
+        assert fired == [([True, False, True], True)]
+    assert ending.result() == _oracle(config, params, [5, 11, 17], 5)
+    assert late.result() == _oracle(config, params, [9, 23, 41, 2], 4)
+    assert going.result() == _solo_sampled(lm, [3, 2, 9], 9,
+                                           temperature=0.8, seed=5)
+
+
+def test_rounds_ahead_counted_on_spans_and_counter(lm):
+    """``ahead`` on each ``engine.round`` and
+    ``kftpu_engine_rounds_ahead_total`` say the same: every round a
+    cycle reads was launched before the one before it was read, but the
+    first after the engine was idle."""
+    name = "rounds-ahead"
+    eng, collector = _round_engine(lm, name, slots=2, steps_per_sync=2)
+    assert _engine_counter("kftpu_engine_rounds_ahead_total",
+                           model=name) == 0
+    eng.submit([5, 11, 17], max_new=9)
+    eng.submit([3, 2], max_new=4)
+    _run_out(eng)
+    eng.submit([7, 2], max_new=5)                   # after an idle spell
+    _run_out(eng)
+    stepped = [r for r in _rounds(collector) if r.attrs["k"]]
+    ahead = [r.attrs["ahead"] for r in stepped]
+    assert ahead == [False, True, True, True, False, True]
+    assert _engine_counter("kftpu_engine_rounds_ahead_total",
+                           model=name) == sum(ahead)
+
+
+def test_snapshots_from_other_threads_while_rounds_run_ahead(lm):
+    """Callers read the engine's occupancy from their own threads while
+    its thread launches, reads and drops rounds: no reader sees a torn
+    list of rounds in flight, and every stream matches its oracle."""
+    import sys
+
+    config, params = lm
+    prompts = [(5 + i, 11, 17) for i in range(6)]
+    want = {p: _oracle(config, params, list(p), 6) for p in prompts}
+    eng = DecodeEngine(config, params, slots=4, steps_per_sync=2)
+    results, errors = {}, []
+    stop = threading.Event()
+
+    def poll():
+        while not stop.is_set():
+            try:
+                assert 0 <= eng.snapshot()["active_slots"] <= len(prompts)
+            except Exception as e:  # noqa: BLE001 — reported below
+                errors.append(e)
+                return
+
+    def client(p):
+        results[p] = eng.submit(list(p), max_new=6).result()
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        pollers = [threading.Thread(target=poll) for _ in range(4)]
+        clients = [threading.Thread(target=client, args=(p,))
+                   for p in prompts]
+        for t in pollers + clients:
+            t.start()
+        for t in clients:
+            t.join(timeout=120)
+        stop.set()
+        for t in pollers:
+            t.join(timeout=30)
+        assert not any(t.is_alive() for t in pollers + clients)
+    finally:
+        sys.setswitchinterval(old)
+        eng.close()
+    assert not errors
+    assert results == want

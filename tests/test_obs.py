@@ -623,12 +623,15 @@ def test_trace_collector_component_renders():
 def test_engine_round_phases_on_the_profiler_timeline(lm):
     """With a recording annotator in the profiler bridge's place: the
     ``engine.*`` annotations TILE the engine thread, none ever nested in
-    another. A round is admission's leaves (``engine.admit.host``, and
-    inside a row admission ``.launch``, ``.insert``, ``.read`` in the
-    order that path runs them), then ``engine.step``, ``engine.sync``,
-    ``engine.emit``; no clock read of ``run_once`` falls outside one of
-    them except the round's own phase boundaries and the admission's,
-    each of which is read once, between two leaves."""
+    another. The first round, on an idle engine, is admission's leaves
+    (``engine.admit.host``, and inside a row admission ``.launch``,
+    ``.insert``, back to ``.host`` once its programs are enqueued, then
+    ``.read`` when it lands), then ``engine.step`` for each of the two
+    rounds it launches, ``engine.sync`` and ``engine.emit`` for the
+    first; a round after it launches the next round before admission,
+    then reads and emits the one before. No clock read of ``run_once``
+    falls outside a leaf except the round's own phase boundaries and the
+    admission's, each of which is read once, between two leaves."""
     import contextlib
 
     from kubeflow_tpu.serving.engine import DecodeEngine
@@ -672,20 +675,26 @@ def test_engine_round_phases_on_the_profiler_timeline(lm):
     assert all(b[1] == e[1] for b, e in zip(events[::2], events[1::2]))
     assert all(n.startswith("engine.") for _k, n, _d in events)
     opened = [n for kind, n, _d in events if kind == "B"]
-    tail = ["engine.step", "engine.sync", "engine.emit"]
+    tail = ["engine.sync", "engine.emit"]
     assert opened == (
         ["engine.admit.host", "engine.admit.launch", "engine.admit.insert",
-         "engine.admit.read", "engine.admit.host"] + tail
-        + (["engine.admit.host"] + tail) * 2)
+         "engine.admit.host", "engine.admit.read", "engine.admit.host",
+         "engine.step", "engine.step"] + tail
+        + ["engine.step", "engine.admit.host"] + tail
+        # the third reads the round whose launch covered the budget
+        + ["engine.admit.host"] + tail)
+    orders = [("admit_s", "step_s", "sync_s", "emit_s")] + [
+        ("step_s", "admit_s", "sync_s", "emit_s")] * 2
     boundaries = set()
-    for r in rounds:
+    for r, order in zip(rounds, orders):
         t = r.start
         boundaries.add(t)
-        for p in ("admit_s", "step_s", "sync_s", "emit_s"):
+        for p in order:
             assert r.attrs["wait_s"] == 0.0 and r.attrs[p] > 0
             t += r.attrs[p]
             boundaries.add(t)
         assert t == r.end
+    assert [r.attrs["ahead"] for r in rounds] == [False, True, True]
     # round 0's row admission: its four leaf changes are the only other
     # clock reads between two annotations, and they are the boundaries of
     # its four durations (host is two stretches: before the launch and
